@@ -41,6 +41,7 @@ import os
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 
 BLOCK_ROWS = 256  # keeps 16-bit limb block-partials exact in f32 (< 2^24)
@@ -55,41 +56,48 @@ def enabled() -> bool:
 def _kernel(gid_ref, vals_ref, out_ref, *, n_groups: int):
     """One grid step = one row block → one [G, S] output slot.
 
-    gid_ref:  [B] int32 group ids (>= n_groups → masked/dead row)
+    gid_ref:  [1, 1, B] int32 group ids (>= n_groups → masked/dead row)
     vals_ref: [B, S] f32 state contributions (limbs already split)
     out_ref:  [1, G, S] this block's partials
     """
-    gid = gid_ref[...]
-    onehot = (gid[:, None]
-              == jax.lax.broadcasted_iota(jnp.int32, (1, n_groups), 1)
-              ).astype(jnp.float32)                       # [B, G]
-    vals = vals_ref[...]                                  # [B, S]
-    out_ref[0, :, :] = jax.lax.dot_general(
-        onehot, vals, (((0,), (0,)), ((), ())),            # [G, S]
-        preferred_element_type=jnp.float32,
-    )
+    gid = gid_ref[0]                                       # [1, B]
+    onehot_t = (jax.lax.broadcasted_iota(jnp.int32, (n_groups, 1), 0)
+                == gid).astype(jnp.float32)                # [G, B]
+    # HIGHEST: a 16-bit limb does not survive a single bf16 MXU pass
+    out_ref[0, :, :] = jnp.dot(onehot_t, vals_ref[...],    # [G, S]
+                               precision=jax.lax.Precision.HIGHEST,
+                               preferred_element_type=jnp.float32)
 
 
 def _blocked_call(gid: jnp.ndarray, vals: jnp.ndarray, n_groups: int,
                   interpret: bool) -> jnp.ndarray:
-    """→ [nb, G, S] per-block partials (reduced by the caller)."""
+    """→ [nb, G, S] per-block partials (reduced by the caller).
+
+    gid rides as [nb, 1, B]: a 1-D int32 operand would have to be blocked
+    at XLA's 1024-element tile, and B must stay 256 (exactness, above);
+    with the block's last two dimensions equal to the array's the TPU
+    compiler takes any B. The package turns x64 on, so index-map
+    constants are spelled int32 — Mosaic refuses an i64 block index.
+    """
     n, s = vals.shape
     nb = -(-n // BLOCK_ROWS)
     pad = nb * BLOCK_ROWS - n
     if pad:
         gid = jnp.pad(gid, (0, pad), constant_values=n_groups)
         vals = jnp.pad(vals, ((0, pad), (0, 0)))
+    z = np.int32(0)
     return pl.pallas_call(
         functools.partial(_kernel, n_groups=n_groups),
         grid=(nb,),
         in_specs=[
-            pl.BlockSpec((BLOCK_ROWS,), lambda i: (i,)),
-            pl.BlockSpec((BLOCK_ROWS, s), lambda i: (i, 0)),
+            pl.BlockSpec((1, 1, BLOCK_ROWS), lambda i: (i, z, z)),
+            pl.BlockSpec((BLOCK_ROWS, s), lambda i: (i, z)),
         ],
-        out_specs=pl.BlockSpec((1, n_groups, s), lambda i: (i, 0, 0)),
+        out_specs=pl.BlockSpec((1, n_groups, s), lambda i: (i, z, z)),
         out_shape=jax.ShapeDtypeStruct((nb, n_groups, s), jnp.float32),
         interpret=interpret,
-    )(gid, vals)
+        name="grouped_sums",
+    )(gid.reshape(nb, 1, BLOCK_ROWS), vals)
 
 
 def grouped_sums(gid, int_states, n_groups: int,

@@ -25,9 +25,12 @@ Design:
   same n_groups > cap contract the sort engine's drivers already replay
   on (capacity-growth replay, ops/grouping.grouped_merge docstring).
 - Kernels are serial per-row loops (grid=(1,)) — the table lives in one
-  ref and rows chain through `lax.while_loop` probes. On CPU they run
-  under the Pallas interpreter (`use_interpret()`), so tier-1 and the
-  verifier sweeps execute the same kernel logic bit-for-bit.
+  ref and rows chain through `lax.while_loop` probes. They run ONLY
+  under the Pallas interpreter, on CPU (`use_interpret()`): the TPU
+  compiler refuses all three (`TPU_REFUSAL`), so on a TPU backend the
+  hash engine is not selectable (`tpu_refusal()`, enforced by
+  plan/stats.choose_breaker_engine) and nothing here is ever interpreted
+  on a chip.
 - Join probe returns a bounded-fanout match matrix mm[n, F] plus EXACT
   per-row match counts; rows with more than F matches set the overflow
   counter and the driver re-probes with F doubled (counts, offsets and
@@ -46,8 +49,28 @@ from jax.experimental import pallas as pl
 
 def use_interpret() -> bool:
     """Interpret kernels off-TPU: tier-1/CI and the verifier sweeps then
-    exercise the hash engine on CPU with the exact kernel semantics."""
+    exercise the hash engine on CPU with the exact kernel semantics. A
+    CPU-test device only — never true on a TPU backend."""
     return jax.default_backend() != "tpu"
+
+
+# The v5e compiler's verdict on this module (jax 0.9.0 / libtpu 0.0.34,
+# compiled for a described v5e:2x2 at n = 2^17): the kernels walk whole
+# unblocked int64 refs with scalar loops. As written they are refused
+# while lowering; with 32-bit refs in scalar memory the design compiles
+# only while every ref fits 1 MiB of SMEM (n = 2^13 yes, n = 2^17 no).
+TPU_REFUSAL = (
+    "ops/pallas_hash kernels are refused by the TPU compiler: "
+    "group_insert and join_probe 'Only arrays with 32-bit element types "
+    "can be converted to scalars, but got: float64', join_insert "
+    "RecursionError while lowering; with 32-bit refs in SMEM 'Ran out of "
+    "memory in memory space smem. Used 3.00M of 1.00M' at 2^17 rows")
+
+
+def tpu_refusal() -> Optional[str]:
+    """Why the hash engine cannot run on this backend, or None if it can
+    (off-TPU it runs interpreted)."""
+    return TPU_REFUSAL if jax.default_backend() == "tpu" else None
 
 
 # ---------------------------------------------------------------------------

@@ -327,13 +327,16 @@ class MeshExecutor:
         override, else the CBO thresholds. Stamped on the node (EXPLAIN)
         and counted on the shared engine-dispatch families. Runs at trace
         time, so a cached mesh program keeps its engine choice."""
-        from presto_tpu.plan.stats import choose_breaker_engine
+        from presto_tpu.plan.stats import (HashEngineUnavailable,
+                                           choose_breaker_engine)
 
         override = getattr(self.config, "breaker_engine", "auto")
         hbo = getattr(self.config, "hbo", "observe")
         try:
             engine, why = choose_breaker_engine(node, self.catalog, override,
                                                 hbo=hbo)
+        except HashEngineUnavailable:
+            raise
         except Exception:
             engine, why = "sort", "stats derivation failed"
         node.__dict__["_breaker_engine"] = engine

@@ -423,22 +423,62 @@ def _observed(node: PlanNode, catalog, site: str):
         return None
 
 
+class HashEngineUnavailable(ValueError):
+    """breaker_engine=hash was asked for on a backend whose compiler
+    refuses the hash engine's kernels."""
+
+
+def _hash_refusal() -> Optional[str]:
+    """The platform gate: the hash engine exists only where its kernels
+    compile (lazy import — the CBO stays importable without Pallas)."""
+    from presto_tpu.ops.pallas_hash import tpu_refusal
+
+    return tpu_refusal()
+
+
+def require_hash_engine() -> None:
+    """The loud half of the gate: a forced ``hash`` on a TPU backend
+    raises, naming the kernels and the compiler's refusal."""
+    refusal = _hash_refusal()
+    if refusal:
+        raise HashEngineUnavailable(
+            f"breaker_engine=hash is not selectable on this device: "
+            f"{refusal}")
+
+
+def _sort_where_hash_is_refused(engine: str, why: str):
+    """The quiet half: a stats verdict of ``hash`` on a TPU backend
+    becomes ``sort`` with a why-string EXPLAIN ANALYZE shows."""
+    if engine == "hash" and _hash_refusal():
+        return "sort", (f"hash engine not selectable on tpu (kernels "
+                        f"refused by the compiler); stats said {why}")
+    return engine, why
+
+
 def choose_breaker_engine(node: PlanNode, catalog,
                           override: str = "auto", hbo: str = "off"):
     """(engine, why) for a pipeline breaker: ``engine`` ∈ {sort, hash}.
 
     ``override`` is the ``breaker_engine`` session property: ``sort`` /
-    ``hash`` force the engine; ``auto`` asks the stats above. No stats →
-    sort (never regress the known-good engine on a blind guess).
+    ``hash`` force the engine (``hash`` raises HashEngineUnavailable on
+    a TPU backend); ``auto`` asks the stats below, then the platform."""
+    if override == "sort":
+        return "sort", "session breaker_engine=sort"
+    if override == "hash":
+        require_hash_engine()
+        return "hash", "session breaker_engine=hash"
+    return _sort_where_hash_is_refused(
+        *_engine_from_stats(node, catalog, hbo))
+
+
+def _engine_from_stats(node: PlanNode, catalog, hbo: str = "off"):
+    """The CBO's verdict, platform aside. No stats → sort (never regress
+    the known-good engine on a blind guess).
 
     ``hbo="correct"`` consults the runstats history first: a previous run
     of the same structural fingerprint replaces the estimated group /
     build-row counts with observed ones, and the why string carries an
     ``(hbo: observed)`` provenance suffix."""
-    if override == "sort":
-        return "sort", "session breaker_engine=sort"
-    if override == "hash":
-        return "hash", "session breaker_engine=hash"
     if isinstance(node, Aggregate):
         if not node.group_keys:
             return "sort", "global aggregate"
@@ -518,8 +558,9 @@ def choose_breaker_engine_observed(node: PlanNode, groups: float,
         if dup < HASH_MIN_DUPLICATION:
             return "sort", (f"observed duplication x{dup:.2g} < "
                             f"{HASH_MIN_DUPLICATION:.2g} (adaptive: observed)")
-        return "hash", (f"observed {groups:.3g} groups, x{dup:.3g} "
-                        f"duplication (adaptive: observed)")
+        return _sort_where_hash_is_refused(
+            "hash", (f"observed {groups:.3g} groups, x{dup:.3g} "
+                     f"duplication (adaptive: observed)"))
     return "sort", "not an engine-dimensioned breaker"
 
 
@@ -575,6 +616,9 @@ def choose_join_mode(chain, catalog, override: str = "auto",
         if not j.build_unique and build_rows > MULTIWAY_MAX_FANOUT_BUILD_ROWS:
             return "binary", (f"{src} fanout build {build_rows:.3g} rows > "
                               f"{MULTIWAY_MAX_FANOUT_BUILD_ROWS}{suffix}")
+        if not j.build_unique and _hash_refusal():
+            return "binary", ("fanout leg needs the hash engine's probe "
+                              "kernel — not selectable on tpu")
         total_build += build_rows
     if total_build > MULTIWAY_MAX_BUILD_ROWS:
         return "binary", (f"{src} combined builds {total_build:.3g} rows > "
