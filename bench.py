@@ -902,7 +902,11 @@ def _compile_tail_child(mode: str):
 def _run_compile_tail(extra: dict, remaining: float):
     """Cold-boot vs farm-armed-boot A/B (BENCH_NOTES round 16): serving
     warmup_s and first-query e2e, four child processes, one cache dir."""
-    d = tempfile.mkdtemp(prefix="bench_farm_")
+    # a fixed artifact root, emptied per run (the XLA cache is not here:
+    # presto_tpu.compile_cache_dir decides that one)
+    d = os.path.join(DATA_DIR, "farm_ab")
+    shutil.rmtree(d, ignore_errors=True)
+    os.makedirs(d)
     rec = {}
     try:
         for mode in ("cold", "record", "converge", "armed"):

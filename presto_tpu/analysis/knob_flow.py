@@ -91,7 +91,6 @@ PLANE = "knob-flow"
 # it; anything in neither set is an undeclared knob and flags at sinks.
 _CACHE_VOLATILE_ENVS = {
     "PRESTO_TPU_CACHE_DIR": "artifact/corpus location, not content",
-    "PRESTO_TPU_COMPILE_CACHE": "arms the XLA executable cache",
     "PRESTO_TPU_DEVPROF_SAMPLE_S": "device-memory sampling period",
     "PRESTO_TPU_FARM": "arms boot-time pre-compilation",
     "PRESTO_TPU_FARM_LIMIT": "boot arming budget",

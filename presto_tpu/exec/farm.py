@@ -532,7 +532,6 @@ def boot(catalog, config=None, workers: Optional[int] = None,
         os.environ.get("PRESTO_TPU_FARM_WORKERS", _DEFAULT_WORKERS))
     limit = limit or int(
         os.environ.get("PRESTO_TPU_FARM_LIMIT", _DEFAULT_BOOT_LIMIT))
-    _programs.enable_compilation_cache()
     # register pytree serialization on THIS thread, before workers exist:
     # a worker registering mid-boot can lose an import race against
     # another worker's lazy ops import, and artifact restore would

@@ -274,56 +274,17 @@ def _persist_dir() -> Optional[str]:
     return os.path.join(d, "programs")
 
 
-_compilation_cache_state = [None]  # shared: guarded-by(_lock); None=untried
-
-
 def enable_compilation_cache() -> bool:
-    """Arm the XLA persistent compilation cache under the same
-    PRESTO_TPU_CACHE_DIR umbrella as the jax.export artifacts, so a
+    """Whether JAX's persistent compilation cache is on, so that a
     restored program's first call fetches its backend executable from
-    disk instead of re-compiling the StableHLO. Idempotent and
-    best-effort: where jax/the backend doesn't support it the restore
-    path keeps working and reports honestly as ``restored_retrace``."""
-    import os
+    disk instead of re-compiling the StableHLO. Reports only:
+    ``presto_tpu/__init__`` decides the directory once, at import (or
+    leaves it to ``JAX_COMPILATION_CACHE_DIR``), and nothing re-points
+    it afterwards — the path is part of the cache's key."""
+    import jax
 
-    d = _persist_dir()
-    if d is None:
-        return False
-    with _lock:
-        if _compilation_cache_state[0] is not None:
-            return _compilation_cache_state[0]
-    ok = False
-    try:
-        import jax
-
-        cache_dir = os.path.join(os.path.dirname(d), "xla_cache")
-        os.makedirs(cache_dir, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        # engine programs are often tiny (CPU lowers them in ms); persist
-        # everything so the compile-tail win doesn't depend on thresholds
-        try:
-            jax.config.update(
-                "jax_persistent_cache_min_compile_time_secs", 0.0)
-        except Exception:
-            pass
-        try:
-            jax.config.update(
-                "jax_persistent_cache_min_entry_size_bytes", 0)
-        except Exception:
-            pass
-        ok = True
-    except Exception:
-        ok = False
-    with _lock:
-        # racing enablers run the same idempotent jax.config updates;
-        # last writer records the same verdict
-        _compilation_cache_state[0] = ok  # lint: allow(check-then-act)
-    return ok
-
-
-def compilation_cache_active() -> bool:
-    with _lock:
-        return bool(_compilation_cache_state[0])
+    return bool(jax.config.jax_enable_compilation_cache
+                and jax.config.jax_compilation_cache_dir)
 
 
 _pytree_serialization_ready = False  # shared: guarded-by(_pytree_ser_lock)
@@ -433,9 +394,7 @@ def _persist_program(entry: ProgramEntry, args, kw) -> None:
     if d is None or entry.fp is None:
         return
     _ensure_pytree_serialization()
-    enable_compilation_cache()
     try:
-        # submodule: not reachable as an attribute on older jax
         from jax import export as jax_export
 
         data = jax_export.export(entry.jfn)(*args, **kw).serialize()
@@ -535,7 +494,6 @@ def prewarm_artifacts(threads: int = 2,
     if d is None:
         return 0
     _ensure_pytree_serialization()
-    enable_compilation_cache()
     try:
         files = sorted(fn for fn in os.listdir(d)
                        if fn.endswith(".jaxexp"))
@@ -589,9 +547,8 @@ def _restore_programs_inner(entry: ProgramEntry) -> None:
     if d is None:
         return
     _ensure_pytree_serialization()
-    # armed BEFORE the restored program's first call, so its backend
-    # compile is a persistent-cache fetch (restored_executable) instead
-    # of a silent re-pay
+    # with the persistent cache on, the restored program's first call is
+    # a cache fetch (restored_executable) instead of a silent re-pay
     executable = enable_compilation_cache()
     try:
         from jax import export as jax_export

@@ -384,3 +384,44 @@ def test_program_persistence_gate_defaults_off(cat, tmp_path, monkeypatch):
     LocalRunner(cat, ExecConfig()).run(
         "select count(*) as c from region")
     assert not (tmp_path / "programs").exists()
+
+
+# ---------------------------------------------------------------------------
+# compile-cache directory: one pure decision, settable from outside
+
+
+@pytest.mark.parametrize("environ,expect", [
+    ({"JAX_COMPILATION_CACHE_DIR": "/x"}, None),  # set → leave alone
+    ({"JAX_COMPILATION_CACHE_DIR": "/x",
+      "PRESTO_TPU_CACHE_DIR": "/y"}, None),
+    ({}, "checkout"),
+    ({"PRESTO_TPU_CACHE_DIR": "/y"}, "checkout"),  # umbrella ≠ XLA cache
+])
+def test_compile_cache_dir_is_a_pure_function_of_env(environ, expect):
+    import os
+
+    import presto_tpu
+
+    got = presto_tpu.compile_cache_dir(environ)
+    if expect is None:
+        assert got is None
+    else:
+        checkout = os.path.dirname(os.path.dirname(
+            os.path.abspath(presto_tpu.__file__)))
+        assert got == os.path.join(checkout, ".jax_cache")
+
+
+def test_persisted_query_does_not_repoint_compile_cache(cat, tmp_path,
+                                                        monkeypatch):
+    import jax
+
+    monkeypatch.setenv("PRESTO_TPU_CACHE_DIR", str(tmp_path))
+    monkeypatch.setenv("PRESTO_TPU_PROGRAM_PERSIST", "1")
+    before = jax.config.jax_compilation_cache_dir
+    programs.reset(counters_only=False)
+    LocalRunner(cat, ExecConfig()).run(
+        "select l_linestatus as f, sum(l_tax) as s from lineitem "
+        "where l_discount > 0.03 group by l_linestatus order by f")
+    assert jax.config.jax_compilation_cache_dir == before
+    assert not (tmp_path / "xla_cache").exists()
+    assert programs.enable_compilation_cache() == bool(before)
