@@ -1046,11 +1046,10 @@ class Coordinator:
                               "drain", "e2e")),
                 "",
             ]
+        tr_spans = tracer.spans() if tracer is not _obs_trace.NOOP else None
         try:
             # query doctor: ranked bottleneck attribution over lifecycle +
             # inflight telemetry (present only when a plane saw the query)
-            tr_spans = (tracer.spans()
-                        if tracer is not _obs_trace.NOOP else None)
             doctor = _obs_inflight.analyze(session_qid or qid,
                                            spans=tr_spans)
             if doctor is not None and doctor.get("verdict"):
@@ -1062,7 +1061,19 @@ class Coordinator:
                 lines.append("")
         except Exception:
             pass
-        lines += [dplan.to_string(), "", "-- task execution profile --"]
+        lines += [dplan.to_string(), ""]
+        # the engine each breaker took and why (the tasks' breaker_engine
+        # trace markers): where the platform refused the hash engine, the
+        # why-string says so here
+        verdicts = sorted({
+            (s.attrs.get("node"), s.attrs.get("engine"), s.attrs.get("why"))
+            for s in (tr_spans or ())
+            if s.kind == "breaker_engine" and s.attrs})
+        if verdicts:
+            lines.append("-- breaker engines --")
+            lines += [f"  {n} [engine={e}: {w}]" for n, e, w in verdicts]
+            lines.append("")
+        lines.append("-- task execution profile --")
         by_fid: Dict[int, list] = {}
         for tid, fid, info in stats:
             by_fid.setdefault(fid, []).append((tid, info))
