@@ -2,7 +2,7 @@
 
 Everything the engine knew about memory was self-reported (memory.py
 pool reservations) and everything it knew about hardware efficiency was
-hand-derived offline (BENCH_NOTES utilization math). This module is the
+hand-derived offline. This module is the
 device-side truth plane:
 
   * **per-program cost/memory analysis** — every program the structural
@@ -15,8 +15,8 @@ device-side truth plane:
     and per query;
   * **HBM watermark sampling** — ``device.memory_stats()`` at span
     boundaries plus a background cadence, with honest ``unavailable``
-    labeling when the backend has no device memory introspection (CPU
-    fallback — the same policy bench.py applies to its device probe);
+    labeling when the backend has no device memory introspection (the
+    CPU backend of the tests);
   * **ledger-vs-device reconciliation** — the sampled device watermark
     against the MemoryPool ledger's own peak, exported as the
     ``presto_tpu_memory_ledger_drift_ratio`` histogram: it catches
@@ -267,25 +267,15 @@ def _stop_sampler() -> None:
 # -- per-program XLA cost / memory analysis ----------------------------------
 
 
-def _first_dict(obj) -> Optional[dict]:
-    """cost_analysis() is a dict on Lowered and a list of dicts on
-    Compiled across jax versions — accept both shapes."""
-    if isinstance(obj, dict):
-        return obj
-    if isinstance(obj, (list, tuple)) and obj and isinstance(obj[0], dict):
-        return obj[0]
-    return None
-
-
 def analyze_lowered(lowered) -> Dict[str, Any]:
     """Cost + memory analysis of one jax Lowered. The cost side is free;
     the memory side pays one ``.compile()`` (served by the persistent
-    XLA cache when PRESTO_TPU_CACHE_DIR is set) — acceptable because the
+    XLA cache on a TPU backend) — acceptable because the
     whole plane is opt-in. Missing pieces are recorded as absent, never
     guessed."""
     rec: Dict[str, Any] = {}
     try:
-        ca = _first_dict(lowered.cost_analysis())
+        ca = lowered.cost_analysis()
         if ca:
             if ca.get("flops") is not None:
                 rec["flops"] = float(ca["flops"])

@@ -17,21 +17,7 @@ from jax.sharding import Mesh
 
 
 WORKERS = "workers"
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma=True):
-    """`jax.shard_map` across jax versions.
-
-    The top-level alias (and the check_rep -> check_vma rename) only exist
-    from jax 0.5/0.7; on older jax the same function lives at
-    jax.experimental.shard_map.shard_map with the old kwarg name."""
-    fn = getattr(jax, "shard_map", None)
-    if fn is not None:
-        return fn(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as legacy
-    return legacy(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=check_vma)
+shard_map = jax.shard_map
 
 
 def make_mesh(n_devices: Optional[int] = None) -> Mesh:

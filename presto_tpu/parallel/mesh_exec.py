@@ -982,8 +982,11 @@ class MeshExecutor:
                 jnp.stack(sites.lane_used + [jnp.int64(0)]), WORKERS)
             # pmax, not psum: the lane maximum is a high-water mark — the
             # worst (src device, dst partition) lane anywhere on the mesh
+            # (in int32 — a lane holds far fewer than 2^31 rows, and the
+            # TPU compiler lowers a 64-bit all-reduce only for sums)
             lmax = jax.lax.pmax(
-                jnp.stack(sites.lane_max + [jnp.int64(0)]), WORKERS)
+                jnp.stack(sites.lane_max + [jnp.int64(0)]).astype(jnp.int32),
+                WORKERS)
             return out, ovf, used, lmax
 
         in_specs = tuple(P(WORKERS) if sh else P()
