@@ -5,6 +5,12 @@
     python -m presto_tpu.server --worker --coordinator-url http://host:8080 \
         --catalog tpch:sf=1 [--node-id w1] [--secret S]
 
+One process per chip: both roles touch their jax device, and a TPU chip
+belongs to one process at a time. On a one-chip host the coordinator runs
+with `--platform cpu` and the worker owns the chip (each start line names
+the device its process holds). `chip_smoke.py` runs both roles in ONE
+process instead.
+
 Reference: server/PrestoServer.java:69-119 — one binary, role decided by
 config (coordinator=true/false); here by flag. Workers announce to the
 coordinator (airlift discovery analog) and serve the /v1/task data plane;
@@ -105,8 +111,11 @@ def main(argv=None):
     p.add_argument("--memory-pool-bytes", type=int, default=None)
     p.add_argument("--spill-dir", default=None)
     p.add_argument("--platform", default=None,
-                   help="jax platform override (e.g. cpu, tpu) — the site "
-                        "config may ignore the JAX_PLATFORMS env var")
+                   help="jax platform for THIS process (cpu, tpu). A chip "
+                        "belongs to one process at a time and both roles "
+                        "touch their device: on a one-chip host start the "
+                        "coordinator with --platform cpu and let the "
+                        "worker own the chip")
     p.add_argument("--password-file", default=None,
                    help="(coordinator) enable BASIC auth from this file "
                         "(lines: user:salt:sha256(salt||password))")
@@ -132,10 +141,14 @@ def main(argv=None):
                         "table/column authorization rules")
     args = p.parse_args(argv)
 
-    if args.platform:
-        import jax
+    import jax
 
+    if args.platform:
         jax.config.update("jax_platforms", args.platform)
+    # take the device now and name it on the start line: a second process
+    # asking for a chip that is already owned fails here, not mid-query
+    dev = jax.devices()
+    device = f"device {dev[0].platform}:{dev[0].device_kind} x{len(dev)}"
 
     if args.function_plugin:
         from presto_tpu.functions import registry
@@ -180,7 +193,7 @@ def main(argv=None):
             cluster_memory_limit_bytes=args.cluster_memory_limit_bytes,
             access_control=access_control, tls=tls,
         )
-        print(f"coordinator listening on {coord.url}", flush=True)
+        print(f"coordinator listening on {coord.url} ({device})", flush=True)
         stop = []
         signal.signal(signal.SIGTERM, lambda *a: stop.append(1))
         try:
@@ -214,7 +227,7 @@ def main(argv=None):
         run_slots=args.run_slots,
         tls=wtls,
     )
-    print(f"worker {node_id} listening on {w.url}"
+    print(f"worker {node_id} listening on {w.url} ({device})"
           + (f", announcing to {args.coordinator_url}"
              if args.coordinator_url else ""), flush=True)
     stop = []

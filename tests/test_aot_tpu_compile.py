@@ -1,0 +1,228 @@
+"""What the TPU's compiler says, asked without a TPU.
+
+Every case compiles one main-path program for a *described* v5e:2x2 (the
+TPU compiler is installed here; no chip is attached) at the shapes of one
+default batch, n = 2^17, with ``interpret=False``. A kernel that passes the
+Pallas interpreter can still be refused here — the hash engine's kernels
+are (ops/pallas_hash.TPU_REFUSAL), which is why that engine is not
+selectable on a TPU backend, and one case pins that gate.
+
+The multi-operand int64 sorts of the sort engine (build_side, grouped_merge
+on wide keys) are accepted too but take minutes each to compile, so they
+stay out of this file; CHANGES.md (PR 23) has their verdicts and seconds.
+
+The topology is described inside a module-scoped fixture — never at import:
+only one process may load the TPU library, and under xdist every worker
+imports every test file. Compiles happen in the test's own process, with the
+persistent compilation cache off (a described-device executable cannot be
+read back without a chip).
+"""
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
+                          SingleDeviceSharding)
+
+N = 1 << 17
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        t = topologies.get_topology_desc(platform="tpu",
+                                         topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield t
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _placed(tree, sharding):
+    """The same pytree, each shape leaf placed by `sharding` (static
+    arguments pass through)."""
+    return jax.tree_util.tree_map(
+        lambda x: (jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding)
+                   if hasattr(x, "shape") else x),
+        tree)
+
+
+def _sds(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, jnp.dtype(dtype), sharding=sharding)
+
+
+@pytest.mark.parametrize("groups,n_states", [(64, 2), (128, 16)])
+def test_grouped_sums_kernel_compiles(one_chip, groups, n_states):
+    """The MXU limb-split group-by (PRESTO_TPU_PALLAS=1): the one Pallas
+    kernel selectable on a TPU backend. (128, 16) is the widest the
+    direct-domain path asks for (_MASK_SLOTS groups, Q1-sized state list)."""
+    from presto_tpu.ops import pallas_groupby
+
+    compiled = jax.jit(
+        lambda g, *s: pallas_groupby.grouped_sums(
+            g, list(s), groups, interpret=False)
+    ).lower(_sds((N,), jnp.int32, one_chip),
+            *[_sds((N,), jnp.int64, one_chip)] * n_states).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_searchsorted_probe_compiles(one_chip):
+    """The sort engine's join probe against a sorted build (ops/join.py):
+    two searchsorted passes + the collision scan, int64 keys."""
+    from presto_tpu.batch import Batch, Column
+    from presto_tpu.ops.join import build_side, probe_unique
+    from presto_tpu.types import BIGINT
+
+    def batch(names):
+        return Batch(names, [BIGINT] * len(names),
+                     [Column(jax.ShapeDtypeStruct((N,), jnp.int64))
+                      for _ in names],
+                     jax.ShapeDtypeStruct((N,), jnp.bool_), {})
+
+    table = jax.eval_shape(lambda b: build_side(b, ["bk"]),
+                           batch(["bk", "payload"]))
+    compiled = jax.jit(
+        lambda t, p: probe_unique(t, p, ["pk"], ["bk"])
+    ).lower(_placed(table, one_chip),
+            _placed(batch(["pk", "v"]), one_chip)).compile()
+    assert compiled.memory_analysis() is not None
+
+
+def test_q6_scan_filter_aggregate_chain_compiles(one_chip, monkeypatch):
+    """TPC-H Q6's leaf fragment as the engine really builds it: run Q6 on
+    the CPU over 8 batches of 2^17 rows, record the fused fragment-step
+    programs (exec/fragment_jit.py) with the arguments they were called
+    with, and hand the same programs, at the same shapes, to the TPU
+    compiler."""
+    import pandas as pd
+
+    from presto_tpu.catalog.memory import MemoryConnector
+    from presto_tpu.connector import Catalog
+    from presto_tpu.exec import ExecConfig, LocalRunner, programs
+    from presto_tpu.types import DATE, DecimalType
+
+    rows = 8 * N
+    conn = MemoryConnector()
+    conn.add_table(
+        "lineitem",
+        pd.DataFrame({
+            "l_extendedprice": np.full(rows, 1000.0),
+            "l_discount": np.full(rows, 0.06),
+            "l_quantity": np.full(rows, 10, np.int64),
+            "l_shipdate": np.full(rows, 8800, np.int64)}),
+        types={"l_extendedprice": DecimalType(15, 2),
+               "l_discount": DecimalType(15, 2), "l_shipdate": DATE})
+    cat = Catalog()
+    cat.register("m", conn, default=True)
+
+    calls = []
+    real_wrap = programs.wrap
+
+    def spying_wrap(entry, node_stats, node_kind, key):
+        wrapped = real_wrap(entry, node_stats, node_kind, key)
+
+        def spy(*a, **k):
+            shapes = jax.tree_util.tree_map(
+                lambda x: (jax.ShapeDtypeStruct(x.shape, x.dtype)
+                           if hasattr(x, "shape") else x), (a, k))
+            calls.append((key, entry.jfn, shapes))
+            return wrapped(*a, **k)
+
+        spy._entry = entry
+        return spy
+
+    monkeypatch.setattr(programs, "wrap", spying_wrap)
+    programs.reset(counters_only=False)
+    out = LocalRunner(cat, ExecConfig()).run("""
+        select sum(l_extendedprice * l_discount) as revenue from lineitem
+        where l_shipdate >= date '1994-01-01'
+          and l_shipdate < date '1995-01-01'
+          and l_discount between 0.05 and 0.07 and l_quantity < 24""")
+    assert len(out) == 1
+
+    steps = {key: (jfn, shapes) for key, jfn, shapes in calls
+             if key.startswith("fragment")}
+    assert steps, sorted({k for k, _, _ in calls})
+    for key, (jfn, (a, k)) in steps.items():
+        leaves = jax.tree_util.tree_leaves((a, k))
+        assert any(getattr(x, "shape", ())[-1:] == (N,) for x in leaves), key
+        jfn.lower(*_placed(a, one_chip), **_placed(k, one_chip)).compile()
+
+
+def test_all_to_all_exchange_compiles_on_four_device_mesh(topo):
+    """One HASH-repartition exchange of the mesh data plane
+    (parallel/mesh_exec.py): partition layout → fused lane pack → ONE
+    all_to_all → unpack, as a shard_map program over the 2x2's four chips,
+    2^17 rows per device."""
+    from presto_tpu.batch import Batch, Column
+    from presto_tpu.ops.partition import partition_layout
+    from presto_tpu.parallel import lanes
+    from presto_tpu.parallel.mesh import WORKERS, shard_map
+    from presto_tpu.parallel.mesh_exec import _fused_all_to_all
+    from presto_tpu.types import BIGINT, DATE
+
+    n_dev = len(topo.devices)
+    assert n_dev == 4
+    mesh = Mesh(np.array(topo.devices), (WORKERS,))
+    rows = NamedSharding(mesh, P(WORKERS))
+    per_cap = N // n_dev
+    glob = Batch(["k", "d"], [BIGINT, DATE],
+                 [Column(_sds((n_dev * N,), jnp.int64, rows)),
+                  Column(_sds((n_dev * N,), jnp.int32, rows))],
+                 _sds((n_dev * N,), jnp.bool_, rows), {})
+    plan = lanes.plan_lanes(glob)
+
+    def exchange(b):
+        sperm, dest, _counts, routed, _ovf = partition_layout(
+            b, ["k"], n_dev, per_cap)
+        bufs = lanes.pack_partitioned(b, plan, sperm, dest, routed,
+                                      n_dev * per_cap)
+        return lanes.unpack_batch(b, plan,
+                                  _fused_all_to_all(bufs, n_dev, per_cap))
+
+    compiled = jax.jit(shard_map(
+        exchange, mesh=mesh, in_specs=(P(WORKERS),),
+        out_specs=P(WORKERS), check_vma=False)).lower(glob).compile()
+    assert "all-to-all" in compiled.as_text()
+
+
+def test_hash_engine_is_a_loud_error_on_a_tpu_backend(monkeypatch):
+    """The hash engine's kernels are refused by the TPU compiler, so with
+    the backend reported as TPU: `auto` answers sort and says why (EXPLAIN
+    shows it), a forced `hash` raises at plan install, and the multiway
+    join declines fanout legs. Steered here, in the test — the program has
+    no option for it."""
+    from presto_tpu.catalog.tpch import tpch_catalog
+    from presto_tpu.exec import ExecConfig, LocalRunner
+    from presto_tpu.ops import pallas_hash
+    from presto_tpu.plan import stats
+
+    sql = ("select l_returnflag, sum(l_quantity) as s from lineitem "
+           "group by l_returnflag")
+    cat = tpch_catalog(0.01)
+    assert "[engine=hash: est" in LocalRunner(cat, ExecConfig()).explain(sql)
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert not pallas_hash.use_interpret()
+    plan = LocalRunner(cat, ExecConfig()).explain(sql)
+    assert "[engine=sort: hash engine not selectable on tpu" in plan
+    assert stats.choose_breaker_engine_observed(
+        LocalRunner(cat, ExecConfig()).plan(sql).root.child, 8.0,
+        1e6)[0] in ("sort",)
+    with pytest.raises(stats.HashEngineUnavailable,
+                       match="group_insert.*32-bit element types"):
+        LocalRunner(cat, ExecConfig(breaker_engine="hash")).run(sql)
