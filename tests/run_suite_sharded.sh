@@ -6,7 +6,7 @@
 # of compiled executables accumulate; every test FILE passes in
 # isolation, and the same suite ran 575- and 628-green in one process
 # earlier on the same day — the crash is jaxlib/XLA process-lifetime
-# state, not a test failure; see BENCH_NOTES.md "Known issue").
+# state, not a test failure).
 # Sharding bounds each process's lifetime while keeping full coverage.
 #
 # Usage: tests/run_suite_sharded.sh [num_shards]   (default 4)
