@@ -249,9 +249,10 @@ def served_phase(sf: float, device, events: CacheEvents) -> bool:
     catalog = build_catalog([f"tpch:sf={sf:g}"])
     emit({"phase": "setup", "sf": sf, "lineitem_rows": len(ref.lineitem),
           "reference_data_s": time.time() - t0})
-    emit(kernel_phase())
+    kernel = kernel_phase()
+    emit(kernel)
 
-    all_ok = True
+    all_ok = not kernel["interpreted"]
     runner = DistributedRunner(catalog, n_workers=1, config=ExecConfig())
     try:
         url = runner.coordinator.url

@@ -1932,8 +1932,7 @@ def _breaker_engine_choice(node: PlanNode, ctx: "ExecContext",
     (plan/stats.choose_breaker_engine). Stamps the decision + rationale
     on the node for EXPLAIN and, when ``record``, bumps the
     engine-labeled dispatch counters (ctx.stats + /v1/metrics)."""
-    from presto_tpu.plan.stats import (HashEngineUnavailable,
-                                       choose_breaker_engine)
+    from presto_tpu.plan.stats import choose_breaker_engine
     from presto_tpu.scan import metrics as _scan_metrics
 
     override = getattr(ctx.config, "breaker_engine", "auto")
@@ -1941,8 +1940,6 @@ def _breaker_engine_choice(node: PlanNode, ctx: "ExecContext",
     try:
         engine, why = choose_breaker_engine(node, ctx.catalog, override,
                                             hbo=hbo)
-    except HashEngineUnavailable:
-        raise
     except Exception:
         engine, why = "sort", "stats derivation failed"
     node.__dict__["_breaker_engine"] = engine
@@ -5030,13 +5027,10 @@ class _MultiwayProber:
             unique = bool(node.build_unique[i])
             hash_engine = False
             if not unique:
-                from presto_tpu.plan.stats import (
-                    HashEngineUnavailable, choose_breaker_engine)
+                from presto_tpu.plan.stats import choose_breaker_engine
                 try:
                     eng, _ = choose_breaker_engine(
                         shims[i], ctx.catalog, override, hbo=hbo)
-                except HashEngineUnavailable:
-                    raise
                 except Exception:
                     eng = "sort"
                 hash_engine = eng == "hash"
@@ -6351,10 +6345,10 @@ def install_plan_programs(root: PlanNode, ctx: ExecContext) -> None:
     precompilation so scan-chain compiles overlap host decode. Call after
     every structure-mutating pass (subquery binding, colocation tagging,
     fragment decode)."""
-    if getattr(ctx.config, "breaker_engine", "auto") == "hash":
-        from presto_tpu.plan.stats import require_hash_engine
+    from presto_tpu.plan.stats import require_hash_engine
 
-        require_hash_engine()  # loud, at install — not a quiet sort later
+    # loud, at install — never a quiet sort later
+    require_hash_engine(getattr(ctx.config, "breaker_engine", "auto"))
     _programs.install_plan(root, ctx.config)
     if getattr(ctx.config, "devprof", "off") == "on":
         from presto_tpu.obs import devprof as _devprof

@@ -327,16 +327,13 @@ class MeshExecutor:
         override, else the CBO thresholds. Stamped on the node (EXPLAIN)
         and counted on the shared engine-dispatch families. Runs at trace
         time, so a cached mesh program keeps its engine choice."""
-        from presto_tpu.plan.stats import (HashEngineUnavailable,
-                                           choose_breaker_engine)
+        from presto_tpu.plan.stats import choose_breaker_engine
 
         override = getattr(self.config, "breaker_engine", "auto")
         hbo = getattr(self.config, "hbo", "observe")
         try:
             engine, why = choose_breaker_engine(node, self.catalog, override,
                                                 hbo=hbo)
-        except HashEngineUnavailable:
-            raise
         except Exception:
             engine, why = "sort", "stats derivation failed"
         node.__dict__["_breaker_engine"] = engine
@@ -846,6 +843,9 @@ class MeshExecutor:
         ONLY the sites that overflowed. Boosts are local to this call —
         an overflow on one query must not permanently inflate every later
         query's capacities (the old executor-level _cap_boost did)."""
+        from presto_tpu.plan.stats import require_hash_engine
+
+        require_hash_engine(getattr(self.config, "breaker_engine", "auto"))
         boosts: Dict[int, int] = {}
         lane_overrides: Dict[int, int] = {}
         adaptive_state = None

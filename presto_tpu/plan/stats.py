@@ -436,10 +436,11 @@ def _hash_refusal() -> Optional[str]:
     return tpu_refusal()
 
 
-def require_hash_engine() -> None:
-    """The loud half of the gate: a forced ``hash`` on a TPU backend
+def require_hash_engine(override: str) -> None:
+    """The loud half of the gate, called where a plan starts to run
+    (plan install, the mesh executor): a forced ``hash`` on a TPU backend
     raises, naming the kernels and the compiler's refusal."""
-    refusal = _hash_refusal()
+    refusal = _hash_refusal() if override == "hash" else None
     if refusal:
         raise HashEngineUnavailable(
             f"breaker_engine=hash is not selectable on this device: "
@@ -460,12 +461,12 @@ def choose_breaker_engine(node: PlanNode, catalog,
     """(engine, why) for a pipeline breaker: ``engine`` ∈ {sort, hash}.
 
     ``override`` is the ``breaker_engine`` session property: ``sort`` /
-    ``hash`` force the engine (``hash`` raises HashEngineUnavailable on
-    a TPU backend); ``auto`` asks the stats below, then the platform."""
+    ``hash`` force the engine (a forced ``hash`` on a TPU backend never
+    gets this far: plan install raises, ``require_hash_engine``);
+    ``auto`` asks the stats below, then the platform."""
     if override == "sort":
         return "sort", "session breaker_engine=sort"
     if override == "hash":
-        require_hash_engine()
         return "hash", "session breaker_engine=hash"
     return _sort_where_hash_is_refused(
         *_engine_from_stats(node, catalog, hbo))

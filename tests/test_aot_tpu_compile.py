@@ -220,9 +220,14 @@ def test_hash_engine_is_a_loud_error_on_a_tpu_backend(monkeypatch):
     assert not pallas_hash.use_interpret()
     plan = LocalRunner(cat, ExecConfig()).explain(sql)
     assert "[engine=sort: hash engine not selectable on tpu" in plan
-    assert stats.choose_breaker_engine_observed(
-        LocalRunner(cat, ExecConfig()).plan(sql).root.child, 8.0,
-        1e6)[0] in ("sort",)
+    agg = LocalRunner(cat, ExecConfig()).plan(sql).root.child
+    assert stats.choose_breaker_engine_observed(agg, 8.0, 1e6)[0] == "sort"
     with pytest.raises(stats.HashEngineUnavailable,
                        match="group_insert.*32-bit element types"):
         LocalRunner(cat, ExecConfig(breaker_engine="hash")).run(sql)
+    with pytest.raises(stats.HashEngineUnavailable):
+        from presto_tpu.parallel.mesh import make_mesh
+        from presto_tpu.parallel.mesh_exec import MeshExecutor
+
+        MeshExecutor(cat, make_mesh(2),
+                     ExecConfig(breaker_engine="hash")).run(sql)
