@@ -167,3 +167,75 @@ def test_histogram_selectivity_handles_skew():
     # uniform model would say ~1% — histogram must land near 95%
     assert abs(sel - true_frac) < 0.1
     assert sel > 0.5
+
+
+# ---------------------------------------------------------------------------
+# breaker engine × platform: the hash engine's kernels do not compile for the
+# TPU (ops/pallas_hash.TPU_REFUSAL), so the verdict depends on the backend
+# the process observes. The backend is steered here, in the test.
+
+_Q1_SHAPE = ("select l_returnflag, l_linestatus, sum(l_quantity) as s "
+             "from lineitem group by l_returnflag, l_linestatus")
+
+
+def _agg_node(tpch):
+    from presto_tpu.plan.nodes import Aggregate
+
+    node = LocalRunner(tpch, ExecConfig()).plan(_Q1_SHAPE).root
+    while not isinstance(node, Aggregate):
+        node = node.children()[0]
+    return node
+
+
+@pytest.mark.parametrize("backend,override,engine,why", [
+    ("cpu", "auto", "hash", "est "),
+    ("cpu", "hash", "hash", "session breaker_engine=hash"),
+    ("cpu", "sort", "sort", "session breaker_engine=sort"),
+    ("tpu", "auto", "sort", "hash engine not selectable on tpu"),
+    ("tpu", "sort", "sort", "session breaker_engine=sort"),
+])
+def test_breaker_engine_follows_the_platform(tpch, monkeypatch, backend,
+                                             override, engine, why):
+    import jax
+
+    from presto_tpu.plan.stats import choose_breaker_engine
+
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    got, got_why = choose_breaker_engine(_agg_node(tpch), tpch, override)
+    assert got == engine
+    assert why in got_why
+    if backend == "tpu" and override == "auto":
+        # the stats verdict it replaced stays readable in EXPLAIN
+        assert "stats said est" in got_why
+
+
+@pytest.mark.parametrize("backend,expect", [("cpu", "hash"), ("tpu", "sort")])
+def test_observed_engine_verdict_follows_the_platform(tpch, monkeypatch,
+                                                      backend, expect):
+    import jax
+
+    from presto_tpu.plan.stats import choose_breaker_engine_observed
+
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    got, why = choose_breaker_engine_observed(_agg_node(tpch), 6.0, 6e6)
+    assert got == expect
+    assert "(adaptive: observed)" in why
+
+
+@pytest.mark.parametrize("backend,override,raises", [
+    ("cpu", "hash", False), ("tpu", "auto", False), ("tpu", "sort", False),
+    ("tpu", "hash", True),
+])
+def test_forced_hash_engine_is_refused_only_on_tpu(monkeypatch, backend,
+                                                   override, raises):
+    import jax
+
+    from presto_tpu.plan.stats import (HashEngineUnavailable,
+                                       require_hash_engine)
+
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    if raises:
+        with pytest.raises(HashEngineUnavailable, match="join_insert"):
+            require_hash_engine(override)
+    else:
+        require_hash_engine(override)

@@ -357,7 +357,7 @@ class TestShippedTree:
 
     def test_cli_json_schema(self, tmp_path, capsys):
         # exposition-style contract for CI consumers: the --json document
-        # is {findings: [{rule, loc, message, plane}], count, planes}
+        # is {findings: [{rule, loc, message, plane}], count, planes, timings}
         from presto_tpu.analysis.__main__ import main
 
         bad = tmp_path / "bad_mod.py"
@@ -372,7 +372,7 @@ class TestShippedTree:
         rc = main(["--no-lint", "--concurrency", "--json", str(bad)])
         doc = json.loads(capsys.readouterr().out)
         assert rc == 1
-        assert set(doc) == {"findings", "count", "planes"}
+        assert set(doc) == {"findings", "count", "planes", "timings"}
         assert doc["count"] == len(doc["findings"]) >= 1
         assert any("concurrency" in p for p in doc["planes"])
         for f in doc["findings"]:
