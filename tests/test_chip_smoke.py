@@ -103,5 +103,5 @@ def test_last_line_of_a_rehearsal_is_the_device_object_and_never_ok(capfd):
     assert set(last) == {"ok", "device"} and last["ok"] is False
     assert set(last["device"]) == {"platform", "kind", "count"}
     phases = [json.loads(ln).get("phase") for ln in lines[:-1]]
-    assert phases == ["setup", "kernel", "query", "query", "query", "total"]
-    assert all(json.loads(ln)["correct"] for ln in lines[2:5])
+    assert phases == ["setup", "query", "query", "query", "total"]
+    assert all(json.loads(ln)["correct"] for ln in lines[1:4])
