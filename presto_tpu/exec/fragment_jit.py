@@ -47,6 +47,7 @@ import jax
 import jax.numpy as jnp
 
 from presto_tpu.batch import Batch
+from presto_tpu.obs import trace as _obs_trace
 
 
 class Window:
@@ -139,8 +140,9 @@ def _flush(pending: List[Batch], bucket_width: int = 0) -> WindowItem:
         return pending[0]
     # host-side stacking decision: bucket_width is a plain Python int
     width = max(_pow2_at_least(k), int(bucket_width))  # lint: allow(host-sync)
-    padded = pending + [dead_like(pending[-1])] * (width - k)
-    w = Window(stack_batches(padded), k, width, pending[0])
+    with _obs_trace.current().phase("window_stack", items=k):
+        padded = pending + [dead_like(pending[-1])] * (width - k)
+        w = Window(stack_batches(padded), k, width, pending[0])
     from presto_tpu.obs import devprof as _devprof
 
     if _devprof.active():
@@ -185,11 +187,17 @@ class WindowSource:
         self._stop = threading.Event()
         self._exc: Optional[BaseException] = None
         self._pending: List[Batch] = []
+        # the producer thread records into its creator's tracer
+        self._tracer = _obs_trace.current()
         self._thread = threading.Thread(
             target=self._produce, name="fragment-window-producer", daemon=True)
         self._thread.start()
 
     def _produce(self):
+        with _obs_trace.use(self._tracer):
+            self._produce_windows()
+
+    def _produce_windows(self):
         pending = self._pending
         key = None
         bw = self._bucket_w
@@ -224,22 +232,25 @@ class WindowSource:
             except Exception:
                 # telemetry must never kill the producer thread
                 pass
-        while True:
-            stopped = self._stop.is_set()
-            if stopped and not force:
-                return False
-            try:
-                self._q.put(item, timeout=0.05)
-                return True
-            except queue.Full:
-                if stopped and force:
-                    # nobody will consume after a stop — drop the sentinel
-                    # rather than spin against a full queue under join()
+        with self._tracer.phase("window_queue_full", wait=True):
+            while True:
+                stopped = self._stop.is_set()
+                if stopped and not force:
                     return False
+                try:
+                    self._q.put(item, timeout=0.05)
+                    return True
+                except queue.Full:
+                    if stopped and force:
+                        # nobody will consume after a stop — drop the
+                        # sentinel rather than spin against a full queue
+                        # under join()
+                        return False
 
     def __iter__(self) -> Iterator[WindowItem]:
         while True:
-            item = self._q.get()
+            with self._tracer.phase("window_wait", wait=True):
+                item = self._q.get()
             if item is _SENTINEL:
                 if self._exc is not None:
                     exc, self._exc = self._exc, None

@@ -602,6 +602,7 @@ def wrap(entry: ProgramEntry, node_stats: Dict[str, float],
     from presto_tpu.obs import trace as _obs_trace
 
     jfn = entry.jfn
+    phase_name = "program_call:" + node_kind
 
     def wrapped(*args, **kw):
         ev = entry.ready
@@ -610,6 +611,7 @@ def wrap(entry: ProgramEntry, node_stats: Dict[str, float],
             # paying a duplicate trace for a program that is about to
             # land deserialized (bounded — restore never blocks forever)
             ev.wait(30.0)
+        tr = _obs_trace.current()
         r = entry.restored
         if r:
             fn = r.get(_avals_key(args, kw))
@@ -622,14 +624,16 @@ def wrap(entry: ProgramEntry, node_stats: Dict[str, float],
                                  if i not in nums) if nums else args)
                     dkw = ({k: v for k, v in kw.items()
                             if k not in names} if names else kw)
-                    return fn(*dyn, **dkw)
+                    with tr.phase(phase_name):
+                        return fn(*dyn, **dkw)
                 except Exception:
                     pass  # shape/layout drift: fall through to jfn
         try:
-            t0 = time.perf_counter()
             w0 = time.time()
-            out = jfn(*args, **kw)
-            dt = time.perf_counter() - t0
+            with tr.phase(phase_name):
+                t0 = time.perf_counter()
+                out = jfn(*args, **kw)
+                dt = time.perf_counter() - t0
             cur = jfn._cache_size()
         except AttributeError:
             return jfn(*args, **kw)
@@ -657,7 +661,6 @@ def wrap(entry: ProgramEntry, node_stats: Dict[str, float],
         if delta > 0:
             record_compiles(delta, dt)
             _persist_program(entry, args, kw)
-            tr = _obs_trace.current()
             if tr.enabled:
                 tr.record("compile", "compile", w0, w0 + dt,
                           node=node_kind, key=key)

@@ -135,9 +135,9 @@ class ExecConfig:
     # EXPLAIN ANALYZE: per-operator wall/rows/batches accounting (forces a
     # device sync per batch — off in production, like Presto's verbose stats)
     collect_stats: bool = False
-    # query-lifecycle span tracing (obs/trace.py): operator, compile,
-    # host_decode, device_transfer, exchange_wait spans. Cheap enough to
-    # stay on (no per-batch device sync); False makes every span site a
+    # query-lifecycle span tracing (obs/trace.py): operator, compile and
+    # exchange_wait spans, and the per-thread engine phases. Cheap enough
+    # to stay on (no per-batch device sync); False makes every span site a
     # single attribute check on the NOOP tracer
     tracing: bool = True
     # memory + spill (reference: MemoryPool / spiller; None = unlimited)
@@ -536,6 +536,7 @@ def collapse_chain(node: PlanNode) -> Tuple[PlanNode, Callable[[Batch], Batch]]:
     else:
         steps.reverse()
 
+        @jax.named_scope("scan_chain")
         def chain(b: Batch) -> Batch:
             for s in steps:
                 b = s(b)
@@ -925,32 +926,12 @@ def _scan_batches(scan: TableScan, ctx: ExecContext) -> Iterator[Batch]:
                 return conn.read_split_selective(
                     split, columns, _f, capacity=capacity, adaptive=_a,
                     counters=_count)
-    if ctx.tracer.enabled:
-        # host_decode / device_transfer sub-spans per split. The parent is
-        # captured HERE (the consumer thread, under the task/query span) —
-        # the prefetch producer thread has no span stack of its own.
-        _tracer = ctx.tracer
-        _scan_parent = _tracer.current_parent()
-        _inner_read = read_split
-
-        def read_split(split, columns, capacity=None,  # noqa: E306
-                       _rs=_inner_read):
-            w0 = time.time()
-            b = _rs(split, columns, capacity=capacity)
-            w1 = time.time()
-            _tracer.record("host_decode", "host_decode", w0, w1,
-                           parent_id=_scan_parent, table=scan.table)
-            # upload dispatch only — never block on device readiness here:
-            # a sync per split would serialize the prefetch pipeline the
-            # engine is built around (collect_stats is the opt-in sync path)
-            _tracer.record("device_transfer", "device_transfer", w1,
-                           time.time(), parent_id=_scan_parent,
-                           table=scan.table)
-            return b
+    tracer = ctx.tracer
     depth = ctx.config.scan_prefetch
     if depth <= 0 or len(splits) <= 1:
         for split in splits:
-            b = read_split(split, columns, capacity=cap)
+            with tracer.phase("scan_read"):
+                b = read_split(split, columns, capacity=cap)
             yield b.rename(symbols)
         return
     # pipelined scan: a host thread decodes/stages splits ahead of the
@@ -966,11 +947,14 @@ def _scan_batches(scan: TableScan, ctx: ExecContext) -> Iterator[Batch]:
         try:
             # the producer thread carries the query's tracer so span sites
             # below the connector (selective cascade) keep recording
-            with _obs_trace.use(ctx.tracer):
+            with _obs_trace.use(tracer):
                 for split in splits:
                     if stop.is_set():
                         break
-                    q.put(read_split(split, columns, capacity=cap))
+                    with tracer.phase("scan_read"):
+                        b = read_split(split, columns, capacity=cap)
+                    with tracer.phase("scan_queue_full", wait=True):
+                        q.put(b)
             q.put(_SENTINEL)
         except BaseException as e:  # surface read errors on the consumer
             q.put(e)
@@ -980,7 +964,8 @@ def _scan_batches(scan: TableScan, ctx: ExecContext) -> Iterator[Batch]:
     t.start()
     try:
         while True:
-            item = q.get()
+            with tracer.phase("scan_wait", wait=True):
+                item = q.get()
             if item is _SENTINEL:
                 return
             if isinstance(item, BaseException):
@@ -2030,6 +2015,7 @@ def _agg_steps(node: Aggregate, engine: str = "sort") -> SimpleNamespace:
             states.append(StateCol(c.values, c.validity, op))
         return keys, states
 
+    @jax.named_scope("breaker_step")
     def merge_step(acc: Optional[Batch], b: Batch, cap: int,
                    prechained: bool = False):
         if not prechained:
@@ -3292,10 +3278,11 @@ def _execute_aggregate(node: Aggregate, ctx: ExecContext) -> Iterator[Batch]:
                         raise RuntimeError(
                             "aggregate capacity growth exceeded retries")
 
-            def confirm(block):
+            def confirm(block, site="agg_confirm"):
                 nonlocal cap
                 while window and (block or len(window) > depth):
-                    ngi = int(window[0][2])  # usually already on host
+                    with ctx.tracer.phase("host_sync:" + site):
+                        ngi = int(window[0][2])  # usually already on host
                     dcap = window[0][3]  # capacity the entry merged at
                     if ngi <= dcap:
                         hbo_obs["groups"] = max(hbo_obs["groups"], ngi)
@@ -3342,7 +3329,7 @@ def _execute_aggregate(node: Aggregate, ctx: ExecContext) -> Iterator[Batch]:
                         _note_spill_revoke(node, ctx, freed)
                 else:
                     mctx.set_bytes(out_bytes)
-            confirm(block=True)
+            confirm(block=True, site="breaker_finish")
 
         def grace_ingest(stream):
             """Hash-partition chained input batches straight to spill (the
@@ -3396,7 +3383,6 @@ def _execute_aggregate(node: Aggregate, ctx: ExecContext) -> Iterator[Batch]:
 
             def dispatch(item):
                 acc_before = state["acc"]
-                t0 = time.time()
                 out, ng = apply(acc_before, item, cap)
                 state["acc"] = out
                 fused = isinstance(item, _fragment_jit.Window)
@@ -3404,10 +3390,6 @@ def _execute_aggregate(node: Aggregate, ctx: ExecContext) -> Iterator[Batch]:
                                        else item.capacity)
                 _record_fragment_dispatch(node, ctx, fused,
                                           item.k if fused else 1)
-                if fused and ctx.tracer.enabled:
-                    ctx.tracer.record("fragment_step", "fragment_step", t0,
-                                      time.time(), batches=item.k,
-                                      width=item.width)
                 if no_overflow:
                     return
                 try:
@@ -3452,10 +3434,11 @@ def _execute_aggregate(node: Aggregate, ctx: ExecContext) -> Iterator[Batch]:
                         raise RuntimeError(
                             "aggregate capacity growth exceeded retries")
 
-            def confirm(block):
+            def confirm(block, site="agg_confirm"):
                 nonlocal cap
                 while window and (block or len(window) > depth):
-                    ngi = int(window[0][2])
+                    with ctx.tracer.phase("host_sync:" + site):
+                        ngi = int(window[0][2])
                     dcap = window[0][3]
                     if ngi <= dcap:
                         hbo_obs["groups"] = max(hbo_obs["groups"], ngi)
@@ -3501,7 +3484,7 @@ def _execute_aggregate(node: Aggregate, ctx: ExecContext) -> Iterator[Batch]:
                             _note_spill_revoke(node, ctx, freed)
                     else:
                         mctx.set_bytes(out_bytes)
-                confirm(block=True)
+                confirm(block=True, site="breaker_finish")
             except _GraceOverflow as ov:
                 # recover everything the producer pulled but never delivered
                 # so the grace handler spills the COMPLETE remaining input
@@ -6000,6 +5983,7 @@ def _topn_step(node: Sort) -> Callable:
     chain = chain0 or (lambda b: b)
     cap = round_up_capacity(node.limit)
 
+    @jax.named_scope("topn")
     def topn_step(acc: Optional[Batch], b: Batch):
         b = chain(b)
         if acc is not None:
@@ -6047,15 +6031,9 @@ def _execute_sort(node: Sort, ctx: ExecContext) -> Iterator[Batch]:
             try:
                 for item in src:
                     if isinstance(item, _fragment_jit.Window):
-                        t0 = time.time()
                         acc = (jfstep0(item.stacked) if acc is None
                                else jfstep(acc, item.stacked))
                         _record_fragment_dispatch(node, ctx, True, item.k)
-                        if ctx.tracer.enabled:
-                            ctx.tracer.record(
-                                "fragment_step", "fragment_step", t0,
-                                time.time(), batches=item.k,
-                                width=item.width)
                     else:
                         acc = jstep(acc, item)
                         _record_fragment_dispatch(node, ctx, False)

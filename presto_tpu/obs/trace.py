@@ -14,8 +14,9 @@ Span kinds:
   task             one worker task execution
   operator         one plan node's aggregate batch-production wall
   compile          one XLA compile event inside a jitted program
-  host_decode      one split's host-side decode (incl. selective cascade)
-  device_transfer  host→device upload + readiness of one split's batch
+  phase            one engine phase of one thread role, aggregated over
+                   the trace: attrs carry role, n, busy_s, self_s, max_s
+                   (and items, wait) — see "Engine phases" below
   exchange_wait    time a consumer spent blocked on a pull exchange; on
                    the mesh path, one per fused-collective exchange site
                    with lane occupancy attrs (fid/bytes/lanes_used/util)
@@ -40,6 +41,25 @@ Span kinds:
                    boundary (obs/devprof.py; attrs carry bytes_in_use /
                    peak or an honest available=false reason on CPU)
 
+Engine phases. What a thread does per batch or per window (read a split,
+stack a window, call a compiled program, read a scalar back from the
+device, wait on a queue) is bracketed with `tracer.phase(name)`. A phase
+makes no `Span` per occurrence: it adds to an aggregate keyed by (thread
+role, name) — n, busy_s, self_s (busy less the phases opened inside it on
+the same thread), max_s, first start, last end — and, unless `wait=True`,
+enters `jax.profiler.TraceAnnotation("engine:" + name)`, so that under an
+open profiler session the phase is an event on that thread's line of the
+xplane, on the profiler's clock (with no session open the annotation is a
+no-op inside jax). Wait phases stay out of the xplane: a thread blocked on
+a queue is not what the host was doing. The thread role is the thread's
+name with the task id cut off (`task`, `scan-prefetch`,
+`fragment-window-producer`); the thread that opens a trace's `query` span
+is `coordinator`. A dump carries each aggregate as one span of kind
+`phase`; when the `query` span closes, the phases (the absorbed tasks'
+too), the task and query walls and the span count are folded into one
+small summary per statement, kept for the last 1,024 statements of the
+process and read with `summaries()` — after the cluster is gone too.
+
 Everything is allocation-light: tracing disabled means every call site
 talks to the module NOOP singleton (`enabled=False` short-circuits before
 any work), so `ExecConfig.tracing=False` costs one attribute check.
@@ -61,8 +81,10 @@ import itertools
 import os
 import threading
 import time
-from collections import OrderedDict
+from collections import OrderedDict, deque
 from typing import Dict, List, Optional, Tuple
+
+from jax.profiler import TraceAnnotation as _Annotation
 
 # header carried on every coordinator↔worker HTTP call; value is
 # "{trace_id}:{parent_span_id}" (parent = the coordinator's root span)
@@ -135,6 +157,85 @@ class _NoopSpan:
 _NOOP_SPAN = _NoopSpan()
 
 
+def _thread_role(name: str) -> str:
+    """`task-q7.0.0` -> `task`, `scan-prefetch` -> `scan-prefetch`: the
+    leading all-letter parts of a thread's name, so the ids go."""
+    keep = []
+    for part in name.split("-"):
+        if not part.isalpha():
+            break
+        keep.append(part)
+    return "-".join(keep) or name
+
+
+class _ThreadPhases:
+    """One thread's phase aggregates within one tracer. Written by that
+    thread alone, so the hot path takes no lock."""
+
+    __slots__ = ("role", "aggs", "child_s")
+
+    def __init__(self, role: str):
+        self.role = role
+        # name -> [n, busy_s, self_s, max_s, first_start, last_end, items,
+        # wait]
+        self.aggs: Dict[str, list] = {}
+        self.child_s = 0.0  # busy_s of the phases closed inside the open one
+
+
+class _Phase:
+    """Context for one occurrence of a phase; see Tracer.phase()."""
+
+    __slots__ = ("_st", "_name", "_ann", "_items", "_t0", "_outer_child_s")
+
+    def __init__(self, st: _ThreadPhases, name: str, ann, items: int):
+        self._st = st
+        self._name = name
+        self._ann = ann
+        self._items = items
+
+    def __enter__(self):
+        st = self._st
+        self._outer_child_s = st.child_s
+        st.child_s = 0.0
+        if self._ann is not None:
+            self._ann.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        t1 = time.perf_counter()
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+        st = self._st
+        dt = t1 - self._t0
+        a = st.aggs.get(self._name)
+        if a is None:
+            a = st.aggs[self._name] = [0, 0.0, 0.0, 0.0, self._t0, t1, 0,
+                                       self._ann is None]
+        a[0] += 1
+        a[1] += dt
+        a[2] += dt - st.child_s
+        if dt > a[3]:
+            a[3] = dt
+        a[5] = t1
+        a[6] += self._items
+        st.child_s = self._outer_child_s + dt
+        return False
+
+
+class _NoopPhase:
+    __slots__ = ()
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+
+_NOOP_PHASE = _NoopPhase()
+
+
 class Tracer:
     """Thread-safe span sink for one trace. A per-thread span stack gives
     `span()` contexts their default parent; threads that never opened a
@@ -150,6 +251,26 @@ class Tracer:
         self._spans: List[Span] = []
         self._lock = threading.Lock()
         self._tls = threading.local()
+        # every thread's phase aggregates, registered once per thread
+        self._phase_threads: List[_ThreadPhases] = []
+        self._phase_ids: Dict[Tuple[str, str], str] = {}
+
+    def _phases_here(self) -> _ThreadPhases:
+        st = getattr(self._tls, "phases", None)
+        if st is None:
+            st = self._tls.phases = _ThreadPhases(
+                _thread_role(threading.current_thread().name))
+            with self._lock:
+                self._phase_threads.append(st)
+        return st
+
+    def phase(self, name: str, wait: bool = False, items: int = 0) -> _Phase:
+        """`with tracer.phase(name):` round one occurrence of an engine
+        phase on this thread (module docstring). `wait=True` marks time
+        spent blocked: aggregated, never annotated. `items` counts what
+        the occurrence handled (batches stacked)."""
+        return _Phase(self._phases_here(), name,
+                      None if wait else _Annotation("engine:" + name), items)
 
     def _stack(self) -> list:
         st = getattr(self._tls, "stack", None)
@@ -168,8 +289,11 @@ class Tracer:
         st = self._stack()
         pid = parent_id if parent_id is not None else (
             st[-1] if st else self.root_id)
-        if self.root_id is None:
+        root = self.root_id is None
+        if root:
             self.root_id = sid
+            if kind == "query":
+                self._phases_here().role = "coordinator"
         sp = Span(sid, pid, name, kind, time.time(), None, attrs or None)
         st.append(sid)
         try:
@@ -178,6 +302,8 @@ class Tracer:
             st.pop()
             sp.end = time.time()
             self._add(sp)
+            if root and kind == "query":
+                _keep_summary(self.summary())
 
     def record(self, name: str, kind: str, start: float, end: float,
                parent_id: Optional[str] = None, **attrs) -> Span:
@@ -212,8 +338,65 @@ class Tracer:
                            d.get("attrs")))
 
     def spans(self) -> List[Span]:
+        """Recorded and absorbed spans, then this tracer's own phase
+        aggregates as one `phase` span each (same ids on every call)."""
         with self._lock:
-            return list(self._spans)
+            out = list(self._spans)
+            threads = list(self._phase_threads)
+        merged: Dict[Tuple[str, str], list] = {}
+        for st in threads:
+            for name, a in list(st.aggs.items()):
+                m = merged.get((st.role, name))
+                if m is None:
+                    merged[(st.role, name)] = list(a)
+                else:
+                    m[0] += a[0]
+                    m[1] += a[1]
+                    m[2] += a[2]
+                    m[3] = max(m[3], a[3])
+                    m[4] = min(m[4], a[4])
+                    m[5] = max(m[5], a[5])
+                    m[6] += a[6]
+        with self._lock:
+            for key in merged:
+                if key not in self._phase_ids:
+                    self._phase_ids[key] = _new_span_id()
+        # spans are on time.time(); phases take perf_counter() and are moved
+        # onto the spans' clock as they leave
+        shift = time.time() - time.perf_counter()
+        for key in sorted(merged):
+            n, busy, self_s, max_s, first, last, items, wait = merged[key]
+            attrs = {"role": key[0], "n": n, "busy_s": round(busy, 6),
+                     "self_s": round(self_s, 6), "max_s": round(max_s, 6)}
+            if items:
+                attrs["items"] = items
+            if wait:
+                attrs["wait"] = True
+            out.append(Span(self._phase_ids[key], self.root_id, key[1], "phase",
+                            first + shift, last + shift, attrs))
+        return out
+
+    def summary(self) -> dict:
+        """What one statement's trace comes to: its phases by thread role
+        (the absorbed tasks' included), the query and task walls, the
+        coordinator's wait on the root stream, and what the tracer itself
+        made. Small enough to keep after the trace is gone."""
+        spans = self.spans()
+        root = next((s for s in spans if s.span_id == self.root_id), None)
+        tasks = [s.duration_s for s in spans if s.kind == "task"]
+        return {
+            "queryId": self.trace_id,
+            "wall_s": round(root.duration_s, 6) if root is not None else None,
+            "tasks": len(tasks),
+            "task_wall_s": round(sum(tasks), 6),
+            "exchange_wait_s": round(sum(
+                float((s.attrs or {}).get("wait_s") or 0.0) for s in spans
+                if s.kind == "exchange_wait"
+                and s.parent_id == self.root_id), 6),
+            "spans": len(spans),
+            "dropped": self.dropped,
+            "phases": phases_by_role(spans),
+        }
 
     def token(self, parent_id: Optional[str] = None) -> str:
         return format_token(self.trace_id,
@@ -228,6 +411,7 @@ class Tracer:
             "dropped": self.dropped,
             "spans": [s.to_dict() for s in spans],
             "tree": build_tree(spans),
+            "phases": phases_by_role(spans),
         }
 
 
@@ -247,6 +431,9 @@ class NoopTracer:
     def record(self, name, kind, start, end, parent_id=None, **attrs):
         return _NOOP_SPAN
 
+    def phase(self, name, wait=False, items=0):
+        return _NOOP_PHASE
+
     def absorb(self, span_dicts, parent_map=None):
         pass
 
@@ -261,7 +448,7 @@ class NoopTracer:
 
     def to_json(self):
         return {"traceId": "", "rootSpanId": None, "dropped": 0,
-                "spans": [], "tree": []}
+                "spans": [], "tree": [], "phases": {}}
 
 
 NOOP = NoopTracer()
@@ -288,6 +475,46 @@ def use(tracer):
         yield tracer
     finally:
         _current.tracer = prev
+
+
+def phases_by_role(spans: List[Span]) -> Dict[str, Dict[str, dict]]:
+    """{role: {phase: {n, busy_s, self_s, max_s[, items][, wait]}}} over the
+    `phase` spans of a trace; the same phase from several tasks adds up."""
+    out: Dict[str, Dict[str, dict]] = {}
+    for s in spans:
+        if s.kind != "phase" or not s.attrs:
+            continue
+        a = s.attrs
+        d = out.setdefault(a.get("role") or "?", {}).setdefault(
+            s.name, {"n": 0, "busy_s": 0.0, "self_s": 0.0, "max_s": 0.0})
+        d["n"] += int(a.get("n") or 0)
+        d["busy_s"] = round(d["busy_s"] + float(a.get("busy_s") or 0.0), 6)
+        d["self_s"] = round(d["self_s"] + float(a.get("self_s") or 0.0), 6)
+        d["max_s"] = max(d["max_s"], float(a.get("max_s") or 0.0))
+        if a.get("items"):
+            d["items"] = d.get("items", 0) + int(a["items"])
+        if a.get("wait"):
+            d["wait"] = True
+    return out
+
+
+# per-statement summaries of the last statements traced in this process:
+# what the benchmark reads after the cluster is closed (the counterpart of
+# exec/programs.snapshot())
+_summaries: "deque[dict]" = deque(maxlen=1024)  # shared: guarded-by(_summaries_lock)
+_summaries_lock = threading.Lock()
+
+
+def _keep_summary(doc: dict) -> None:
+    with _summaries_lock:
+        _summaries.append(doc)
+
+
+def summaries() -> List[dict]:
+    """Tracer.summary() of the last 1,024 statements whose `query` span
+    closed in this process, oldest first."""
+    with _summaries_lock:
+        return list(_summaries)
 
 
 def build_tree(spans: List[Span]) -> List[dict]:

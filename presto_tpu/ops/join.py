@@ -82,6 +82,7 @@ def align_probe_strings(
     return out
 
 
+@jax.named_scope("join_build")
 def build_side(batch: Batch, key_names: Sequence[str]) -> BuildTable:
     """Sort the (concatenated, still masked) build input by key hash; dead
     rows sink to the end via a sentinel hash."""
@@ -127,6 +128,7 @@ def _keys_equal(table: BuildTable, build_idx, probe: Batch,
     return ok
 
 
+@jax.named_scope("join_probe")
 def probe_unique(
     table: BuildTable,
     probe: Batch,
@@ -162,6 +164,7 @@ def probe_unique(
     return idx, matched & live
 
 
+@jax.named_scope("join_probe")
 def probe_counts(
     table: BuildTable,
     probe: Batch,
@@ -204,6 +207,7 @@ def probe_counts(
     return lo.astype(jnp.int32), counts, offsets, total, live, overflow
 
 
+@jax.named_scope("join_probe")
 def probe_expand(
     table: BuildTable,
     probe: Batch,
@@ -306,6 +310,7 @@ def _encode_join_planes(batch: Batch, key_names: Sequence[str],
     return jnp.stack(planes), live, live & matchable
 
 
+@jax.named_scope("join_build")
 def hash_build_side(batch: Batch, key_names: Sequence[str],
                     probe_dtypes: Sequence) -> HashJoinTable:
     """Build-side insert on the Pallas linear-probing kernel. The table
@@ -337,6 +342,7 @@ def _hash_probe(table: HashJoinTable, probe: Batch,
     return mm, cnt, ovf, live
 
 
+@jax.named_scope("join_probe")
 def hash_probe_unique(table: HashJoinTable, probe: Batch,
                       probe_keys: Sequence[str], compare_dtypes: Sequence):
     """Unique-build fast path: first (only) match per probe row.
@@ -347,6 +353,7 @@ def hash_probe_unique(table: HashJoinTable, probe: Batch,
     return idx, cnt > 0
 
 
+@jax.named_scope("join_probe")
 def hash_probe_counts(table: HashJoinTable, probe: Batch,
                       probe_keys: Sequence[str], compare_dtypes: Sequence,
                       max_fanout_scan: int = 8):
@@ -365,6 +372,7 @@ def hash_probe_counts(table: HashJoinTable, probe: Batch,
     return mm, counts, offsets, total, live, ovf.astype(jnp.int64)
 
 
+@jax.named_scope("join_probe")
 def hash_probe_expand(table: HashJoinTable, mm: jnp.ndarray,
                       counts: jnp.ndarray, offsets: jnp.ndarray,
                       chunk_base, out_capacity: int):
@@ -453,6 +461,7 @@ def _mw_unique_state(specs, state):
     return idxs, matcheds
 
 
+@jax.named_scope("join_probe")
 def multiway_counts(tables, probe: Batch, specs, fanouts):
     """Pass 1 of the N-ary probe: per-leg match state, per-leg effective
     counts (left legs floor at 1 — the null-extension row), the combined
@@ -499,6 +508,7 @@ def multiway_counts(tables, probe: Batch, specs, fanouts):
             jnp.stack(ovfs))
 
 
+@jax.named_scope("join_probe")
 def multiway_expand(tables, probe: Batch, specs, state, chats, offsets,
                     T, chunk_base, out_capacity: int, probe_cols,
                     build_cols):
@@ -580,6 +590,7 @@ def multiway_expand(tables, probe: Batch, specs, state, chats, offsets,
     return Batch(names, types, cols, out_live, dicts)
 
 
+@jax.named_scope("join_probe")
 def multiway_probe_unique(tables, probe: Batch, specs, probe_cols,
                           build_cols):
     """All-unique fast path — the dominant star-schema shape: every leg

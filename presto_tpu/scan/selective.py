@@ -54,30 +54,25 @@ def selective_read(
 
     from presto_tpu.obs import trace as _obs_trace
 
-    tracer = _obs_trace.current()
-    cascade_w0 = time.time() if tracer.enabled else 0.0
     filter_cols = list(filters)
     order = adaptive.order(filter_cols) if adaptive is not None else filter_cols
-    decoded_f, n = decode(tuple(filter_cols))
-    sel = np.arange(n)
-    for col in order:
-        if not len(sel):
-            break
-        arr, valid, _ = decoded_f[col]
-        t0 = time.perf_counter()
-        mask = filters[col].test(
-            arr[sel], valid[sel] if valid is not None else None)
-        rows_in = len(sel)
-        sel = sel[mask]
-        if adaptive is not None:
-            adaptive.update(col, rows_in, len(sel),
-                            time.perf_counter() - t0)
+    # filter-decode + cascade wall, before any payload materializes
+    with _obs_trace.current().phase("scan_filter_cascade"):
+        decoded_f, n = decode(tuple(filter_cols))
+        sel = np.arange(n)
+        for col in order:
+            if not len(sel):
+                break
+            arr, valid, _ = decoded_f[col]
+            t0 = time.perf_counter()
+            mask = filters[col].test(
+                arr[sel], valid[sel] if valid is not None else None)
+            rows_in = len(sel)
+            sel = sel[mask]
+            if adaptive is not None:
+                adaptive.update(col, rows_in, len(sel),
+                                time.perf_counter() - t0)
     m = len(sel)
-    if tracer.enabled:
-        # filter-decode + cascade wall, before any payload materializes
-        tracer.record("scan_filter_cascade", "host_decode", cascade_w0,
-                      time.time(), table=getattr(handle, "name", "?"),
-                      rows_in=int(n), rows_out=int(m))
     if counters is not None and n > m:
         counters("rows_predecode_filtered", n - m)
         counters("bytes_skipped", (n - m) * _bytes_per_row(handle, columns))

@@ -318,24 +318,13 @@ class DistributedScheduler:
             h["X-Presto-Cluster-Secret"] = self.cluster_secret
         return h
 
-    def execute(self, query_id: str, dplan: DistributedPlan,
-                workers: List[NodeInfo],
-                config: Optional[ExecConfig] = None,
-                stats_out: Optional[list] = None,
-                tracer=None):
-        """`stats_out`, when given, is filled with one
-        (task_id, fragment_id, task_info_dict) per task after the result
-        stream completes — the per-task stats rollup EXPLAIN ANALYZE
-        renders (QueryStats/TaskStats introspection analog).
-
-        `tracer` (obs.trace.Tracer) makes every task-create POST carry the
-        query's trace token; after the stream completes the scheduler pulls
-        each task's span dump and stitches query → stage → task."""
-        config = config or self.config
-        tracer = tracer or _obs_trace.NOOP
-        trace_parent = tracer.current_parent()
-        trace_hdrs = ({_obs_trace.TRACE_HEADER: tracer.token(trace_parent)}
-                      if tracer.enabled else {})
+    def _create_tasks(self, query_id: str, dplan: DistributedPlan,
+                      workers: List[NodeInfo], config: ExecConfig,
+                      trace_hdrs: dict, created: list) -> Dict[int, List[str]]:
+        """Place every fragment's tasks and POST them, phase by phase.
+        Every task created is appended to `created` as (task_id, worker),
+        so that the caller can abort them whatever happens here; returns
+        the task URLs by fragment."""
         if not workers:
             raise QueryFailed("no active workers")
         frags = dplan.fragments
@@ -468,7 +457,6 @@ class DistributedScheduler:
             if info.get("state") == "failed":
                 raise QueryFailed(info.get("error") or "task failed")
 
-        created = []
         dead: set = set()
 
         def mark_dead(x):
@@ -508,36 +496,60 @@ class DistributedScheduler:
                 created.append((ntid, nw))
                 return ntid, nw
 
+        # phase by phase; within a phase producers first (ascending fid
+        # = topological order). All-at-once has exactly one phase.
+        for ph in range(last_phase + 1):
+            phase_tids = []
+            for tid, w, fid, i, p in assignments:
+                if p != ph:
+                    continue
+                try:
+                    if id(w) in dead:
+                        raise urllib.error.URLError("worker known dead")
+                    post_task(tid, w, fid, i)
+                    created.append((tid, w))
+                    phase_tids.append((tid, w, fid, i))
+                except (urllib.error.URLError, OSError):
+                    # creation-time loss: any task is re-placeable on a
+                    # survivor BEFORE its consumers wire upstreams
+                    # (producers post first — ascending fid order)
+                    ntid, nw = reschedule(tid, w, fid, i)
+                    phase_tids.append((ntid, nw, fid, i))
+            if ph < last_phase:
+                # gate the next phase on this (build) phase finishing
+                self._wait_finished(
+                    phase_tids,
+                    timeout_s=getattr(config, "phase_wait_timeout_s",
+                                      600.0),
+                    on_lost=(reschedule if ph == 0 and grouped
+                             else None),
+                    extra_headers=trace_hdrs)
+        return task_urls
+
+    def execute(self, query_id: str, dplan: DistributedPlan,
+                workers: List[NodeInfo],
+                config: Optional[ExecConfig] = None,
+                stats_out: Optional[list] = None,
+                tracer=None):
+        """`stats_out`, when given, is filled with one
+        (task_id, fragment_id, task_info_dict) per task after the result
+        stream completes — the per-task stats rollup EXPLAIN ANALYZE
+        renders (QueryStats/TaskStats introspection analog).
+
+        `tracer` (obs.trace.Tracer) makes every task-create POST carry the
+        query's trace token; after the stream completes the scheduler pulls
+        each task's span dump and stitches query → stage → task."""
+        config = config or self.config
+        tracer = tracer or _obs_trace.NOOP
+        trace_parent = tracer.current_parent()
+        trace_hdrs = ({_obs_trace.TRACE_HEADER: tracer.token(trace_parent)}
+                      if tracer.enabled else {})
+        created = []
         completed = False
         try:
-            # phase by phase; within a phase producers first (ascending fid
-            # = topological order). All-at-once has exactly one phase.
-            for ph in range(last_phase + 1):
-                phase_tids = []
-                for tid, w, fid, i, p in assignments:
-                    if p != ph:
-                        continue
-                    try:
-                        if id(w) in dead:
-                            raise urllib.error.URLError("worker known dead")
-                        post_task(tid, w, fid, i)
-                        created.append((tid, w))
-                        phase_tids.append((tid, w, fid, i))
-                    except (urllib.error.URLError, OSError):
-                        # creation-time loss: any task is re-placeable on a
-                        # survivor BEFORE its consumers wire upstreams
-                        # (producers post first — ascending fid order)
-                        ntid, nw = reschedule(tid, w, fid, i)
-                        phase_tids.append((ntid, nw, fid, i))
-                if ph < last_phase:
-                    # gate the next phase on this (build) phase finishing
-                    self._wait_finished(
-                        phase_tids,
-                        timeout_s=getattr(config, "phase_wait_timeout_s",
-                                          600.0),
-                        on_lost=(reschedule if ph == 0 and grouped
-                                 else None),
-                        extra_headers=trace_hdrs)
+            with tracer.phase("schedule"):
+                task_urls = self._create_tasks(query_id, dplan, workers,
+                                               config, trace_hdrs, created)
             # stream the root fragment's single output buffer
             root_urls = [f"{u}/results/0" for u in task_urls[dplan.root_fid]]
             client = ExchangeClient(root_urls)
@@ -582,8 +594,9 @@ class DistributedScheduler:
                     except Exception:
                         pass
             if tracer.enabled:
-                self._collect_task_traces(tracer, created, trace_parent,
-                                          trace_hdrs)
+                with tracer.phase("trace_collect"):
+                    self._collect_task_traces(tracer, created, trace_parent,
+                                              trace_hdrs)
         except ExchangeFailure as e:
             raise QueryFailed(str(e), retryable=not e.task_error) from e
         finally:

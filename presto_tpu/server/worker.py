@@ -245,7 +245,8 @@ class TaskExecution:
             while True:
                 w0 = time.perf_counter()
                 try:
-                    b = next(it)
+                    with self.tracer.phase("exchange_wait", wait=True):
+                        b = next(it)
                 except StopIteration:
                     break
                 dt = time.perf_counter() - w0
@@ -403,6 +404,10 @@ class TaskExecution:
                      for k, v in ctx.stats.items()]
             self.stats_report = rows
 
+    def _live_rows(self, b: Batch) -> int:
+        with self.tracer.phase("host_sync:sink_count"):
+            return int(np.asarray(b.live).sum())
+
     def _make_sink(self, f: Fragment, cfg):
         sink = self._make_sink_inner(f, cfg)
         if not self._count_progress and self._inflight is None:
@@ -413,7 +418,7 @@ class TaskExecution:
             # serialize so a sink raise still leaves the rows visible
             rows = 0
             if self._count_progress:
-                rows = int(np.asarray(b.live).sum())
+                rows = self._live_rows(b)
                 self.rows_emitted += rows
                 self.batches_emitted += 1
             if self._inflight is not None:
@@ -425,6 +430,12 @@ class TaskExecution:
         return counting_sink
 
     def _make_sink_inner(self, f: Fragment, cfg):
+        phase = self.tracer.phase
+
+        def page_of(b: Batch, **kw):
+            with phase("host_sync:sink_serialize"):
+                return serialize_batch(b, dict_refs=True, **kw)
+
         if f.output_partitioning == OUT_HASH and self.update.n_out_partitions > 1:
             pid_fn = _jit_partition_ids(
                 tuple(f.output_keys), self.update.n_out_partitions
@@ -435,15 +446,15 @@ class TaskExecution:
             def sink(b: Batch):
                 # device-side hash, host-side scatter into per-consumer pages
                 # (PartitionedOutputOperator.partitionPage:377 analog)
-                pid = np.asarray(pid_fn(b))
-                live = np.asarray(b.live)
+                with phase("host_sync:sink_count"):
+                    pid = np.asarray(pid_fn(b))
+                    live = np.asarray(b.live)
                 if rid_fn is None:
                     for p in range(self.update.n_out_partitions):
                         mask = live & (pid == p)
                         if mask.any():
                             self.buffer.enqueue(
-                                p, serialize_batch(b.with_live(mask),
-                                                   dict_refs=True))
+                                p, page_of(b.with_live(mask)))
                     return
                 # partition-aligned exchange: the consumer breaker radix-
                 # partitions on these same keys, so split each consumer's
@@ -459,9 +470,8 @@ class TaskExecution:
                         continue
                     for r in np.unique(rid[pmask]):
                         self.buffer.enqueue(
-                            p, serialize_batch(
-                                b.with_live(pmask & (rid == r)),
-                                radix=(int(r), R, keys), dict_refs=True))
+                            p, page_of(b.with_live(pmask & (rid == r)),
+                                       radix=(int(r), R, keys)))
 
             return sink
 
@@ -473,19 +483,19 @@ class TaskExecution:
                 # page-level round robin (the reference's
                 # ArbitraryOutputBuffer: any consumer may take a page;
                 # deterministic rotation here keeps tasks balanced)
-                if int(np.asarray(b.live).sum()) == 0:
+                if self._live_rows(b) == 0:
                     return
                 p = state["next"] % n_parts
                 state["next"] += 1
-                self.buffer.enqueue(p, serialize_batch(b, dict_refs=True))
+                self.buffer.enqueue(p, page_of(b))
 
             return sink
 
         def sink(b: Batch):
             # gather/broadcast: one serialized page, fanned out by the buffer
-            if int(np.asarray(b.live).sum()) == 0:
+            if self._live_rows(b) == 0:
                 return
-            page = serialize_batch(b, dict_refs=True)
+            page = page_of(b)
             if f.output_partitioning == OUT_BROADCAST:
                 self.buffer.enqueue(None, page)
             else:

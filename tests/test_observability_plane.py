@@ -5,7 +5,11 @@ and the slow-query event sink.
 Reference modules: airlift trace-token propagation, DistributionStat /
 TimeStat metrics export, the EventListener SPI's QueryCompletedEvent."""
 
+import glob
 import json
+import os
+import sys
+import threading
 import time
 import urllib.error
 import urllib.request
@@ -22,6 +26,11 @@ from presto_tpu.obs import trace as obs_trace
 from presto_tpu.obs.events import SlowQueryLogger
 from presto_tpu.obs.exposition import lint_exposition
 from presto_tpu.server.metrics import _fmt, render_metrics
+
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:  # the benchmark's trace reduction and query texts
+    sys.path.insert(0, ROOT)
 
 
 def _catalog():
@@ -230,6 +239,114 @@ class TestTracer:
         assert reg.get("y") is t3
 
 
+
+class TestPhases:
+    def test_nesting_gives_self_time_and_items_add_up(self):
+        tr = obs_trace.Tracer()
+        with tr.span("query", "query"):
+            with tr.phase("outer"):
+                time.sleep(0.01)
+                for k in (3, 5):
+                    with tr.phase("inner", items=k):
+                        time.sleep(0.01)
+        ph = tr.to_json()["phases"]["coordinator"]
+        outer, inner = ph["outer"], ph["inner"]
+        assert (outer["n"], inner["n"], inner["items"]) == (1, 2, 8)
+        assert inner["busy_s"] == inner["self_s"] >= 0.02
+        assert outer["busy_s"] >= 0.03
+        # busy less what was opened inside it on the same thread
+        assert outer["self_s"] == pytest.approx(
+            outer["busy_s"] - inner["busy_s"], abs=1e-5)
+        assert inner["max_s"] <= inner["busy_s"]
+        # a dump carries one span of kind `phase` per aggregate, with the
+        # same id every time, hung under the root
+        spans = [s for s in tr.spans() if s.kind == "phase"]
+        assert sorted(s.name for s in spans) == ["inner", "outer"]
+        assert {s.parent_id for s in spans} == {tr.root_id}
+        assert [s.span_id for s in spans] == \
+            [s.span_id for s in tr.spans() if s.kind == "phase"]
+        assert spans[0].attrs["role"] == "coordinator"
+
+    def test_two_threads_give_two_roles(self):
+        tr = obs_trace.Tracer()
+
+        def work():
+            with obs_trace.use(tr), obs_trace.current().phase("scan_read"):
+                pass
+
+        with tr.span("task", "task"):
+            threads = [threading.Thread(target=work, name=n) for n in
+                       ("scan-prefetch", "task-q7.0.0", "task-q7.0.1.r1")]
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(10)
+            assert not any(t.is_alive() for t in threads)
+        ph = obs_trace.phases_by_role(tr.spans())
+        assert sorted(ph) == ["scan-prefetch", "task"]
+        assert ph["task"]["scan_read"]["n"] == 2     # the ids are cut off
+        assert ph["scan-prefetch"]["scan_read"]["n"] == 1
+
+    def test_noop_tracer_records_nothing(self):
+        before = len(obs_trace.summaries())
+        with obs_trace.NOOP.span("query", "query"):
+            with obs_trace.NOOP.phase("outer", items=2):
+                with obs_trace.NOOP.phase("w", wait=True):
+                    pass
+        assert obs_trace.NOOP.spans() == []
+        assert obs_trace.NOOP.to_json()["phases"] == {}
+        assert len(obs_trace.summaries()) == before
+
+    def test_wait_phase_enters_no_annotation(self, monkeypatch):
+        entered = []
+
+        class Spy:
+            def __init__(self, name):
+                self.name = name
+
+            def __enter__(self):
+                entered.append(self.name)
+
+            def __exit__(self, *exc):
+                pass
+
+        monkeypatch.setattr(obs_trace, "_Annotation", Spy)
+        tr = obs_trace.Tracer()
+        with tr.phase("window_stack"):
+            with tr.phase("window_queue_full", wait=True):
+                pass
+        assert entered == ["engine:window_stack"]
+        ph = obs_trace.phases_by_role(tr.spans())
+        (by_name,) = ph.values()
+        assert by_name["window_queue_full"]["wait"] is True
+        assert "wait" not in by_name["window_stack"]
+
+    def test_query_span_close_leaves_a_summary(self):
+        coord = obs_trace.Tracer(trace_id="T_sum")
+        with coord.span("query", "query") as root:
+            with coord.phase("schedule"):
+                pass
+            coord.record("exchange_wait", "exchange_wait", 0.0, 1.0,
+                         parent_id=root.span_id, wait_s=0.25)
+            worker = obs_trace.Tracer(trace_id="T_sum")
+            with worker.span("task", "task"):
+                with worker.phase("program_call:Aggregate"):
+                    pass
+                # a worker's own exchange wait is not the coordinator's
+                worker.record("exchange_wait", "exchange_wait", 0.0, 1.0,
+                              wait_s=9.0)
+            coord.absorb(worker.to_json()["spans"])
+        doc = obs_trace.summaries()[-1]
+        assert doc["queryId"] == "T_sum" and doc["tasks"] == 1
+        assert doc["exchange_wait_s"] == 0.25 and doc["dropped"] == 0
+        assert doc["spans"] == len(coord.spans())
+        assert doc["phases"]["coordinator"]["schedule"]["n"] == 1
+        role = obs_trace._thread_role(threading.current_thread().name)
+        assert doc["phases"][role]["program_call:Aggregate"]["n"] == 1
+        # a task tracer's root is no query span: it leaves none of its own
+        assert [d["queryId"] for d in obs_trace.summaries()].count("T_sum") == 1
+
+
 # -- slow-query sink (unit) ------------------------------------------------
 
 
@@ -417,6 +534,148 @@ def test_local_runner_trace_and_disable():
     r2 = LocalRunner(cat, ExecConfig(tracing=False))
     r2.run("select count(*) as n from t")
     assert r2.last_trace is None
+
+
+# -- engine phases through the cluster ---------------------------------------
+
+
+def _lineitem_catalog(sf="0.01"):
+    from presto_tpu.server.__main__ import build_catalog
+
+    return build_catalog([f"tpch:sf={sf}"])
+
+
+def _query_text(qid):
+    with open(os.path.join(ROOT, "benchmark", "queries", qid + ".json")) as f:
+        params = json.load(f)["params"]["fixed"]
+    with open(os.path.join(ROOT, "benchmark", "queries", qid + ".sql")) as f:
+        return f.read().format(**params).strip()
+
+
+def _run_statement(coord, sql):
+    # 60,000 lineitem rows in batches of 8,192, so that windows are stacked
+    qe = coord.query_manager.create_query(
+        coord.protocol.session_from_headers(
+            {"X-Presto-Session": "batch_rows=8192"}), sql)
+    assert qe.wait(120)
+    assert qe.state == "FINISHED", qe.error
+    return qe.query_id
+
+
+def _counts(summary):
+    return {(role, name): agg["n"]
+            for role, by_name in summary["phases"].items()
+            for name, agg in by_name.items()}
+
+
+def test_statements_leave_phase_summaries_that_outlive_the_cluster():
+    from presto_tpu.server.coordinator import DistributedRunner
+
+    ids = {}
+    with DistributedRunner(_lineitem_catalog(), n_workers=1) as dr:
+        coord = dr.coordinator
+        for qid in ("q1", "q6"):          # grouped, ungrouped
+            sql = _query_text(qid)
+            ids[qid] = [_run_statement(coord, sql) for _ in range(3)][1:]
+        with urllib.request.urlopen(
+                f"{coord.url}/v1/query/{ids['q1'][-1]}/trace", timeout=10) as r:
+            doc = json.loads(r.read())
+        assert doc["phases"]["coordinator"]["schedule"]["n"] == 1
+        assert any(s["kind"] == "phase" for s in doc["spans"])
+        for gone in ("host_decode", "device_transfer", "fragment_step"):
+            assert not any(s["kind"] == gone for s in doc["spans"])
+    # the cluster is closed: the summaries are still there
+    by_id = {d["queryId"]: d for d in obs_trace.summaries()}
+    for qid, (second, third) in ids.items():
+        a, b = by_id[second], by_id[third]
+        assert _counts(a) == _counts(b), qid      # counts repeat exactly
+        assert a["spans"] == b["spans"] and a["dropped"] == 0
+        task = a["phases"]["task"]
+        assert any(n.startswith("program_call:") for n in task), task
+        assert any(n.startswith("host_sync:") for n in task), task
+        assert task["window_wait"]["wait"] is True
+        # the fused path ran: batches were stacked into windows
+        stack = a["phases"]["fragment-window-producer"]["window_stack"]
+        assert stack["n"] >= 1 and stack["items"] >= 2
+        assert a["phases"]["scan-prefetch"]["scan_read"]["n"] >= 7
+        assert a["phases"]["coordinator"]["trace_collect"]["n"] == 1
+        assert a["tasks"] >= 2 and a["task_wall_s"] > 0
+        named = sum(agg["self_s"] for agg in task.values())
+        assert 0 < named <= a["task_wall_s"] * 1.01
+    assert "host_sync:breaker_finish" in by_id[ids["q1"][0]]["phases"]["task"]
+
+
+def test_engine_phases_share_the_profilers_clock(tmp_path):
+    """Off the chip: under a profiler session the phases are events on the
+    engine threads' lines of the xplane, inside the benchmark's window."""
+    import jax.profiler
+
+    from benchmark import trace_reduce
+    from presto_tpu.server.coordinator import DistributedRunner
+
+    with DistributedRunner(_lineitem_catalog(), n_workers=1) as dr:
+        sql = _query_text("q6")
+        _run_statement(dr.coordinator, sql)      # compile outside the trace
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        opts.host_tracer_level = 2
+        jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+        try:
+            with jax.profiler.TraceAnnotation(trace_reduce.WINDOW_SPAN):
+                _run_statement(dr.coordinator, sql)
+        finally:
+            jax.profiler.stop_trace()
+    (path,) = glob.glob(os.path.join(
+        str(tmp_path), "plugins", "profile", "*", "*.xplane.pb"))
+    trace = trace_reduce.load_xplane(path)
+    window, found = None, {}
+    for plane in trace["planes"]:
+        for line in plane["lines"]:
+            for name, start, dur in line["events"]:
+                if name == trace_reduce.WINDOW_SPAN:
+                    window = (start, start + dur)
+                elif name.startswith("engine:") and \
+                        line["name"].startswith(trace_reduce.PYTHON_LINE):
+                    found.setdefault(name, []).append((start, start + dur))
+    assert window is not None
+    assert "engine:window_stack" in found, sorted(found)
+    assert any(n.startswith("engine:program_call:") for n in found)
+    assert not any("wait" in n or "queue_full" in n for n in found)
+    for spans in found.values():
+        for a, b in spans:
+            assert window[0] <= a <= b <= window[1]
+
+
+@pytest.mark.parametrize("qid", ["q6", "q1"])
+def test_fragment_programs_carry_operator_names(qid, monkeypatch):
+    """`jax.named_scope` at trace time: the fused fragment program's
+    operations are located under the operator that made them."""
+    from presto_tpu.exec import programs
+    from presto_tpu.exec.runner import LocalRunner
+
+    texts = {}
+    wrap = programs.wrap
+
+    def spying_wrap(entry, node_stats, node_kind, key):
+        fn = wrap(entry, node_stats, node_kind, key)
+        if "fragment_step" not in key:
+            return fn
+
+        def call(*args, **kw):
+            if key not in texts:
+                texts[key] = entry.jfn.lower(*args, **kw).as_text(
+                    debug_info=True)
+            return fn(*args, **kw)
+
+        call._entry = entry
+        return call
+
+    monkeypatch.setattr(programs, "wrap", spying_wrap)
+    LocalRunner(_lineitem_catalog(), ExecConfig(batch_rows=8192)).run(
+        _query_text(qid))
+    assert texts, "no fused fragment program ran"
+    for key, text in texts.items():
+        assert "breaker_step/scan_chain/" in text, key
 
 
 # -- runtime statistics feedback plane (obs/runstats.py) -------------------
