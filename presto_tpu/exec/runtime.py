@@ -2230,7 +2230,7 @@ def _record_fragment_dispatch(node: PlanNode, ctx: "ExecContext",
 
 def _inflight_window_hook(node: PlanNode, ctx: "ExecContext"):
     """WindowSource on_window callback publishing the staging watermark
-    (windows stacked ahead of the consumer) into the inflight plane.
+    (windows staged ahead of the consumer) into the inflight plane.
     None when the plane is off, so the producer thread pays nothing."""
     inf = ctx.inflight
     if inf is None:
@@ -2688,11 +2688,11 @@ def _execute_aggregate(node: Aggregate, ctx: ExecContext) -> Iterator[Batch]:
             jit_frag_step = _node_jit(
                 node, _ek("fragment_step"),
                 lambda: _fragment_jit.scan_stepper(ms, False),
-                static_argnums=(2,), **_step_jit_kw)
+                static_argnums=(3,), **_step_jit_kw)
             jit_frag_step0 = _node_jit(
                 node, _ek("fragment_step0"),
                 lambda: _fragment_jit.scan_stepper(ms, True),
-                static_argnums=(1,))
+                static_argnums=(2,))
 
     _bind_engine(engine)
 
@@ -3342,15 +3342,16 @@ def _execute_aggregate(node: Aggregate, ctx: ExecContext) -> Iterator[Batch]:
 
         def absorb_fused(stream):
             """Whole-fragment ingest: consecutive same-structure batches
-            arrive STACKED (WindowSource double-buffers them), and one
-            fused program folds chain+merge over the whole window on-device
-            via lax.scan — O(batches / window) dispatches instead of
-            O(batches). The overflow protocol matches absorb(): an
-            optimistic window of (checkpoint, item, max-ng) confirms up to
-            `depth` items late and replays from the checkpoint on the rare
-            capacity overflow, with whole windows as the replay unit.
-            Growth past the grace ceiling unstacks the unmerged windows
-            back to raw batches for the hash-partitioned spill path."""
+            arrive as a WINDOW of references (WindowSource stages one
+            ahead), and one fused program stacks the window and folds
+            chain+merge over it on-device via lax.scan — O(batches /
+            window) dispatches instead of O(batches). The overflow
+            protocol matches absorb(): an optimistic window of (checkpoint,
+            item, max-ng) confirms up to `depth` items late and replays
+            from the checkpoint on the rare capacity overflow, with whole
+            windows as the replay unit. Growth past the grace ceiling hands
+            the unmerged windows' real batches to the hash-partitioned
+            spill path."""
             nonlocal cap
             depth = max(1, ctx.config.agg_pipeline_depth)
             no_overflow = not key_syms
@@ -3361,8 +3362,8 @@ def _execute_aggregate(node: Aggregate, ctx: ExecContext) -> Iterator[Batch]:
             def apply(acc_before, item, c):
                 if isinstance(item, _fragment_jit.Window):
                     if acc_before is None:
-                        return jit_frag_step0(item.stacked, c)
-                    return jit_frag_step(acc_before, item.stacked, c)
+                        return jit_frag_step0(*item.operands, c)
+                    return jit_frag_step(acc_before, *item.operands, c)
                 if acc_before is None:
                     return jit_step0(item, c)
                 return jit_step(acc_before, item, c)
@@ -3374,9 +3375,8 @@ def _execute_aggregate(node: Aggregate, ctx: ExecContext) -> Iterator[Batch]:
                 for e in entries:
                     item = e[1]
                     if isinstance(item, _fragment_jit.Window):
-                        out.extend(
-                            (None, rb, None) for rb in
-                            _fragment_jit.unstack_batch(item.stacked, item.k))
+                        out.extend((None, rb, None)
+                                   for rb in item.batches[:item.k])
                     else:
                         out.append((None, item, None))
                 return out
@@ -6014,9 +6014,9 @@ def _execute_sort(node: Sort, ctx: ExecContext) -> Iterator[Batch]:
         node.__dict__["_fragment_fusion"] = (
             "fused" if frag_why is None else frag_why)
         if frag_why is None:
-            # fused fragment: fold the TopN step over stacked windows
-            # on-device — the heap never overflows (capacity is the LIMIT)
-            # so there is no confirm/replay protocol to thread through
+            # fused fragment: stack each window and fold the TopN step over
+            # it on-device — the heap never overflows (capacity is the
+            # LIMIT) so there is no confirm/replay protocol to thread through
             jfstep = _node_jit(
                 node, "fragment_topn",
                 lambda: _fragment_jit.topn_stepper(topn_step, False),
@@ -6031,8 +6031,8 @@ def _execute_sort(node: Sort, ctx: ExecContext) -> Iterator[Batch]:
             try:
                 for item in src:
                     if isinstance(item, _fragment_jit.Window):
-                        acc = (jfstep0(item.stacked) if acc is None
-                               else jfstep(acc, item.stacked))
+                        acc = (jfstep0(*item.operands) if acc is None
+                               else jfstep(acc, *item.operands))
                         _record_fragment_dispatch(node, ctx, True, item.k)
                     else:
                         acc = jstep(acc, item)
@@ -6269,18 +6269,18 @@ def _warm_agg_breaker(node: Aggregate, scan: TableScan, scan_cap: int,
     acc, _ = jit_step0(zb, cap)
     acc, _ = jit_step(acc, zb, cap)
     if _fragment_eligibility(node, ctx.config) is None:
-        stacked = _fragment_jit.stack_batches(
-            [zb] * max(2, ctx.config.fragment_window))
+        width = max(2, ctx.config.fragment_window)
+        win = _fragment_jit.Window((zb,) * width, width)
         jit_frag_step = _node_jit(
             node, _ek("fragment_step"),
             lambda: _fragment_jit.scan_stepper(merge_step, False),
-            static_argnums=(2,), **_step_jit_kw)
+            static_argnums=(3,), **_step_jit_kw)
         jit_frag_step0 = _node_jit(
             node, _ek("fragment_step0"),
             lambda: _fragment_jit.scan_stepper(merge_step, True),
-            static_argnums=(1,))
-        facc, _ = jit_frag_step0(stacked, cap)
-        facc, _ = jit_frag_step(facc, stacked, cap)
+            static_argnums=(2,))
+        facc, _ = jit_frag_step0(*win.operands, cap)
+        facc, _ = jit_frag_step(facc, *win.operands, cap)
         jax.block_until_ready(facc.live)
     jax.block_until_ready(acc.live)
 
@@ -6288,7 +6288,7 @@ def _warm_agg_breaker(node: Aggregate, scan: TableScan, scan_cap: int,
 def _warm_topn_breaker(node: Sort, scan: TableScan, scan_cap: int,
                        ctx: ExecContext) -> None:
     """Warm the TopN breaker's stepping programs (per-batch and, when the
-    fragment fuses, the stacked-window variants) from a fabricated scan
+    fragment fuses, the window variants) from a fabricated scan
     batch — same memoized _topn_step closure and _node_jit keys as the
     executor."""
     zb = _fabricate_scan_batch(scan, scan_cap, ctx)
@@ -6301,8 +6301,8 @@ def _warm_topn_breaker(node: Sort, scan: TableScan, scan_cap: int,
     acc = jstep(None, zb)
     acc = jstep(acc, zb)
     if _fragment_eligibility(node, ctx.config) is None:
-        stacked = _fragment_jit.stack_batches(
-            [zb] * max(2, ctx.config.fragment_window))
+        width = max(2, ctx.config.fragment_window)
+        win = _fragment_jit.Window((zb,) * width, width)
         jfstep = _node_jit(
             node, "fragment_topn",
             lambda: _fragment_jit.topn_stepper(topn_step, False),
@@ -6310,8 +6310,8 @@ def _warm_topn_breaker(node: Sort, scan: TableScan, scan_cap: int,
         jfstep0 = _node_jit(
             node, "fragment_topn0",
             lambda: _fragment_jit.topn_stepper(topn_step, True))
-        facc = jfstep0(stacked)
-        facc = jfstep(facc, stacked)
+        facc = jfstep0(*win.operands)
+        facc = jfstep(facc, *win.operands)
         jax.block_until_ready(facc.live)
     jax.block_until_ready(acc.live)
 

@@ -5,6 +5,7 @@ result-identical to the per-batch path, decline the modes it cannot
 cover (grace spill, grouped execution), and actually collapse the
 dispatch count."""
 
+import contextlib
 import time
 
 import jax.numpy as jnp
@@ -36,7 +37,7 @@ def _mkbatch(n=4, base=0, cap=8):
 
 
 # ---------------------------------------------------------------------------
-# window stacking units
+# window assembly units: a window is references, never a copy
 
 
 def test_iter_windows_groups_and_pads():
@@ -46,24 +47,24 @@ def test_iter_windows_groups_and_pads():
     assert [type(i) for i in items] == [fj.Window, fj.Window]
     assert items[0].k == 4 and items[0].width == 4
     assert items[1].k == 2 and items[1].width == 2
-    assert items[0].stacked.live.shape == (4, 8)
+    assert len(items[0].batches) == 4
+    assert all(x is y for x, y in zip(items[0].batches, bs[:4]))
+    assert all(x is y for x, y in zip(items[1].batches, bs[4:]))
 
 
-def test_iter_windows_ragged_tail_pads_to_pow2_with_dead_rows():
+def test_iter_windows_ragged_tail_pads_to_pow2_by_reference():
     bs = [_mkbatch(base=i) for i in range(7)]
     (w,) = list(fj.iter_windows(iter(bs), width=8))
-    assert w.k == 7 and w.width == 8
-    # padding slice is a dead clone of the last real batch
-    assert not bool(w.stacked.live[7].any())
-    assert bool(w.stacked.live[6].any())
-    np.testing.assert_array_equal(np.asarray(w.stacked.column("x").values[7]),
-                                  np.asarray(bs[-1].column("x").values))
+    assert w.k == 7 and w.width == 8 and len(w.batches) == 8
+    assert all(x is y for x, y in zip(w.batches[:7], bs))
+    # the padding slot IS the last real batch (the step masks it dead)
+    assert w.batches[7] is bs[-1]
 
 
 def test_iter_windows_lone_batch_passes_through():
     bs = [_mkbatch()]
     items = list(fj.iter_windows(iter(bs), width=8))
-    assert len(items) == 1 and isinstance(items[0], Batch)
+    assert len(items) == 1 and items[0] is bs[0]
 
 
 def test_iter_windows_flushes_on_structure_change():
@@ -72,24 +73,67 @@ def test_iter_windows_flushes_on_structure_change():
     items = list(fj.iter_windows(iter(small + big), width=8))
     assert isinstance(items[0], fj.Window) and items[0].k == 3
     assert isinstance(items[1], fj.Window) and items[1].k == 2
-    assert items[0].stacked.live.shape[1] == 8
-    assert items[1].stacked.live.shape[1] == 16
+    assert items[0].width == 4 and items[1].width == 2
+    assert {b.live.shape for b in items[0].batches} == {(8,)}
+    assert {b.live.shape for b in items[1].batches} == {(16,)}
 
 
-def test_unstack_roundtrip():
+def test_window_operands_and_footprint():
+    """What the fused step is called with: the tuple itself and `k` as an
+    int32 device scalar (an operand, so its value selects no program); the
+    footprint is the step's stacked temporary, `width` batches."""
+    from presto_tpu.memory import batch_device_bytes
+
     bs = [_mkbatch(base=i) for i in range(5)]
     (w,) = list(fj.iter_windows(iter(bs), width=8))
-    back = fj.unstack_batch(w.stacked, w.k)
-    assert len(back) == 5
-    for orig, rb in zip(bs, back):
-        np.testing.assert_array_equal(np.asarray(orig.column("x").values),
-                                      np.asarray(rb.column("x").values))
-        np.testing.assert_array_equal(np.asarray(orig.live),
-                                      np.asarray(rb.live))
+    batches, k = w.operands
+    assert batches is w.batches
+    assert k.shape == () and k.dtype == np.int32 and int(k) == 5
+    # resident on the device, made once for each value: no transfer a call
+    assert w.operands[1] is k
+    assert fj.window_device_bytes(w) == 8 * batch_device_bytes(bs[0])
+    # shape_bucketing pads every multi-batch flush to the full width
+    (wb,) = list(fj.iter_windows(iter(bs[:2]), width=8, bucket=True))
+    assert wb.k == 2 and wb.width == 8 and wb.batches[2:] == (bs[1],) * 6
+
+
+@contextlib.contextmanager
+def _no_dispatch(monkeypatch):
+    """No array may move and no eager stack or padding op may run."""
+    import jax
+
+    def boom(*a, **k):
+        raise AssertionError("eager jnp call on the window path")
+
+    monkeypatch.setattr(jnp, "stack", boom)
+    monkeypatch.setattr(jnp, "zeros_like", boom)
+    # the config value, not jax.transfer_guard(): the producer is another
+    # thread, and the context manager is thread-local
+    prev = jax.config.jax_transfer_guard
+    jax.config.update("jax_transfer_guard", "disallow")
+    try:
+        yield
+    finally:
+        jax.config.update("jax_transfer_guard", prev)
+
+
+@pytest.mark.parametrize("source", ["iter_windows", "WindowSource"])
+def test_window_assembly_makes_no_jax_dispatch(source, monkeypatch):
+    bs = [_mkbatch(base=i) for i in range(7)]
+    with _no_dispatch(monkeypatch):
+        if source == "iter_windows":
+            items = list(fj.iter_windows(iter(bs), width=8))
+        else:
+            src = fj.WindowSource(iter(bs), width=8)
+            items = list(src)
+            src.close()
+    (w,) = items
+    assert w.k == 7 and w.width == 8
+    assert all(x is y for x, y in zip(w.batches, bs + bs[-1:]))
 
 
 # ---------------------------------------------------------------------------
-# the async double-buffer producer
+# the async producer
 
 
 def test_window_source_preserves_order():
@@ -98,13 +142,12 @@ def test_window_source_preserves_order():
     got = []
     for item in src:
         if isinstance(item, fj.Window):
-            got.extend(fj.unstack_batch(item.stacked, item.k))
+            got.extend(item.batches[:item.k])
         else:
             got.append(item)
     src.close()
     assert len(got) == 20
-    for i, b in enumerate(got):
-        assert int(b.column("x").values[0]) == i
+    assert all(x is y for x, y in zip(got, bs))
 
 
 def test_window_source_drain_recovers_undelivered():
@@ -112,16 +155,27 @@ def test_window_source_drain_recovers_undelivered():
     the producer pulled but never delivered, in stream order."""
     bs = [_mkbatch(base=i) for i in range(32)]
     src = fj.WindowSource(iter(bs), width=4)
-    consumed = []
     it = iter(src)
     first = next(it)
     assert isinstance(first, fj.Window)
-    consumed.extend(fj.unstack_batch(first.stacked, first.k))
+    consumed = list(first.batches[:first.k])
     rest = src.drain()
-    firsts = [int(b.column("x").values[0]) for b in consumed + rest]
-    # no duplicates, no gaps within what was pulled; prefix of the stream
-    assert firsts == sorted(set(firsts))
-    assert firsts[: len(consumed)] == [0, 1, 2, 3]
+    # no duplicates, no gaps within what was pulled; prefix of the stream;
+    # the very objects the stream yielded, not copies
+    got = consumed + rest
+    assert len(got) >= 8  # the window in hand and the staged one, at least
+    assert all(x is y for x, y in zip(got, bs))
+
+
+def test_window_source_drain_drops_the_ragged_tails_padding():
+    """A staged ragged window gives back its k real batches only."""
+    bs = [_mkbatch(base=i) for i in range(7)]
+    src = fj.WindowSource(iter(bs), width=8)
+    deadline = time.time() + 10.0
+    while src._q.empty() and time.time() < deadline:
+        time.sleep(0.01)
+    rest = src.drain()
+    assert len(rest) == 7 and all(x is y for x, y in zip(rest, bs))
 
 
 def test_window_source_propagates_producer_exception():
@@ -168,6 +222,130 @@ def _run_pair(sql, n=3000, **cfg):
     off = LocalRunner(cat, ExecConfig(batch_rows=512,
                                       fragment_fusion=False, **cfg))
     return on, on.run(sql), off, off.run(sql)
+
+
+# ---------------------------------------------------------------------------
+# the fused steppers on ragged windows, against the per-batch fold
+
+
+def _find(node, kind):
+    if isinstance(node, kind):
+        return node
+    for c in node.children():
+        hit = _find(c, kind)
+        if hit is not None:
+            return hit
+    return None
+
+
+@pytest.fixture(scope="module")
+def steppers():
+    """The engine's own merge closures and scan batches for a keyed
+    aggregate and a TopN, with the fused and per-batch programs jitted
+    once for the module (so a second trace shows in `_cache_size`)."""
+    import jax
+
+    from presto_tpu.exec import runtime as rt
+    from presto_tpu.plan.nodes import Aggregate, Sort
+
+    r = LocalRunner(_memory_catalog(4096, nulls=False),
+                    ExecConfig(batch_rows=512))
+    agg = _find(r.plan("select g, count(*) c, sum(v) s, min(s) m from t"
+                       " where v < 12 group by g").root, Aggregate)
+    srt = _find(r.plan("select g, v from t order by v desc limit 7").root,
+                Sort)
+    ms = rt._agg_steps(agg, "sort").merge_step
+    ts = rt._topn_step(srt)
+    env = {
+        "agg": dict(
+            batches=list(rt._fused_child(agg.child, r._new_ctx())[0]),
+            step0=jax.jit(fj.scan_stepper(ms, True), static_argnums=(2,)),
+            step=jax.jit(fj.scan_stepper(ms, False), static_argnums=(3,),
+                         donate_argnums=(0,)),
+            one0=jax.jit(lambda b, cap: ms(None, b, cap),
+                         static_argnums=(1,)),
+            one=jax.jit(lambda acc, b, cap: ms(acc, b, cap),
+                        static_argnums=(2,)),
+            cap=(64,)),
+        "topn": dict(
+            batches=list(rt._fused_child(srt.child, r._new_ctx())[0]),
+            step0=jax.jit(fj.topn_stepper(ts, True)),
+            step=jax.jit(fj.topn_stepper(ts, False), donate_argnums=(0,)),
+            one0=jax.jit(lambda b: (ts(None, b), 0)),
+            one=jax.jit(lambda acc, b: (ts(acc, b), 0)),
+            cap=()),
+    }
+    assert all(len(e["batches"]) == 8 for e in env.values())
+    return env
+
+
+def _live_rows(acc):
+    import jax
+
+    live = np.asarray(acc.live)
+    return [np.asarray(x)[live] for x in jax.tree_util.tree_leaves(acc)]
+
+
+@pytest.mark.parametrize("k,width", [(2, 8), (5, 8), (6, 8), (7, 8), (3, 4)])
+@pytest.mark.parametrize("kind", ["agg", "topn"])
+def test_ragged_window_step_matches_per_batch_fold(steppers, kind, k, width):
+    """`k` real batches padded to `width` by reference: the fused pair
+    (first window, then a second one into the donated accumulator) must
+    equal the per-batch fold over the real batches alone — dead slots add
+    nothing — and must run the full window's program, not one per `k`."""
+    import jax
+
+    e = steppers[kind]
+    bs, cap = e["batches"], e["cap"]
+    full = next(fj.iter_windows(iter(bs[:width]), width))
+    assert full.k == full.width == width
+    acc = e["step0"](*full.operands, *cap)
+    e["step"](acc if kind == "topn" else acc[0], *full.operands, *cap)
+    traced = e["step0"]._cache_size(), e["step"]._cache_size()
+
+    (w,) = fj.iter_windows(iter(bs[:k]), width, bucket=True)
+    assert (w.k, w.width) == (k, width)
+    out = e["step0"](*w.operands, *cap)
+    acc, ng = out if kind == "agg" else (out, 0)
+    out = e["step"](acc, *w.operands, *cap)
+    acc, ng2 = out if kind == "agg" else (out, 0)
+    assert (e["step0"]._cache_size(), e["step"]._cache_size()) == traced
+
+    ref, ns = None, []
+    for b in bs[:k] + bs[:k]:
+        ref, n = (e["one0"](b, *cap) if ref is None
+                  else e["one"](ref, b, *cap))
+        ns.append(int(n))
+    assert int(ng) == max(ns[:k]) and int(ng2) == max(ns[k:])
+    np.testing.assert_array_equal(np.asarray(acc.live), np.asarray(ref.live))
+    for got, want in zip(_live_rows(acc), _live_rows(ref)):
+        np.testing.assert_array_equal(got, want)
+    if kind == "agg":
+        # the group table is equal slot for slot, dead slots included
+        for got, want in zip(jax.tree_util.tree_leaves(acc),
+                             jax.tree_util.tree_leaves(ref)):
+            np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    # the scan's batches were operands of a donating program four times
+    # over and are still there: only the accumulator is donated
+    assert not any(x.is_deleted() for b in w.batches
+                   for x in jax.tree_util.tree_leaves(b))
+    assert int(np.asarray(w.batches[-1].live).sum()) > 0
+
+
+def test_cached_batches_survive_donating_statements():
+    """donate_stepping on (the default): a global aggregate's and a TopN's
+    fused steps donate their accumulator and nothing else, so a second and
+    third statement over the connector's cached split batches read them
+    intact."""
+    cat = _memory_catalog(4096, nulls=False)
+    r = LocalRunner(cat, ExecConfig(batch_rows=512, donate_stepping=True))
+    off = LocalRunner(cat, ExecConfig(batch_rows=512, fragment_fusion=False))
+    for sql in ("select count(*) c, sum(v) s from t where v < 12",
+                "select g, v from t order by v desc limit 7"):
+        want = off.run(sql)
+        for _ in range(3):
+            assert_frames_match(r.run(sql), want)
+        assert r.last_stats["fragment.dispatches"] >= 1
 
 
 def test_fused_agg_matches_and_collapses_dispatches():
