@@ -573,7 +573,8 @@ def execute_node(node: PlanNode, ctx: ExecContext) -> Iterator[Batch]:
         # merges, probes) is capacity-shaped, so coalesce before fanning
         # out (reference: operator/project/MergingPageOutput.java)
         stream = _merging_output(stream, ctx.config.batch_rows,
-                                 bucket=ctx.config.shape_bucketing != "off")
+                                 bucket=ctx.config.shape_bucketing != "off",
+                                 tracer=ctx.tracer)
     yield from stream
 
 
@@ -601,13 +602,15 @@ def _pad_batch(b: Batch, cap: int) -> Batch:
 
 
 def _merging_output(stream: Iterator[Batch], target_cap: int,
-                    bucket: bool = False) -> Iterator[Batch]:
+                    bucket: bool = False,
+                    tracer=_obs_trace.NOOP) -> Iterator[Batch]:
     """MergingPageOutput analog: compact sparse batches (live rows to the
     front), slice them to their power-of-two bucket, and concatenate until
     a full batch accumulates. Dense batches pass through untouched; empty
-    batches are dropped. Costs one host sync per input batch (num_live) —
-    repaid many times over by the capacity-shaped work it removes
-    downstream on selective multi-join plans.
+    batches are dropped. Costs one host sync per input batch (num_live:
+    the phase `host_sync:join_output_rows`) — repaid many times over by the
+    capacity-shaped work it removes downstream on selective multi-join
+    plans.
 
     ``bucket`` (shape_bucketing=pow2) additionally pads every flush —
     including the single-batch passthrough — up to the stream's pow2
@@ -652,7 +655,9 @@ def _merging_output(stream: Iterator[Batch], target_cap: int,
     def drain(block_all: bool):
         while window and (block_all or len(window) > 1):
             b, cnt = window.pop(0)
-            dense = consume(b, int(cnt))
+            with tracer.phase("host_sync:join_output_rows"):
+                n = int(cnt)
+            dense = consume(b, n)
             if dense is not None:
                 if pending:
                     yield flush()
@@ -739,7 +744,8 @@ def _fused_child(node: PlanNode, ctx: ExecContext):
         # breakers pull children through here, not execute_node — apply
         # the same sparse-output coalescing before the consumer's chain
         stream = _merging_output(stream, ctx.config.batch_rows,
-                                 bucket=ctx.config.shape_bucketing != "off")
+                                 bucket=ctx.config.shape_bucketing != "off",
+                                 tracer=ctx.tracer)
     return stream, (up or (lambda b: b))
 
 
@@ -4053,51 +4059,57 @@ def _radix_join(node: HashJoin, ctx: ExecContext,
     if ctx.config.spill_enabled:
         ctx.memory_pool.add_revoker(_revoke)
     try:
-        for b in build_stream:
-            rid = _radix_tag(b, P, node.right_keys)
-            if rid is not None:
-                ub = _untag_batch(b)
-                # num_live stays a device scalar — summed lazily so the
-                # aligned fast path adds no per-page sync
-                subs = [(rid, ub, ub.num_live())]
-                _stat("radix.aligned_batches", 1)
-                _scan_metrics.record("radix_aligned_batches", 1)
-            else:
-                subs = split_b(_untag_batch(b))
-            for p, sub, n in subs:
-                prows[p] = prows[p] + n
-                if p in bfiles:
-                    bfiles[p].append(sub)
-                    continue
-                parts[p].append(sub)
-                pbytes[p] += batch_device_bytes(sub)
-                if budget is not None and pbytes[p] > budget:
-                    spill_build_partition(p)
-            if rev["flag"]:
-                # revoke ladder asked for memory back: spill the LARGEST
-                # resident build partition down to host
-                rev["flag"] = False
-                resident = [(pp, pbytes[pp]) for pp in range(P)
-                            if parts[pp] and pp not in bfiles]
-                if resident:
-                    pp, nbytes = max(resident, key=lambda t: t[1])
-                    spill_build_partition(pp)
-                    _note_spill_revoke(node, ctx, nbytes)
-        prows = [int(r) for r in prows]
-        for p in range(P):
-            if prows[p]:
-                _obs_metrics.RADIX_PARTITION_ROWS.observe(
-                    prows[p], plane="worker", side="build")
+        # `join_build`: the drain and split of the build stream and the P
+        # partitions' tables (a hybrid-spilled partition's comes later)
+        with ctx.tracer.phase("join_build") as build_ph:
+            for b in build_stream:
+                build_ph.items += 1
+                rid = _radix_tag(b, P, node.right_keys)
+                if rid is not None:
+                    ub = _untag_batch(b)
+                    # num_live stays a device scalar — summed lazily so the
+                    # aligned fast path adds no per-page sync
+                    subs = [(rid, ub, ub.num_live())]
+                    _stat("radix.aligned_batches", 1)
+                    _scan_metrics.record("radix_aligned_batches", 1)
+                else:
+                    subs = split_b(_untag_batch(b))
+                for p, sub, n in subs:
+                    prows[p] = prows[p] + n
+                    if p in bfiles:
+                        bfiles[p].append(sub)
+                        continue
+                    parts[p].append(sub)
+                    pbytes[p] += batch_device_bytes(sub)
+                    if budget is not None and pbytes[p] > budget:
+                        spill_build_partition(p)
+                if rev["flag"]:
+                    # revoke ladder asked for memory back: spill the LARGEST
+                    # resident build partition down to host
+                    rev["flag"] = False
+                    resident = [(pp, pbytes[pp]) for pp in range(P)
+                                if parts[pp] and pp not in bfiles]
+                    if resident:
+                        pp, nbytes = max(resident, key=lambda t: t[1])
+                        spill_build_partition(pp)
+                        _note_spill_revoke(node, ctx, nbytes)
+            with ctx.tracer.phase("host_sync:join_build_rows"):
+                prows = [int(r) for r in prows]
+            for p in range(P):
+                if prows[p]:
+                    _obs_metrics.RADIX_PARTITION_ROWS.observe(
+                        prows[p], plane="worker", side="build")
 
-        ident = lambda bb: bb  # noqa: E731 — chain applied before the split
-        probers: Dict[int, _JoinProber] = {}
-        for p in range(P):
-            if p in bfiles:
-                continue
-            build_in = _host_concat(parts[p])
-            parts[p] = []
-            probers[p] = _JoinProber(node, ctx, build_in, ident,
-                                     jkey="radix_", fanout_scan=16)
+            # the chain is applied before the split
+            ident = lambda bb: bb  # noqa: E731
+            probers: Dict[int, _JoinProber] = {}
+            for p in range(P):
+                if p in bfiles:
+                    continue
+                build_in = _host_concat(parts[p])
+                parts[p] = []
+                probers[p] = _JoinProber(node, ctx, build_in, ident,
+                                         jkey="radix_", fanout_scan=16)
 
         jchain = _node_jit(node, "radix_pchain", lambda: chain)
         for raw in probe_stream:
@@ -4222,37 +4234,45 @@ def _join_with_spill(node: HashJoin, ctx: ExecContext,
     if can_spill:
         ctx.memory_pool.add_revoker(_revoke)
     try:
-        for b in build_stream:
-            nb = batch_device_bytes(b)
-            if can_spill and (rev["flag"] or ctx.should_spill(nb)):
-                est_p = _hbo_spill_partitions(node, ctx, "spill_join",
-                                              ctx.config.spill_partitions)
-                bspiller = ctx.spill_manager.partitioning_spiller(
-                    node.right_keys, est_p, "join-build",
-                    partition_budget_bytes=_spill_replay_budget(ctx),
-                    max_depth=max(0, ctx.config.spill_max_depth),
-                    on_grow=lambda child, pp: _note_spill_repartition(
-                        node, ctx, child, pp),
-                    on_spill=_inflight_spill_hook(node, ctx))
-                ctx.track_spill(bspiller)
-                for bb in build_batches:
-                    bspiller.spill(bb)
-                if rev["flag"]:
-                    _note_spill_revoke(node, ctx, mctx.bytes)
-                    rev["flag"] = False
-                build_batches = []
-                mctx.set_bytes(0)
-                bspiller.spill(b)
-                for bb in build_stream:
-                    bspiller.spill(bb)
-                break
-            build_batches.append(b)
-            mctx.set_bytes(mctx.bytes + nb)
+        # the host's share of the build: the drain of the build stream, the
+        # concat and the table's sort (or the hand-over to the spiller);
+        # the program calls and the waits on the exchange are its children
+        with ctx.tracer.phase("join_build") as build_ph:
+            for b in build_stream:
+                build_ph.items += 1
+                nb = batch_device_bytes(b)
+                if can_spill and (rev["flag"] or ctx.should_spill(nb)):
+                    est_p = _hbo_spill_partitions(node, ctx, "spill_join",
+                                                  ctx.config.spill_partitions)
+                    bspiller = ctx.spill_manager.partitioning_spiller(
+                        node.right_keys, est_p, "join-build",
+                        partition_budget_bytes=_spill_replay_budget(ctx),
+                        max_depth=max(0, ctx.config.spill_max_depth),
+                        on_grow=lambda child, pp: _note_spill_repartition(
+                            node, ctx, child, pp),
+                        on_spill=_inflight_spill_hook(node, ctx))
+                    ctx.track_spill(bspiller)
+                    for bb in build_batches:
+                        bspiller.spill(bb)
+                    if rev["flag"]:
+                        _note_spill_revoke(node, ctx, mctx.bytes)
+                        rev["flag"] = False
+                    build_batches = []
+                    mctx.set_bytes(0)
+                    bspiller.spill(b)
+                    for bb in build_stream:
+                        build_ph.items += 1
+                        bspiller.spill(bb)
+                    break
+                build_batches.append(b)
+                mctx.set_bytes(mctx.bytes + nb)
+            if bspiller is None:
+                prober = _JoinProber(
+                    node, ctx, _collect_concat(iter(build_batches)), chain,
+                    jkey=jkey)
 
         if bspiller is None:
-            build_in = _collect_concat(iter(build_batches))
-            yield from _join_probe(node, ctx, build_in, probe_stream, chain,
-                                   jkey=jkey)
+            yield from prober.probe_all(probe_stream)
             return
 
         # spill the (chained) probe side partitioned by the probe keys —
@@ -4690,7 +4710,8 @@ class _JoinProber:
             fp = _runstats.node_fingerprint(node, ctx.catalog)
             if fp is None:
                 return
-            actual = float(table_rows(self.table))
+            with ctx.tracer.phase("host_sync:join_build_rows"):
+                actual = float(table_rows(self.table))
             if actual <= 0:
                 return
             try:
@@ -4741,101 +4762,132 @@ class _JoinProber:
         unconditionally while `total` travels to the host (it is usually
         the only chunk). The radix driver starts ALL partitions of a batch
         before finishing any, so the P count round trips overlap instead
-        of serializing."""
+        of serializing. The state carries the batch's `join_probe` phase:
+        `probe_finish` enters it again."""
         if self.empty:
             return None
         node, table = self.node, self.table
-        if node.build_unique:
-            out, self.bm, n_probe = self.jfn(table, pb_raw, self.bm)
-            self._n_probe = self._n_probe + n_probe
-            return ("u", out)
-        pb, pba = self.chain_j(table, pb_raw)
-        self._n_probe = self._n_probe + jnp.sum(pb.live)
-        lo, counts, offsets, total, _, ovf = self.counts_fn(table, pba)
-        try:
-            total.copy_to_host_async()
-            ovf.copy_to_host_async()
-        except Exception:
-            pass
-        out_cap = self.ctx.config.join_out_capacity or pb.capacity
-        out, exists_acc, self.bm = self.jexpand(
-            table, pb, pba, lo, counts, offsets, 0, out_cap, self.bm)
-        return ("g", pb, pba, lo, counts, offsets, total, ovf, out_cap,
-                out, exists_acc)
+        ph = self.ctx.tracer.phase("join_probe")
+        with ph:
+            if node.build_unique:
+                out, self.bm, n_probe = self.jfn(table, pb_raw, self.bm)
+                self._n_probe = self._n_probe + n_probe
+                self._n_out = self._n_out + jnp.sum(out.live)
+                ph.items = 1
+                return ("u", out)
+            pb, pba = self.chain_j(table, pb_raw)
+            self._n_probe = self._n_probe + jnp.sum(pb.live)
+            lo, counts, offsets, total, _, ovf = self.counts_fn(table, pba)
+            try:
+                total.copy_to_host_async()
+                ovf.copy_to_host_async()
+            except Exception:
+                pass
+            out_cap = self.ctx.config.join_out_capacity or pb.capacity
+            out, exists_acc, self.bm = self.jexpand(
+                table, pb, pba, lo, counts, offsets, 0, out_cap, self.bm)
+            return ("g", pb, pba, lo, counts, offsets, total, ovf, out_cap,
+                    out, exists_acc, ph)
 
     def probe_finish(self, st) -> Iterator[Batch]:
+        """The chunks of one started probe batch. The batch's `join_probe`
+        phase is left before each chunk is handed on (the consumer's time
+        is not the probe's) and entered again after; `items` counts the
+        chunks."""
         if st is None:
             return
-        node, table = self.node, self.table
         if st[0] == "u":
-            self._n_out = self._n_out + jnp.sum(st[1].live)
             yield st[1]
             return
+        node, table, phase = self.node, self.table, self.ctx.tracer.phase
         (_, pb, pba, lo, counts, offsets, total, ovf, out_cap, out,
-         exists_acc) = st
-        # the sort engine's overflow is informational (counts already
-        # widened) and syncs after the chunk loop; the hash engine's must
-        # be confirmed BEFORE chunk 0 is yielded
-        ovn = int(ovf) if self.engine == "hash" else 0
-        if ovn:
-            # hash-engine fanout overflow: counts/total are EXACT but the
-            # match matrix truncated past its width — the optimistically
-            # dispatched chunk 0 would duplicate the last held match, so
-            # discard it, re-probe at doubled widths until every row fits,
-            # and redo chunk 0 from the full matrix. (The discarded
-            # chunk's bm/exists updates only marked GENUINE matches, so
-            # they stand.) Counts don't change, so no re-cumsum drift.
-            ov_rows = ovn
-            fanout = self.fanout_scan
-            while ovn:
-                fanout *= 2
-                if fanout > int(self.table.slot_row.shape[0]):
-                    raise RuntimeError(
-                        "join fanout exceeded build table capacity")
-                _bump_replay_wave(node, self.ctx, cap_to=fanout)
-                lo, counts, offsets, total, _, ovf = self._counts_program(
-                    fanout)(table, pba)
-                ovn = int(ovf)
-            out, exists, self.bm = self.jexpand(
-                table, pb, pba, lo, counts, offsets, 0, out_cap, self.bm)
-            exists_acc = exists_acc | exists
-            ovn = ov_rows  # recorded after the chunk loop
-        self._n_out = self._n_out + jnp.sum(out.live)
+         exists_acc, ph) = st
+        with ph:
+            # the sort engine's overflow is informational (counts already
+            # widened) and syncs after the chunk loop; the hash engine's
+            # must be confirmed BEFORE chunk 0 is yielded
+            ovn = 0
+            if self.engine == "hash":
+                with phase("host_sync:join_overflow"):
+                    ovn = int(ovf)
+            if ovn:
+                # hash-engine fanout overflow: counts/total are EXACT but
+                # the match matrix truncated past its width — the
+                # optimistically dispatched chunk 0 would duplicate the
+                # last held match, so discard it, re-probe at doubled
+                # widths until every row fits, and redo chunk 0 from the
+                # full matrix. (The discarded chunk's bm/exists updates
+                # only marked GENUINE matches, so they stand.) Counts
+                # don't change, so no re-cumsum drift.
+                ov_rows = ovn
+                fanout = self.fanout_scan
+                while ovn:
+                    fanout *= 2
+                    if fanout > int(self.table.slot_row.shape[0]):
+                        raise RuntimeError(
+                            "join fanout exceeded build table capacity")
+                    _bump_replay_wave(node, self.ctx, cap_to=fanout)
+                    lo, counts, offsets, total, _, ovf = \
+                        self._counts_program(fanout)(table, pba)
+                    with phase("host_sync:join_overflow"):
+                        ovn = int(ovf)
+                out, exists, self.bm = self.jexpand(
+                    table, pb, pba, lo, counts, offsets, 0, out_cap, self.bm)
+                exists_acc = exists_acc | exists
+                ovn = ov_rows  # recorded after the chunk loop
+            self._n_out = self._n_out + jnp.sum(out.live)
+            ph.items = 1
         yield out
-        tot = int(total)
+        with ph:
+            with phase("host_sync:join_total"):
+                tot = int(total)
         base = out_cap
         while base < tot:
-            out, exists, self.bm = self.jexpand(
-                table, pb, pba, lo, counts, offsets, base, out_cap, self.bm)
-            exists_acc = exists_acc | exists
-            self._n_out = self._n_out + jnp.sum(out.live)
+            with ph:
+                out, exists, self.bm = self.jexpand(
+                    table, pb, pba, lo, counts, offsets, base, out_cap,
+                    self.bm)
+                exists_acc = exists_acc | exists
+                self._n_out = self._n_out + jnp.sum(out.live)
+                ph.items = 1
             yield out
             base += out_cap
-        if self.engine != "hash":
-            ovn = int(ovf)
-        if ovn:
-            from presto_tpu.scan import metrics as _scan_metrics
+        nb = None
+        with ph:
+            if self.engine != "hash":
+                with phase("host_sync:join_overflow"):
+                    ovn = int(ovf)
+            if ovn:
+                from presto_tpu.scan import metrics as _scan_metrics
 
-            self.overflow_rows += ovn
-            key = "join.fanout_overflow_rows"
-            self.ctx.stats[key] = self.ctx.stats.get(key, 0) + ovn
-            _scan_metrics.record("join_fanout_overflow_rows", ovn)
-            if getattr(self.ctx.config, "hbo", "observe") != "off":
-                try:
-                    from presto_tpu.obs import runstats as _runstats
+                self.overflow_rows += ovn
+                key = "join.fanout_overflow_rows"
+                self.ctx.stats[key] = self.ctx.stats.get(key, 0) + ovn
+                _scan_metrics.record("join_fanout_overflow_rows", ovn)
+                if getattr(self.ctx.config, "hbo", "observe") != "off":
+                    try:
+                        from presto_tpu.obs import runstats as _runstats
 
-                    _runstats.note(
-                        _runstats.node_fingerprint(node, self.ctx.catalog),
-                        "join_build", fanout_overflow_rows=ovn)
-                except Exception:
-                    pass
-        if node.kind in ("left", "full"):
-            nb = self.jnull(table, pb, exists_acc)
-            self._n_out = self._n_out + jnp.sum(nb.live)
+                        _runstats.note(
+                            _runstats.node_fingerprint(node,
+                                                       self.ctx.catalog),
+                            "join_build", fanout_overflow_rows=ovn)
+                    except Exception:
+                        pass
+            if node.kind in ("left", "full"):
+                nb = self.jnull(table, pb, exists_acc)
+                self._n_out = self._n_out + jnp.sum(nb.live)
+                ph.items = 1
+        if nb is not None:
             yield nb
 
     def probe_batch(self, pb_raw: Batch) -> Iterator[Batch]:
         yield from self.probe_finish(self.probe_start(pb_raw))
+
+    def probe_all(self, probe_stream: Iterator[Batch]) -> Iterator[Batch]:
+        for pb in probe_stream:
+            yield from self.probe_batch(pb)
+        yield from self.tail()
 
     def tail(self) -> Iterator[Batch]:
         if not self.empty and self.want_full:
@@ -4859,7 +4911,10 @@ class _JoinProber:
             from presto_tpu.obs import runstats as _runstats
             from presto_tpu.plan.stats import derive as _derive
 
-            n_probe = float(self._n_probe)
+            # the stream's end: the first read that waits for every probe
+            # the device still has queued
+            with ctx.tracer.phase("host_sync:join_selectivity"):
+                n_probe = float(self._n_probe)
             if n_probe <= 0:
                 return
             fp = _runstats.node_fingerprint(self.node, ctx.catalog)
@@ -4884,10 +4939,8 @@ class _JoinProber:
 def _join_probe(node: HashJoin, ctx: ExecContext, build_in: Optional[Batch],
                 probe_stream: Iterator[Batch], chain,
                 jkey: str = "") -> Iterator[Batch]:
-    prober = _JoinProber(node, ctx, build_in, chain, jkey=jkey)
-    for pb in probe_stream:
-        yield from prober.probe_batch(pb)
-    yield from prober.tail()
+    yield from _JoinProber(node, ctx, build_in, chain,
+                           jkey=jkey).probe_all(probe_stream)
 
 
 # ---------------------------------------------------------------------------
@@ -5124,7 +5177,8 @@ class _MultiwayProber:
             for i, fp in enumerate(leg_fps):
                 if fp is None or i >= len(self.tables):
                     continue
-                actual = float(table_rows(self.tables[i]))
+                with ctx.tracer.phase("host_sync:join_build_rows"):
+                    actual = float(table_rows(self.tables[i]))
                 if actual <= 0:
                     continue
                 try:
@@ -5143,60 +5197,76 @@ class _MultiwayProber:
         if self.empty:
             return
         node, ctx, tables = self.node, self.ctx, self.tables
+        # one `join_probe` occurrence a batch, left before each chunk is
+        # handed on and entered again after (as _JoinProber.probe_finish)
+        phase = ctx.tracer.phase
+        ph = phase("join_probe")
         if self.all_unique:
-            out, n_probe, n_leg0 = self.junique(tables, pb_raw)
-            self._n_probe = self._n_probe + n_probe
-            self._n_leg0 = self._n_leg0 + n_leg0
-            self._n_out = self._n_out + jnp.sum(out.live)
+            with ph:
+                out, n_probe, n_leg0 = self.junique(tables, pb_raw)
+                self._n_probe = self._n_probe + n_probe
+                self._n_leg0 = self._n_leg0 + n_leg0
+                self._n_out = self._n_out + jnp.sum(out.live)
+                ph.items = 1
             yield out
             return
-        fanouts = self.fanouts
-        (pb, state, chats, offsets, T, total,
-         ovfs) = self._counts_program(fanouts)(tables, pb_raw)
-        try:
-            total.copy_to_host_async()
-            ovfs.copy_to_host_async()
-        except Exception:
-            pass
-        out_cap = ctx.config.join_out_capacity or pb.capacity
-        # optimistic chunk-0 dispatch while total/ovfs travel to the host
-        out = self.jexpand(tables, pb, state, chats, offsets, T, 0, out_cap)
-        ovn = np.asarray(ovfs)
-        if int(ovn.sum()):
-            # hash-leg fanout overflow: counts are EXACT but that leg's
-            # match matrix truncated — the dispatched chunk 0 would
-            # duplicate its last held match, so discard it, double the
-            # overflowing legs' widths until every row fits, and redo
-            # chunk 0 (the widening-replay ladder, per table)
-            ov_rows = int(ovn.sum())
-            while int(ovn.sum()):
-                fanouts = tuple(
-                    f * 2 if int(ovn[i]) else f
-                    for i, f in enumerate(fanouts))
-                for i, f in enumerate(fanouts):
-                    if (self.specs[i].hash_engine
-                            and f > int(tables[i].slot_row.shape[0])):
-                        raise RuntimeError(
-                            "multiway join fanout exceeded build table "
-                            f"capacity on leg {i}")
-                _bump_replay_wave(node, ctx, cap_to=max(fanouts))
-                (pb, state, chats, offsets, T, total,
-                 ovfs) = self._counts_program(fanouts)(tables, pb_raw)
-                ovn = np.asarray(ovfs)
+        with ph:
+            fanouts = self.fanouts
+            (pb, state, chats, offsets, T, total,
+             ovfs) = self._counts_program(fanouts)(tables, pb_raw)
+            try:
+                total.copy_to_host_async()
+                ovfs.copy_to_host_async()
+            except Exception:
+                pass
+            out_cap = ctx.config.join_out_capacity or pb.capacity
+            # optimistic chunk-0 dispatch while total/ovfs travel to the
+            # host
             out = self.jexpand(tables, pb, state, chats, offsets, T, 0,
                                out_cap)
-            self._note_overflow(ov_rows, ovn)
-        self._n_probe = self._n_probe + jnp.sum(pb.live)
-        self._n_leg0 = self._n_leg0 + jnp.sum(
-            jnp.where(pb.live, chats[0], 0))
-        self._n_out = self._n_out + jnp.sum(out.live)
+            with phase("host_sync:join_overflow"):
+                ovn = np.asarray(ovfs)
+            if int(ovn.sum()):
+                # hash-leg fanout overflow: counts are EXACT but that leg's
+                # match matrix truncated — the dispatched chunk 0 would
+                # duplicate its last held match, so discard it, double the
+                # overflowing legs' widths until every row fits, and redo
+                # chunk 0 (the widening-replay ladder, per table)
+                ov_rows = int(ovn.sum())
+                while int(ovn.sum()):
+                    fanouts = tuple(
+                        f * 2 if int(ovn[i]) else f
+                        for i, f in enumerate(fanouts))
+                    for i, f in enumerate(fanouts):
+                        if (self.specs[i].hash_engine
+                                and f > int(tables[i].slot_row.shape[0])):
+                            raise RuntimeError(
+                                "multiway join fanout exceeded build table "
+                                f"capacity on leg {i}")
+                    _bump_replay_wave(node, ctx, cap_to=max(fanouts))
+                    (pb, state, chats, offsets, T, total,
+                     ovfs) = self._counts_program(fanouts)(tables, pb_raw)
+                    with phase("host_sync:join_overflow"):
+                        ovn = np.asarray(ovfs)
+                out = self.jexpand(tables, pb, state, chats, offsets, T, 0,
+                                   out_cap)
+                self._note_overflow(ov_rows, ovn)
+            self._n_probe = self._n_probe + jnp.sum(pb.live)
+            self._n_leg0 = self._n_leg0 + jnp.sum(
+                jnp.where(pb.live, chats[0], 0))
+            self._n_out = self._n_out + jnp.sum(out.live)
+            ph.items = 1
         yield out
-        tot = int(total)
+        with ph:
+            with phase("host_sync:join_total"):
+                tot = int(total)
         base = out_cap
         while base < tot:
-            out = self.jexpand(tables, pb, state, chats, offsets, T, base,
-                               out_cap)
-            self._n_out = self._n_out + jnp.sum(out.live)
+            with ph:
+                out = self.jexpand(tables, pb, state, chats, offsets, T,
+                                   base, out_cap)
+                self._n_out = self._n_out + jnp.sum(out.live)
+                ph.items = 1
             yield out
             base += out_cap
 
@@ -5233,7 +5303,8 @@ class _MultiwayProber:
         try:
             from presto_tpu.obs import runstats as _runstats
 
-            n_probe = float(self._n_probe)
+            with ctx.tracer.phase("host_sync:join_selectivity"):
+                n_probe = float(self._n_probe)
             if n_probe <= 0:
                 return
             leg0_sel = float(self._n_leg0) / n_probe
@@ -5331,23 +5402,31 @@ def _execute_multiway_join(node: MultiwayJoin,
         pressure_at = None
         partial: List[Batch] = []
         bstream = None
-        for i in range(N):
-            bstream = execute_node(node.builds[i], ctx)
-            partial = []
-            for b in bstream:
-                nb = batch_device_bytes(b)
-                if can_spill and (rev["flag"] or ctx.should_spill(nb)):
-                    rev["flag"] = False
-                    pressure_at = i
+        # one `join_build` for the node's N build sides (as
+        # _join_with_spill has it for one)
+        with ctx.tracer.phase("join_build") as build_ph:
+            for i in range(N):
+                bstream = execute_node(node.builds[i], ctx)
+                partial = []
+                for b in bstream:
+                    nb = batch_device_bytes(b)
+                    if can_spill and (rev["flag"] or ctx.should_spill(nb)):
+                        rev["flag"] = False
+                        pressure_at = i
+                        partial.append(b)
+                        break
                     partial.append(b)
+                    total_bytes += nb
+                    mctx.set_bytes(total_bytes)
+                if pressure_at is not None:
                     break
-                partial.append(b)
-                total_bytes += nb
-                mctx.set_bytes(total_bytes)
-            if pressure_at is not None:
-                break
-            collected.append(partial)
-            partial, bstream = [], None
+                collected.append(partial)
+                partial, bstream = [], None
+            if pressure_at is None:
+                prober = _MultiwayProber(
+                    node, ctx,
+                    [_collect_concat(iter(bb)) for bb in collected], chain)
+            build_ph.items = sum(map(len, collected)) + len(partial)
 
         if pressure_at is not None:
             yield from _mw_binary_cascade(
@@ -5355,8 +5434,6 @@ def _execute_multiway_join(node: MultiwayJoin,
                 partial, bstream, "build memory pressure")
             return
 
-        builds_in = [_collect_concat(iter(bb)) for bb in collected]
-        prober = _MultiwayProber(node, ctx, builds_in, chain)
         if prober.cascade is not None:
             yield from _mw_binary_cascade(
                 node, ctx, probe_stream, chain, collected, None, [], None,

@@ -183,48 +183,61 @@ class _ThreadPhases:
 
 
 class _Phase:
-    """Context for one occurrence of a phase; see Tracer.phase()."""
+    """Context for one occurrence of a phase; see Tracer.phase(). A
+    generator leaves it before each `yield` and enters it again after
+    (`with ph:` more than once): the time adds up and the occurrence counts
+    once. `items` may be set or added to while it is open, where the count
+    is known only at the end; it is added at each exit and starts again at
+    0."""
 
-    __slots__ = ("_st", "_name", "_ann", "_items", "_t0", "_outer_child_s")
+    __slots__ = ("_st", "_name", "_annotate", "_ann", "items", "_t0",
+                 "_outer_child_s", "_counted")
 
-    def __init__(self, st: _ThreadPhases, name: str, ann, items: int):
+    def __init__(self, st: _ThreadPhases, name: str, annotate: bool,
+                 items: int):
         self._st = st
         self._name = name
-        self._ann = ann
-        self._items = items
+        self._annotate = annotate
+        self.items = items
+        self._counted = False
 
     def __enter__(self):
         st = self._st
         self._outer_child_s = st.child_s
         st.child_s = 0.0
-        if self._ann is not None:
+        if self._annotate:  # it starts when it is made: one to each entry
+            self._ann = _Annotation("engine:" + self._name)
             self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
         t1 = time.perf_counter()
-        if self._ann is not None:
+        if self._annotate:
             self._ann.__exit__(None, None, None)
         st = self._st
         dt = t1 - self._t0
         a = st.aggs.get(self._name)
         if a is None:
             a = st.aggs[self._name] = [0, 0.0, 0.0, 0.0, self._t0, t1, 0,
-                                       self._ann is None]
-        a[0] += 1
+                                       not self._annotate]
+        if not self._counted:
+            a[0] += 1
+            self._counted = True
         a[1] += dt
         a[2] += dt - st.child_s
         if dt > a[3]:
             a[3] = dt
         a[5] = t1
-        a[6] += self._items
+        a[6] += self.items
+        self.items = 0
         st.child_s = self._outer_child_s + dt
         return False
 
 
 class _NoopPhase:
     __slots__ = ()
+    items = property(lambda self: 0, lambda self, n: None)
 
     def __enter__(self):
         return self
@@ -269,8 +282,7 @@ class Tracer:
         phase on this thread (module docstring). `wait=True` marks time
         spent blocked: aggregated, never annotated. `items` counts what
         the occurrence handled (batches stacked)."""
-        return _Phase(self._phases_here(), name,
-                      None if wait else _Annotation("engine:" + name), items)
+        return _Phase(self._phases_here(), name, not wait, items)
 
     def _stack(self) -> list:
         st = getattr(self._tls, "stack", None)

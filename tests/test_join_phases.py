@@ -1,0 +1,151 @@
+"""The join path's engine phases (`exec/runtime.py`: `_join_with_spill`,
+`_JoinProber`): TPC-H Q3 at SF 0.01 through the served path leaves
+`join_build`, `join_probe` and the `host_sync:join_*` sites in the
+statement's summary, as often as the plan has joins and probe batches; with
+`tracing=false` the answer is the same and nothing is recorded; a statement
+without a join records none of them. And the one thing the tracer learned
+for it: an occurrence that a generator leaves before a `yield` and enters
+again after counts once."""
+
+import json
+import math
+import os
+import time
+
+import pytest
+
+from presto_tpu import client
+from presto_tpu.obs import trace as obs_trace
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+BATCH = 8192
+JOIN_PHASES = ("join_build", "join_probe", "host_sync:join_build_rows",
+               "host_sync:join_total", "host_sync:join_overflow",
+               "host_sync:join_output_rows", "host_sync:join_selectivity")
+
+
+def query_text(qid):
+    with open(os.path.join(ROOT, "benchmark", "queries", qid + ".json")) as f:
+        params = json.load(f)["params"]["fixed"]
+    with open(os.path.join(ROOT, "benchmark", "queries", qid + ".sql")) as f:
+        return f.read().format(**params).strip()
+
+
+@pytest.fixture(scope="module")
+def url():
+    from presto_tpu.server.__main__ import build_catalog
+    from presto_tpu.server.coordinator import DistributedRunner
+
+    with DistributedRunner(build_catalog(["tpch:sf=0.01"]), n_workers=1) as dr:
+        yield dr.coordinator.url
+
+
+def statement(url, sql, **properties):
+    """(rows, the statement's summary or None) over `/v1/statement`."""
+    session = client.ClientSession(user="test")
+    session.properties.update(batch_rows=str(BATCH), **properties)
+    before = {d["queryId"] for d in obs_trace.summaries()}
+    _, rows = client.execute(url, sql, session)
+    new = [d for d in obs_trace.summaries() if d["queryId"] not in before]
+    assert len(new) <= 1
+    return rows, (new[0] if new else None)
+
+
+def all_phases(summary):
+    return {name: agg for by_name in summary["phases"].values()
+            for name, agg in by_name.items()}
+
+
+def test_q3_records_a_phase_for_every_join_and_every_probe_batch(url):
+    (orders,), = statement(url, "select count(*) from orders")[0]
+    (lineitem,), = statement(url, "select count(*) from lineitem")[0]
+    batches = math.ceil(orders / BATCH) + math.ceil(lineitem / BATCH)
+    statement(url, query_text("q3"))              # compile
+    rows, first = statement(url, query_text("q3"))
+    rows_again, second = statement(url, query_text("q3"))
+    assert rows == rows_again and len(rows) == 10
+    task = first["phases"]["task"]
+    # two joins: customer built and probed by orders (a unique build), that
+    # join's output built and probed by lineitem (the general path)
+    assert task["join_build"]["n"] == 2
+    assert task["join_build"]["items"] >= 2      # build batches drained
+    assert task["host_sync:join_build_rows"]["n"] == 2
+    assert task["host_sync:join_selectivity"]["n"] == 2
+    # one occurrence a probe batch, one chunk each at the shipped
+    # join_out_capacity
+    assert task["join_probe"]["n"] == task["join_probe"]["items"] == batches
+    # the general path reads `total` and the overflow count once a batch
+    general = math.ceil(lineitem / BATCH)
+    assert task["host_sync:join_total"]["n"] == general
+    assert task["host_sync:join_overflow"]["n"] == general
+    # and each join's output is counted once a batch where it is merged
+    assert task["host_sync:join_output_rows"]["n"] == batches
+    # the program calls and the reads are the phases' children: what is
+    # left to `join_build` and `join_probe` is the host's own share
+    for name in ("join_build", "join_probe"):
+        assert 0 <= task[name]["self_s"] < task[name]["busy_s"]
+    named = sum(agg["self_s"] for agg in task.values())
+    assert 0 < named <= first["task_wall_s"] * 1.01
+    # the counts repeat exactly
+    counts = {k: (v["n"], v.get("items")) for k, v in task.items()}
+    assert counts == {k: (v["n"], v.get("items"))
+                      for k, v in second["phases"]["task"].items()}
+
+
+def test_tracing_off_gives_the_same_answer_and_records_nothing(url):
+    rows, summary = statement(url, query_text("q3"))
+    assert summary is not None
+    rows_off, none = statement(url, query_text("q3"), tracing="false")
+    assert rows_off == rows
+    assert none is None
+
+
+def test_a_small_join_out_capacity_adds_chunks_not_occurrences(url):
+    rows, whole = statement(url, query_text("q3"))
+    rows_cut, cut = statement(url, query_text("q3"), join_out_capacity="16")
+    assert rows_cut == rows
+    probe, probe_cut = (d["phases"]["task"]["join_probe"] for d in (whole, cut))
+    # the general path's batches now yield their matches 16 rows at a time:
+    # the phase is left before each chunk and entered again after it
+    assert probe_cut["n"] == probe["n"] == probe["items"]
+    assert probe_cut["items"] > probe_cut["n"]
+
+
+@pytest.mark.parametrize("qid", ["q6", "q1"])
+def test_a_statement_without_a_join_records_no_join_phase(url, qid):
+    _, summary = statement(url, query_text(qid))
+    phases = all_phases(summary)
+    assert not set(JOIN_PHASES) & set(phases)
+    assert not any(n.startswith("host_sync:join_") for n in phases)
+
+
+def test_an_occurrence_left_before_a_yield_counts_once():
+    tr = obs_trace.Tracer()
+
+    def chunks():
+        ph = tr.phase("join_probe")
+        for _ in range(3):
+            with ph:
+                time.sleep(0.002)
+                ph.items = 1
+            yield                     # the consumer's time is not the probe's
+
+    with tr.span("task", "task"):
+        for _ in chunks():
+            time.sleep(0.1)
+        with tr.phase("join_build") as build:
+            build.items = 5               # known only at the end
+    (by_name,) = obs_trace.phases_by_role(tr.spans()).values()
+    probe = by_name["join_probe"]
+    assert probe["n"] == 1 and probe["items"] == 3
+    assert 0.006 <= probe["busy_s"] < 0.15    # three stretches, not the waits
+    assert probe["max_s"] < 0.1
+    assert by_name["join_build"]["n"] == 1
+    assert by_name["join_build"]["items"] == 5
+    # with tracing off the same code talks to the no-op phase
+    noop = obs_trace.NOOP.phase("join_probe")
+    with noop:
+        noop.items = 1
+    with noop:
+        pass
+    assert noop.items == 0
