@@ -64,7 +64,7 @@ from presto_tpu.ops.join import (
     probe_counts,
     probe_expand,
     probe_unique,
-    table_rows,
+    table_stats,
 )
 from presto_tpu.ops.sort import (
     SortKey,
@@ -4498,6 +4498,21 @@ def _join_plan_cdt(node) -> tuple:
         for lk, rk in zip(node.left_keys, node.right_keys))
 
 
+def _observe_build_table(ctx: "ExecContext", table) -> float:
+    """A built join table's live row count, read once from the device; the
+    sorted engine's bucket-search steps ride in the same transfer and are
+    reported as `items` of one `join_search` occurrence a build."""
+    with ctx.tracer.phase("host_sync:join_build_rows"):
+        rows, steps = table_stats(table)
+    if steps is not None:
+        from presto_tpu.scan import metrics as _scan_metrics
+
+        with ctx.tracer.phase("join_search", items=steps):
+            pass
+        _scan_metrics.record("join_search_steps", steps)
+    return float(rows)
+
+
 class _JoinProber:
     """One build table, probed incrementally.
 
@@ -4710,8 +4725,7 @@ class _JoinProber:
             fp = _runstats.node_fingerprint(node, ctx.catalog)
             if fp is None:
                 return
-            with ctx.tracer.phase("host_sync:join_build_rows"):
-                actual = float(table_rows(self.table))
+            actual = _observe_build_table(ctx, self.table)
             if actual <= 0:
                 return
             try:
@@ -5177,8 +5191,7 @@ class _MultiwayProber:
             for i, fp in enumerate(leg_fps):
                 if fp is None or i >= len(self.tables):
                     continue
-                with ctx.tracer.phase("host_sync:join_build_rows"):
-                    actual = float(table_rows(self.tables[i]))
+                actual = _observe_build_table(ctx, self.tables[i])
                 if actual <= 0:
                     continue
                 try:

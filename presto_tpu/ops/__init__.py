@@ -3,8 +3,10 @@
 The analog of presto-main's hot operator internals (MultiChannelGroupByHash,
 PagesHash/JoinHash, PagesIndex sort, PartitionedOutputOperator.partitionPage),
 re-expressed as static-shape XLA programs: sorting + segment ops instead of
-pointer-chasing hash tables, searchsorted probes instead of bucket chains,
-masks instead of selection vectors.
+pointer-chasing hash tables, masks instead of selection vectors. A join
+probe reaches its rows of the sorted build through a directory of hash
+buckets (one gather of a bucket's ends and a few halvings inside it), not
+through bucket chains and not through a binary search of the whole build.
 """
 
 from presto_tpu.ops.hashing import hash_columns

@@ -1,21 +1,27 @@
-"""Hash join kernels: sorted build + searchsorted probe.
+"""Hash join kernels: sorted build + bucket-directory probe.
 
 Reference: operator/HashBuilderOperator.java (build), PagesHash.java:34,152 /
 JoinHash + PositionLinks chains (probe), LookupJoinOperator.java:392-460
 (probe loop with yielding output builder).
 
 TPU-native redesign: no pointer chains. The build side is *sorted by a
-64-bit key hash*; a probe is two vectorized binary searches
-(searchsorted left/right) giving each probe row its candidate range
-[lo, hi). Range semantics replace PositionLinks. Because we join on the
-hash, candidates are verified against the actual key columns (exact
-semantics even under hash collisions).
+64-bit key hash* and carries a directory over the hash's top bits: one
+bucket per slot of capacity, `dir[b]` = sorted position where bucket b
+starts. A probe row reads its bucket's two ends, finishes with a halving
+search *inside the bucket* (as many rounds as the build's fullest bucket
+needs — a handful for uniform hashes, whatever the build's size) and reads
+the end of its run of equal hashes from `run_end`: its candidate range
+[lo, hi), the same one two binary searches over the whole build would
+give. Range semantics replace PositionLinks. Because we join on the hash,
+candidates are verified against the actual key columns (exact semantics
+even under hash collisions).
 
 Fanout handling (the LookupJoinPageBuilder analog): a counts pass computes
 per-probe match counts and a prefix sum; materialization maps each output
-slot i back to (probe_row, ordinal) with one searchsorted over the prefix
-sums — fully vectorized, chunked by the driver when total matches exceed the
-output capacity.
+slot i back to (probe_row, ordinal) by counting the prefix sums' ends at
+or below i — one scatter-add of the ends and a running sum — fully
+vectorized, chunked by the driver when total matches exceed the output
+capacity.
 """
 
 from __future__ import annotations
@@ -43,6 +49,16 @@ class BuildTable(NamedTuple):
     batch: Batch
     n_rows: jnp.ndarray  # device scalar
     orig_live: jnp.ndarray  # bool[cap], aligned with batch
+    # bucket(h) = h >> (63 - k), k = ceil(log2(cap)): monotone in the sort
+    # order. dir[b] = live sorted hashes whose bucket is below b, so bucket
+    # b is [dir[b], dir[b + 1]) and dir[2^k] = n_rows (dead lanes kept out)
+    dir: jnp.ndarray  # int32[2^k + 1]
+    # run_end[i] = first position past i whose hash differs from hashes[i]
+    run_end: jnp.ndarray  # int32[cap]
+    # halving rounds the fullest bucket needs, ceil(log2(largest + 1)): the
+    # probe's loop bound, a device scalar the host reads only where it
+    # reads n_rows (table_stats)
+    search_steps: jnp.ndarray
 
 
 _SENTINEL = jnp.iinfo(jnp.int64).max
@@ -98,10 +114,58 @@ def build_side(batch: Batch, key_names: Sequence[str]) -> BuildTable:
     sorted_h, sperm = jax.lax.sort([h, perm], num_keys=1)
     sorted_batch = permute_batch(batch.with_live(live), sperm)
     n = jnp.sum(live.astype(jnp.int64))
-    return BuildTable(sorted_h, sorted_batch, n, batch.live[sperm])
+    dir_, run_end, steps = _bucket_directory(sorted_h, n)
+    return BuildTable(sorted_h, sorted_batch, n, batch.live[sperm],
+                      dir_, run_end, steps)
+
+
+def _bucket_shift(cap: int) -> int:
+    """63 - k for a build of `cap` lanes: k = ceil(log2(cap)) top bits of a
+    non-negative hash name its bucket."""
+    return 63 - (cap - 1).bit_length()
+
+
+def _running_sum(v: jnp.ndarray, width: int = 1024) -> jnp.ndarray:
+    """jnp.cumsum of a 1-D integer vector, as rows of `width` lanes summed
+    along with the rows' totals carried over: the same numbers in a form
+    the TPU's compiler takes in a second, where the flat scan of a build's
+    2^21 lanes costs it 5-10 s and a cummin 30-55 s (CHANGES.md, PR 28)."""
+    n = v.shape[0]
+    if n <= width:
+        return jnp.cumsum(v)
+    rows = jnp.pad(v, (0, -n % width)).reshape(-1, width)
+    within = jnp.cumsum(rows, axis=1)
+    before = jnp.cumsum(within[:, -1]) - within[:, -1]
+    return (within + before[:, None]).reshape(-1)[:n]
+
+
+def _bucket_directory(sorted_h: jnp.ndarray, n_rows):
+    """(dir, run_end, search_steps) of BuildTable over hashes already
+    sorted: histograms of the lanes' bucket and run ids and running sums —
+    no search."""
+    cap = sorted_h.shape[0]
+    shift = _bucket_shift(cap)
+    pos = jnp.arange(cap, dtype=jnp.int32)
+    bucket = (sorted_h >> shift).astype(jnp.int32)
+    sizes = jnp.zeros(1 << (63 - shift), jnp.int32).at[bucket].add(
+        (pos < n_rows).astype(jnp.int32), indices_are_sorted=True,
+        mode="promise_in_bounds")
+    steps = 32 - jax.lax.clz(jnp.max(sizes))
+    dir_ = jnp.concatenate([jnp.zeros(1, jnp.int32), _running_sum(sizes)])
+    # runs of equal hashes, numbered from 1: a run ends where the next one
+    # starts (slot 0 takes the writes of the lanes that start none)
+    first = jnp.concatenate(
+        [jnp.ones((1,), bool), sorted_h[1:] != sorted_h[:-1]])
+    run = _running_sum(first.astype(jnp.int32))
+    starts = jnp.full(cap + 2, cap, jnp.int32).at[
+        jnp.where(first, run, 0)].set(pos, mode="promise_in_bounds")
+    return dir_, starts[run + 1], steps
 
 
 def _probe_ranges(table: BuildTable, probe: Batch, key_names: Sequence[str]):
+    """Candidate range [lo, hi) of every probe row in the sorted build:
+    what searching `table.hashes` for the row's hash from the left and from
+    the right would give."""
     h = join_hash(probe, key_names)
     live = probe.live
     for k in key_names:
@@ -109,9 +173,37 @@ def _probe_ranges(table: BuildTable, probe: Batch, key_names: Sequence[str]):
         if v is not None:
             live = live & v
     h = jnp.where(live, h, _SENTINEL - 1)  # never matches a real hash*
-    lo = jnp.searchsorted(table.hashes, h, side="left")
-    hi = jnp.searchsorted(table.hashes, h, side="right")
+    hashes = table.hashes
+    cap = hashes.shape[0]
+    bucket = (h >> _bucket_shift(cap)).astype(jnp.int32)
+
+    def halve(_, ends):
+        lo, hi = ends
+        mid = (lo + hi) >> 1
+        below = (lo < hi) & (hashes[mid] < h)
+        return jnp.where(below, mid + 1, lo), jnp.where(below, hi, mid)
+
+    lo, _ = jax.lax.fori_loop(
+        0, table.search_steps, halve,
+        (table.dir[bucket], table.dir[bucket + 1]))
+    at = jnp.minimum(lo, cap - 1)
+    hi = jnp.where(hashes[at] == h, table.run_end[at], lo)
     return h, lo, hi, live
+
+
+def _slot_rows(ends: jnp.ndarray, chunk_base, out_capacity: int):
+    """Probe row of each output slot chunk_base + s, s < out_capacity: the
+    number of inclusive prefix-sum `ends` at or below the slot (rows that
+    emit nothing share their end with the row before and are skipped),
+    clipped to a row. The ends inside the chunk are scattered onto their
+    slots and summed along; those at or below its base are counted."""
+    rel = ends - chunk_base
+    inside = (rel > 0) & (rel < out_capacity)
+    hist = jnp.zeros(out_capacity, jnp.int32).at[
+        jnp.where(inside, rel, out_capacity).astype(jnp.int32)].add(
+            1, mode="drop")
+    row = jnp.sum(rel <= 0).astype(jnp.int32) + _running_sum(hist)
+    return jnp.minimum(row, ends.shape[0] - 1)
 
 
 def _keys_equal(table: BuildTable, build_idx, probe: Batch,
@@ -222,18 +314,16 @@ def probe_expand(
     """General path, pass 2: materialize output slots
     [chunk_base, chunk_base + out_capacity).
 
-    Each output slot i maps to probe_row = searchsorted(offsets_end, i,
-    'right') and ordinal = i - offsets[probe_row]; the build row is
-    lo[probe_row] + ordinal, verified against real keys.
+    Each output slot i maps to probe_row = the number of inclusive ends at
+    or below i (_slot_rows) and ordinal = i - offsets[probe_row]; the build
+    row is lo[probe_row] + ordinal, verified against real keys.
 
     Returns (probe_idx int32[out_capacity], build_idx int32[out_capacity],
     out_live bool[out_capacity]).
     """
     total = offsets + counts  # inclusive ends
     i = jnp.arange(out_capacity, dtype=jnp.int64) + chunk_base
-    probe_row = jnp.searchsorted(total, i, side="right").astype(jnp.int32)
-    pcap = counts.shape[0]
-    probe_row = jnp.clip(probe_row, 0, pcap - 1)
+    probe_row = _slot_rows(total, chunk_base, out_capacity)
     ordinal = i - offsets[probe_row]
     in_range = (i < total[-1]) & (ordinal >= 0) & (ordinal < counts[probe_row])
     build_idx = (lo[probe_row] + ordinal).astype(jnp.int32)
@@ -377,7 +467,7 @@ def hash_probe_expand(table: HashJoinTable, mm: jnp.ndarray,
                       counts: jnp.ndarray, offsets: jnp.ndarray,
                       chunk_base, out_capacity: int):
     """General path, pass 2 — pure XLA (no kernel): slot i maps back to
-    (probe_row, ordinal) by one searchsorted over the inclusive ends and
+    (probe_row, ordinal) by _slot_rows over the inclusive ends and
     the build row is mm[probe_row, ordinal]. Precondition: counts <= F
     everywhere (the driver widened the probe on overflow), so no key
     re-verification is needed — the kernel matched exact planes.
@@ -385,9 +475,7 @@ def hash_probe_expand(table: HashJoinTable, mm: jnp.ndarray,
     Returns (probe_idx, build_idx, out_live), like probe_expand."""
     ends = offsets + counts
     i = jnp.arange(out_capacity, dtype=jnp.int64) + chunk_base
-    pcap = counts.shape[0]
-    probe_row = jnp.searchsorted(ends, i, side="right").astype(jnp.int32)
-    probe_row = jnp.clip(probe_row, 0, pcap - 1)
+    probe_row = _slot_rows(ends, chunk_base, out_capacity)
     ordinal = i - offsets[probe_row]
     in_range = (i < ends[-1]) & (ordinal >= 0) & (ordinal < counts[probe_row])
     fanout = mm.shape[1]
@@ -513,7 +601,7 @@ def multiway_expand(tables, probe: Batch, specs, state, chats, offsets,
                     T, chunk_base, out_capacity: int, probe_cols,
                     build_cols):
     """Pass 2: materialize output slots [chunk_base, chunk_base +
-    out_capacity). One searchsorted over the inclusive ends of T maps a
+    out_capacity). _slot_rows over the inclusive ends of T maps a
     slot to its probe row; the residual ordinal decomposes mixed-radix
     across legs (last leg fastest). Left legs emit their null-extension
     at digit 0 when unmatched. ``build_cols[i]`` are leg i's payload
@@ -521,9 +609,7 @@ def multiway_expand(tables, probe: Batch, specs, state, chats, offsets,
     N = len(specs)
     ends = offsets + T
     i = jnp.arange(out_capacity, dtype=jnp.int64) + chunk_base
-    pcap = T.shape[0]
-    probe_row = jnp.searchsorted(ends, i, side="right").astype(jnp.int32)
-    probe_row = jnp.clip(probe_row, 0, pcap - 1)
+    probe_row = _slot_rows(ends, chunk_base, out_capacity)
     r = i - offsets[probe_row]
     in_range = (i < ends[-1]) & (r >= 0) & (r < T[probe_row])
     digits = [None] * N
@@ -671,9 +757,13 @@ def gather_join_output(
     return Batch(names, types, cols, out_live, dicts)
 
 
-def table_rows(table) -> int:
-    """Host-synced live row count of a built join table (BuildTable or
-    HashJoinTable — both carry ``n_rows`` as a device scalar). One sync;
-    the HBO observation path calls it after the build phase has already
-    materialized the table, so the transfer is of a ready scalar."""
-    return int(table.n_rows)  # lint: allow(host-sync)
+def table_stats(table):
+    """Host-synced (live row count, search steps) of a built join table:
+    ``n_rows`` of a BuildTable or HashJoinTable and, in the same transfer,
+    a BuildTable's ``search_steps`` (None for the hash engine's table,
+    which has no bucket search). One sync; the HBO observation path calls
+    it after the build phase has already materialized the table, so the
+    transfer is of ready scalars."""
+    rows, steps = jax.device_get(
+        (table.n_rows, getattr(table, "search_steps", None)))
+    return int(rows), None if steps is None else int(steps)  # lint: allow(host-sync)

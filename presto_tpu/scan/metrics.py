@@ -16,6 +16,7 @@ COUNTER_NAMES = (
     # (radix-partitioned breakers + join fanout estimation, PR 3)
     "join_fanout_overflow_rows", "radix_partitions_spilled",
     "radix_spill_bytes", "radix_aligned_batches",
+    "join_search_steps",
 )
 
 # dispatch-count counters for whole-fragment fusion (exec/fragment_jit.py):
@@ -61,6 +62,10 @@ _HELP = {
     "radix_aligned_batches":
         "exchange pages consumed with a radix tag, skipping the device "
         "re-partition sort",
+    "join_search_steps":
+        "halving rounds a sort-engine join probe runs inside one bucket of "
+        "its build's directory, summed over the builds observed "
+        "(ops/join.py: search_steps)",
     "fragment_dispatches":
         "fused whole-fragment device dispatches (one lax.scan program "
         "covering a stacked window of batches)",

@@ -80,26 +80,51 @@ def test_grouped_sums_kernel_compiles(one_chip, groups, n_states):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_searchsorted_probe_compiles(one_chip):
-    """The sort engine's join probe against a sorted build (ops/join.py):
-    two searchsorted passes + the collision scan, int64 keys."""
+@pytest.mark.parametrize("program", [
+    "probe_unique", "probe_expand", "bucket_directory"])
+def test_directory_probe_compiles(one_chip, program):
+    """The sort engine's join probe (ops/join.py) at sf1_q3's shapes: a
+    batch of 2^17 int64 keys against a sorted build of twelve such batches
+    — the directory gathers, the halving loop bounded by a device scalar,
+    the collision scan; the slot-to-row scatter and running sum; and the
+    directory itself over hashes already sorted (the build's sort stays out
+    of this file, as above; so does probe_counts, the same probe as
+    probe_unique with a scan of 8 candidates that compiles for 17 s)."""
     from presto_tpu.batch import Batch, Column
-    from presto_tpu.ops.join import build_side, probe_unique
+    from presto_tpu.ops import join
     from presto_tpu.types import BIGINT
 
-    def batch(names):
+    def batch(names, n):
         return Batch(names, [BIGINT] * len(names),
-                     [Column(jax.ShapeDtypeStruct((N,), jnp.int64))
+                     [Column(jax.ShapeDtypeStruct((n,), jnp.int64))
                       for _ in names],
-                     jax.ShapeDtypeStruct((N,), jnp.bool_), {})
+                     jax.ShapeDtypeStruct((n,), jnp.bool_), {})
 
-    table = jax.eval_shape(lambda b: build_side(b, ["bk"]),
-                           batch(["bk", "payload"]))
-    compiled = jax.jit(
-        lambda t, p: probe_unique(t, p, ["pk"], ["bk"])
-    ).lower(_placed(table, one_chip),
-            _placed(batch(["pk", "v"]), one_chip)).compile()
+    if program == "bucket_directory":
+        compiled = jax.jit(join._bucket_directory).lower(
+            _sds((12 * N,), jnp.int64, one_chip),
+            _sds((), jnp.int64, one_chip)).compile()
+        assert compiled.memory_analysis() is not None
+        return
+    table = jax.eval_shape(lambda b: join.build_side(b, ["bk"]),
+                           batch(["bk", "payload"], 12 * N))
+    probe = batch(["pk", "v"], N)
+    if program == "probe_expand":
+        lo, counts, offsets, *_ = jax.eval_shape(
+            lambda t, p: join.probe_counts(t, p, ["pk"], ["bk"]), table, probe)
+        fn = lambda t, p, lo, c, o, base: join.probe_expand(  # noqa: E731
+            t, p, ["pk"], ["bk"], lo, c, o, base, N)
+        args = (table, probe, lo, counts, offsets,
+                jax.ShapeDtypeStruct((), jnp.int64))
+    else:
+        fn = lambda t, p: join.probe_unique(  # noqa: E731
+            t, p, ["pk"], ["bk"])
+        args = (table, probe)
+    compiled = jax.jit(fn).lower(*_placed(args, one_chip)).compile()
     assert compiled.memory_analysis() is not None
+    # no binary search of the whole build or of the prefix sums is left:
+    # the one loop is the halving inside a bucket
+    assert compiled.as_text().count(" while(") == (program != "probe_expand")
 
 
 def test_q6_scan_filter_aggregate_chain_compiles(one_chip, monkeypatch):

@@ -19,7 +19,8 @@ from presto_tpu.obs import trace as obs_trace
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BATCH = 8192
-JOIN_PHASES = ("join_build", "join_probe", "host_sync:join_build_rows",
+JOIN_PHASES = ("join_build", "join_probe", "join_search",
+               "host_sync:join_build_rows",
                "host_sync:join_total", "host_sync:join_overflow",
                "host_sync:join_output_rows", "host_sync:join_selectivity")
 
@@ -90,6 +91,23 @@ def test_q3_records_a_phase_for_every_join_and_every_probe_batch(url):
     counts = {k: (v["n"], v.get("items")) for k, v in task.items()}
     assert counts == {k: (v["n"], v.get("items"))
                       for k, v in second["phases"]["task"].items()}
+
+
+def test_a_builds_search_steps_ride_with_its_row_count(url):
+    """`join_search`: one occurrence a build beside `join_build_rows`, its
+    `items` the table's `search_steps` (a few halvings inside a bucket, not
+    log2 of the build's capacity), and the process counter alike."""
+    from presto_tpu.scan import metrics
+
+    before = metrics.snapshot()["join_search_steps"]
+    _, summary = statement(url, query_text("q3"))
+    task = summary["phases"]["task"]
+    search = task["join_search"]
+    assert search["n"] == task["host_sync:join_build_rows"]["n"] == 2
+    # 1,500 customers and their orders in builds of 2^11 lanes and more
+    assert 2 <= search["items"] <= 2 * 6
+    assert metrics.snapshot()["join_search_steps"] - before == search["items"]
+    assert search["busy_s"] < 0.01 * task["join_build"]["busy_s"]
 
 
 def test_tracing_off_gives_the_same_answer_and_records_nothing(url):

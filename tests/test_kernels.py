@@ -3,13 +3,15 @@ presto-main's per-operator tests, e.g. operator/TestHashAggregationOperator,
 TestHashJoinOperator — SURVEY §4 tier 1)."""
 
 import numpy as np
+import jax
 import jax.numpy as jnp
 import pandas as pd
 import pytest
 
-from presto_tpu.batch import Batch
+from presto_tpu.batch import Batch, Column
 from presto_tpu.types import BIGINT, DOUBLE, INTEGER
 from presto_tpu.ops.grouping import grouped_merge, KeyCol, StateCol
+from presto_tpu.ops import join as join_ops
 from presto_tpu.ops.join import build_side, probe_unique, probe_counts, probe_expand
 from presto_tpu.ops.partition import partition_for_exchange
 from presto_tpu.ops.sort import sort_batch, SortKey, compact, limit_batch
@@ -147,6 +149,186 @@ class TestJoin:
         _, matched = probe_unique(tbl, pb, ("id",), ("id",))
         m = np.asarray(matched)[:10]
         assert m[:5].all() and not m[5:].any()
+
+
+def _key_batch(keys, live=None, valid=None):
+    """A batch of exactly len(keys) lanes (from_numpy would round it up to
+    a power of two) with one BIGINT key column `id`."""
+    keys = np.asarray(keys, np.int64)
+    live = np.ones(len(keys), bool) if live is None else np.asarray(live, bool)
+    col = Column(jnp.asarray(keys),
+                 None if valid is None else jnp.asarray(np.asarray(valid, bool)))
+    return Batch(["id"], [BIGINT], [col], jnp.asarray(live), {})
+
+
+_SENT = np.iinfo(np.int64).max
+
+
+def _few_hashes(batch, key_names):
+    """join_hash with forced collisions: every key lands on one of three
+    hashes, two of them in one bucket of any directory."""
+    v = batch.column(key_names[0]).values
+    return jnp.asarray(np.array([5, 6, 1 << 61], np.int64))[v % 3]
+
+
+def _edge_hashes(batch, key_names):
+    """join_hash that hands live rows the two values the kernels reserve:
+    the build's dead-lane sentinel and the probe's."""
+    v = batch.column(key_names[0]).values
+    return jnp.asarray(np.array([_SENT, _SENT - 1, 7, 0], np.int64))[v % 4]
+
+
+def _range_cases():
+    r = np.random.default_rng(28)
+    some = lambda n, p: r.random(n) < p  # noqa: E731
+    nb, npr = 1024, 256  # one pair of shapes for most cases: one compile
+    return {
+        # name: (build batch, probe batch, join_hash to patch in or None)
+        "long_runs_of_one_key": (
+            _key_batch(r.integers(0, 3, nb)),
+            _key_batch(r.integers(0, 5, npr)), None),
+        "every_row_one_key": (
+            _key_batch(np.full(nb, 42)),
+            _key_batch(r.integers(41, 44, npr)), None),
+        "forced_collisions": (
+            _key_batch(r.integers(0, 50, nb), some(nb, .7)),
+            _key_batch(r.integers(0, 60, npr)), _few_hashes),
+        "dead_lanes_and_null_keys_both_sides": (
+            _key_batch(r.integers(0, 400, nb), some(nb, .6), some(nb, .8)),
+            _key_batch(r.integers(0, 500, npr), some(npr, .6),
+                       some(npr, .7)), None),
+        "distinct_keys_full_build": (
+            _key_batch(r.permutation(nb)),
+            _key_batch(r.integers(0, 2 * nb, npr)), None),
+        "empty_build": (
+            _key_batch(np.arange(nb), np.zeros(nb, bool)),
+            _key_batch(np.arange(npr)), None),
+        "all_dead_build_by_null_keys": (
+            _key_batch(np.arange(nb), valid=np.zeros(nb, bool)),
+            _key_batch(np.arange(npr)), None),
+        "all_dead_probe": (
+            _key_batch(r.integers(0, 30, nb)),
+            _key_batch(np.arange(npr), np.zeros(npr, bool)), None),
+        "reserved_hashes_on_live_rows": (
+            _key_batch(r.integers(0, 4, nb), some(nb, .5)),
+            _key_batch(r.integers(0, 4, npr), some(npr, .8)), _edge_hashes),
+        "capacity_1_hit": (_key_batch([7]), _key_batch([7, 8, 7, 7]), None),
+        "capacity_1_dead": (
+            _key_batch([7], [False]), _key_batch([7, 8, 9, 7]), None),
+        "capacity_not_a_power_of_two": (
+            _key_batch(r.integers(0, 900, 3000), some(3000, .9)),
+            _key_batch(r.integers(0, 1000, 777), some(777, .9)), None),
+    }
+
+
+_RANGE_CASES = _range_cases()
+
+
+@pytest.mark.parametrize("case", sorted(_RANGE_CASES))
+def test_probe_ranges_are_numpy_searchsorted_left_and_right(case, monkeypatch):
+    """The bucket directory's [lo, hi) against a binary search of the whole
+    sorted build, dead lanes' sentinels included: bit for bit."""
+    build, probe, patched_hash = _RANGE_CASES[case]
+    if patched_hash is not None:
+        monkeypatch.setattr(join_ops, "join_hash", patched_hash)
+    tbl = build_side(build, ("id",))
+    h, lo, hi, live = join_ops._probe_ranges(tbl, probe, ("id",))
+    hashes, h = np.asarray(tbl.hashes), np.asarray(h)
+    n = int(tbl.n_rows)
+    assert (np.diff(hashes) >= 0).all()
+    if patched_hash is not _edge_hashes:
+        assert (hashes[n:] == _SENT).all() and (hashes[:n] < _SENT).all()
+    np.testing.assert_array_equal(lo, np.searchsorted(hashes, h, "left"))
+    np.testing.assert_array_equal(hi, np.searchsorted(hashes, h, "right"))
+    # the directory itself: bucket b is [dir[b], dir[b + 1]) of the live lanes
+    d = np.asarray(tbl.dir)
+    k = max(build.capacity - 1, 0).bit_length()
+    assert d.shape == (2 ** k + 1,) and d[0] == 0 and d[-1] == n
+    np.testing.assert_array_equal(
+        np.diff(d), np.bincount(hashes[:n] >> (63 - k), minlength=2 ** k))
+    largest = int(np.diff(d).max())
+    assert int(tbl.search_steps) == largest.bit_length()
+    dead = ~np.asarray(live)
+    assert (h[dead] == _SENT - 1).all()
+    if case == "every_row_one_key":  # one bucket holds the build
+        assert int(tbl.search_steps) == 11 and (hi - lo).max() == 1024
+
+
+def _ends_cases():
+    r = np.random.default_rng(29)
+    n, cap = 256, 128  # one pair of shapes for most cases: one compile
+
+    def padded(*parts):
+        c = np.concatenate([np.atleast_1d(p) for p in parts]).astype(int)
+        return np.concatenate([c, np.zeros(n - len(c), int)])
+
+    return {
+        # name: (counts of a probe batch, chunk_base, out_capacity)
+        "first_chunk": (r.integers(0, 4, n), 0, cap),
+        "chunk_base_past_one_chunk": (r.integers(0, 9, n), 4 * cap, cap),
+        "last_partial_chunk": (r.integers(0, 3, n) // 2, cap, cap),
+        "chunk_base_at_total": (np.full(n, 2), 2 * n, cap),
+        "zero_counts_at_both_ends_of_a_chunk": (
+            padded(np.zeros(5), cap, 0, 0, 5, np.zeros(7), cap - 5, 0, 0, 9),
+            cap, cap),
+        "zero_counts_lead_and_trail": (
+            padded(np.zeros(40), r.integers(0, 3, 50)), 0, cap),
+        "no_row_matches": (np.zeros(n, int), 0, cap),
+        "one_row_emits_everything": (
+            padded(np.zeros(9), 1000), 2 * cap, cap),
+        "every_row_once": (np.ones(n, int), 100, cap),
+        "sparse_matches": ((r.random(n) < .03).astype(int), 0, cap),
+        "one_probe_lane": (np.array([3]), 0, 8),
+        "capacities_not_powers_of_two": (r.integers(0, 9, 777), 333, 3000),
+    }
+
+
+_ENDS_CASES = _ends_cases()
+
+
+@pytest.mark.parametrize("case", sorted(_ENDS_CASES))
+def test_slot_rows_is_numpy_searchsorted_right_of_the_ends(case):
+    counts, base, out_cap = _ENDS_CASES[case]
+    ends = np.cumsum(np.asarray(counts, np.int64))
+    got = join_ops._slot_rows(jnp.asarray(ends), jnp.int64(base), out_cap)
+    want = np.searchsorted(ends, np.arange(out_cap) + base, "right")
+    np.testing.assert_array_equal(got, np.clip(want, 0, len(ends) - 1))
+    assert got.dtype == jnp.int32
+
+
+def _loops(jaxpr):
+    """`while` equations of a jaxpr, at any depth."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "while":
+            found.append(eqn)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += _loops(sub)
+    return found
+
+
+@pytest.mark.parametrize("program,loops", [
+    ("probe_counts", 1), ("probe_unique", 1), ("probe_expand", 0)])
+def test_probe_programs_loop_only_in_the_bounded_finish(program, loops):
+    """No binary search of the whole build or of the prefix sums is left:
+    the one loop of a probe is the halving inside a bucket, bounded by the
+    table's `search_steps`; mapping slots to rows has none."""
+    build = _key_batch(np.arange(1000) % 300)
+    probe = _key_batch(np.arange(256))
+    tbl = build_side(build, ("id",))
+    keys = ("id",)
+    if program == "probe_expand":
+        lo, counts, offsets, *_ = probe_counts(tbl, probe, keys, keys)
+        jaxpr = jax.make_jaxpr(lambda t, p, l, c, o, b: probe_expand(
+            t, p, keys, keys, l, c, o, b, 512))(
+                tbl, probe, lo, counts, offsets, jnp.int64(512))
+    else:
+        fn = {"probe_counts": probe_counts, "probe_unique": probe_unique}[program]
+        jaxpr = jax.make_jaxpr(lambda t, p: fn(t, p, keys, keys))(tbl, probe)
+    # a loop with a static trip count would be a `scan`: a `while` here is
+    # the one bounded by the table's device scalar
+    assert len(_loops(jaxpr.jaxpr)) == loops
+    assert "sort" not in str(jaxpr).replace("indices_are_sorted", "")
 
 
 class TestSortCompact:
