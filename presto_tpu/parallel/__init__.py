@@ -1,13 +1,3 @@
 from presto_tpu.parallel.mesh import make_mesh
-from presto_tpu.parallel.dist import (
-    distributed_aggregate,
-    distributed_join_probe,
-    shard_batch_arrays,
-)
 
-__all__ = [
-    "make_mesh",
-    "distributed_aggregate",
-    "distributed_join_probe",
-    "shard_batch_arrays",
-]
+__all__ = ["make_mesh"]

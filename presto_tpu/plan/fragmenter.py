@@ -9,7 +9,7 @@ TPU-first shape: a fragment is a program executed by one task per worker
 (or one task total for SINGLE); its sink hash-partitions / broadcasts /
 gathers output pages into per-consumer buffers pulled over HTTP (across
 hosts) — within a slice the same partitioning runs as all_to_all collectives
-(presto_tpu.parallel.dist). Partitioning vocabulary mirrors
+(presto_tpu.parallel.mesh_exec). Partitioning vocabulary mirrors
 SystemPartitioningHandle.java:59-66: SOURCE, FIXED_HASH, SINGLE on the
 fragment side; HASH / BROADCAST / GATHER on the output side.
 """

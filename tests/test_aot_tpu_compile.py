@@ -42,7 +42,12 @@ def topo():
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
+    # the verdict asked for is the one the chip's full pipeline gives, not
+    # the suite's cheap compiles (tests/conftest.py)
+    cheap = jax.config.read("jax_disable_most_optimizations")
+    jax.config.update("jax_disable_most_optimizations", False)
     yield t
+    jax.config.update("jax_disable_most_optimizations", cheap)
     jax.config.update("jax_enable_compilation_cache", was)
     compilation_cache.reset_cache()
 

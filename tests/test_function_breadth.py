@@ -154,23 +154,29 @@ def test_mixed_decomposable_and_materialized(runner):
         np.testing.assert_allclose(row.p90, vals[k], rtol=1e-12)
 
 
-def test_distributed_stats_aggs(runner):
-    """Variance/covar decompose through partial/final across the exchange;
-    approx_percentile gathers to a single task."""
+@pytest.fixture(scope="module")
+def cluster(runner):
     from presto_tpu.server.coordinator import DistributedRunner
 
-    r = DistributedRunner(runner.catalog, n_workers=2,
-                          config=ExecConfig(batch_rows=1 << 11))
-    try:
-        sql = """select g, stddev(x) as sd, corr(x, y) as r,
-                        count_if(b) as ci from t group by g order by g"""
-        assert_frames_match(r.run(sql), runner.run(sql), sort_by=["g"], rtol=1e-6)
-        sql2 = "select g, approx_percentile(x, 0.5) as m from t group by g order by g"
-        plan_s = r.explain_distributed(sql2)
-        assert "gather" in plan_s
-        assert_frames_match(r.run(sql2), runner.run(sql2), sort_by=["g"])
-    finally:
-        r.close()
+    with DistributedRunner(runner.catalog, n_workers=2,
+                           config=runner.config) as r:
+        yield r
+
+
+def test_distributed_stats_aggs(runner, cluster):
+    """Variance/covar decompose through partial/final across the
+    exchange."""
+    sql = """select g, stddev(x) as sd, corr(x, y) as r,
+                    count_if(b) as ci from t group by g order by g"""
+    assert_frames_match(cluster.run(sql), runner.run(sql), sort_by=["g"],
+                        rtol=1e-6)
+
+
+def test_distributed_percentile_gathers(runner, cluster):
+    """approx_percentile gathers to a single task."""
+    sql = "select g, approx_percentile(x, 0.5) as m from t group by g order by g"
+    assert "gather" in cluster.explain_distributed(sql)
+    assert_frames_match(cluster.run(sql), runner.run(sql), sort_by=["g"])
 
 
 # ---- scalars ---------------------------------------------------------------

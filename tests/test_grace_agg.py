@@ -77,6 +77,44 @@ def test_grace_from_start_matches_baseline(cat):
                                     agg_cap_ceiling=1 << 9,
                                     spill_partitions=4))
     _check(r.run(SQL), base)
+    assert r.last_stats["spill.partitions"] >= 4
+
+
+def test_grace_recursive_repartition_high_ndv(cat):
+    """A spilled partition whose group count still exceeds the grace
+    ceiling at finalize must split by the NEXT hash bits and recurse
+    (dynamic hybrid hash), not fail or grow an oversized table: with
+    ~2250 groups per partition against a 512 ceiling, repartition waves
+    are mandatory — and the answer must still match. Deliberately the
+    exact config of test_grace_from_start_matches_baseline so every
+    program comes out of the shared structural cache; this test adds
+    only the stats assertion and the replayed exec."""
+    from presto_tpu.exec.runtime import ExecContext, run_plan
+
+    base = _baseline(cat)
+    r = LocalRunner(cat, ExecConfig(batch_rows=1 << 12,
+                                    agg_capacity=1 << 8,
+                                    agg_cap_ceiling=1 << 9,
+                                    spill_partitions=4))
+    qp = r.plan(SQL)
+    ctx = ExecContext(cat, r.config)
+    got = run_plan(qp, ctx).to_pandas()
+    assert ctx.stats.get("spill.repartitions", 0) > 0, \
+        "finalize never recursively repartitioned"
+    _check(got, base)
+
+
+def test_grace_depth_bound_fails_structured(cat):
+    """spill_max_depth=0 forbids recursive repartitioning: a partition
+    over the grace ceiling must fail with a structured
+    SPILL_LIMIT_EXCEEDED, not loop or silently grow past the ceiling."""
+    from presto_tpu.spiller import SpillLimitExceeded
+
+    r = LocalRunner(cat, ExecConfig(
+        batch_rows=1 << 12, agg_capacity=1 << 8, agg_cap_ceiling=1 << 9,
+        spill_partitions=4, spill_max_depth=0))
+    with pytest.raises(SpillLimitExceeded, match="grace ceiling"):
+        r.run(SQL)
 
 
 def test_midstream_overflow_switches_to_grace(cat):
@@ -85,11 +123,14 @@ def test_midstream_overflow_switches_to_grace(cat):
     as state pages, the unmerged window + remaining input as raw rows."""
     base = _baseline(cat)
     # ceiling low enough that growth crosses it, capacity lower still
-    r = LocalRunner(cat, ExecConfig(batch_rows=1 << 11,
-                                    agg_capacity=1 << 6,
+    r = LocalRunner(cat, ExecConfig(batch_rows=1 << 12,
+                                    agg_capacity=1 << 8,
                                     agg_cap_ceiling=1 << 10,
                                     spill_partitions=4))
     _check(r.run(SQL), base)
+    # the table grew by replay before it crossed the ceiling and spilled
+    assert r.last_stats["breaker.replay_waves"] >= 2
+    assert r.last_stats["spill.partitions"] >= 4
 
 
 def test_distributed_partial_passthrough(cat):
@@ -140,9 +181,10 @@ def test_grace_under_tight_pool(cat):
     (accounting was only exercised pool-less before)."""
     base = _baseline(cat)
     r = LocalRunner(cat, ExecConfig(
-        batch_rows=1 << 11, agg_capacity=1 << 8, agg_cap_ceiling=1 << 9,
+        batch_rows=1 << 12, agg_capacity=1 << 8, agg_cap_ceiling=1 << 9,
         memory_pool_bytes=24_000_000, spill_partitions=16))
     _check(r.run(SQL), base)
+    assert r.last_stats["spill.partitions"] >= 16
 
 
 def test_midstream_overflow_with_pool_and_revocation(cat):
@@ -151,10 +193,12 @@ def test_midstream_overflow_with_pool_and_revocation(cat):
     handoff): both spillers finalize bucket-wise into one answer."""
     base = _baseline(cat)
     r = LocalRunner(cat, ExecConfig(
-        batch_rows=1 << 11, agg_capacity=1 << 7, agg_cap_ceiling=1 << 12,
+        batch_rows=1 << 12, agg_capacity=1 << 8, agg_cap_ceiling=1 << 12,
         memory_pool_bytes=16_000_000,
         memory_revoking_threshold=0.5, memory_revoking_target=0.2))
     _check(r.run(SQL), base)
+    assert r.last_stats["breaker.replay_waves"] >= 2
+    assert r.last_stats["spill.partitions"] >= 8
 
 
 def test_grace_disabled_when_spill_off(cat):
@@ -162,16 +206,17 @@ def test_grace_disabled_when_spill_off(cat):
     memory instead and still answer correctly (growth-ladder replay)."""
     base = _baseline(cat)
     r = LocalRunner(cat, ExecConfig(
-        batch_rows=1 << 11, agg_capacity=1 << 7, agg_cap_ceiling=1 << 9,
+        batch_rows=1 << 12, agg_capacity=1 << 8, agg_cap_ceiling=1 << 9,
         spill_enabled=False))
     _check(r.run(SQL), base)
+    assert "spill.partitions" not in r.last_stats
 
 
 def test_tiny_pool_without_spill_fails_cleanly(cat):
     """No spill + a pool too small for the group table: a clean
     ExceededMemoryLimit, not a wrong answer or a hang."""
     r = LocalRunner(cat, ExecConfig(
-        batch_rows=1 << 11, agg_capacity=1 << 7, spill_enabled=False,
+        batch_rows=1 << 12, agg_capacity=1 << 8, spill_enabled=False,
         memory_pool_bytes=400_000))
     with pytest.raises(Exception, match="memory"):
         r.run(SQL)
@@ -184,7 +229,7 @@ def test_grace_distributed_with_pool(cat):
     from presto_tpu.server.coordinator import DistributedRunner
 
     base = _baseline(cat)
-    cfg = ExecConfig(batch_rows=1 << 11, agg_capacity=1 << 8,
+    cfg = ExecConfig(batch_rows=1 << 12, agg_capacity=1 << 8,
                      agg_cap_ceiling=1 << 10,
                      memory_pool_bytes=32_000_000)
     with DistributedRunner(cat, n_workers=2, config=cfg) as dist:
@@ -192,43 +237,6 @@ def test_grace_distributed_with_pool(cat):
 
 
 # ---- PR 15: dynamic hybrid hash — skew-adversarial grace matrix --------
-
-
-def test_grace_recursive_repartition_high_ndv(cat):
-    """A spilled partition whose group count still exceeds the grace
-    ceiling at finalize must split by the NEXT hash bits and recurse
-    (dynamic hybrid hash), not fail or grow an oversized table: with
-    ~2250 groups per partition against a 512 ceiling, repartition waves
-    are mandatory — and the answer must still match. Deliberately the
-    exact config of test_grace_from_start_matches_baseline so every
-    program comes out of the shared structural cache; this test adds
-    only the stats assertion and the replayed exec."""
-    from presto_tpu.exec.runtime import ExecContext, run_plan
-
-    base = _baseline(cat)
-    r = LocalRunner(cat, ExecConfig(batch_rows=1 << 12,
-                                    agg_capacity=1 << 8,
-                                    agg_cap_ceiling=1 << 9,
-                                    spill_partitions=4))
-    qp = r.plan(SQL)
-    ctx = ExecContext(cat, r.config)
-    got = run_plan(qp, ctx).to_pandas()
-    assert ctx.stats.get("spill.repartitions", 0) > 0, \
-        "finalize never recursively repartitioned"
-    _check(got, base)
-
-
-def test_grace_depth_bound_fails_structured(cat):
-    """spill_max_depth=0 forbids recursive repartitioning: a partition
-    over the grace ceiling must fail with a structured
-    SPILL_LIMIT_EXCEEDED, not loop or silently grow past the ceiling."""
-    from presto_tpu.spiller import SpillLimitExceeded
-
-    r = LocalRunner(cat, ExecConfig(
-        batch_rows=1 << 12, agg_capacity=1 << 8, agg_cap_ceiling=1 << 9,
-        spill_partitions=4, spill_max_depth=0))
-    with pytest.raises(SpillLimitExceeded, match="grace ceiling"):
-        r.run(SQL)
 
 
 def test_grace_one_hot_group_skew(cat):

@@ -122,14 +122,18 @@ def test_stall_detected_event_forensics_and_episode_close(tmp_path):
     t.publish("Aggregate", windows=1, rows_out=5)
     t.publish("Aggregate", windows=1, rows_out=5)
     assert _wait_for(lambda: e.stalls >= 1)
+    # the watcher counts the stall, then emits the event, then writes the
+    # forensics: wait for the last of the three
+    forensics = tmp_path / "inflight_forensics.jsonl"
+    assert _wait_for(
+        lambda: forensics.exists() and forensics.read_text().endswith("\n"))
     evs = obs_events.EVENTS.events(query_id="q_stall",
                                    kind="stall_detected")
     assert evs and evs[0]["operator"] == "Aggregate"
     assert evs[0]["taskId"] == "q_stall.0.0"
     assert evs[0]["stalledS"] > 0.1
     # forensic JSONL: last-N window snapshots per operator
-    rec = json.loads(
-        (tmp_path / "inflight_forensics.jsonl").read_text().splitlines()[-1])
+    rec = json.loads(forensics.read_text().splitlines()[-1])
     assert rec["queryId"] == "q_stall" and rec["operator"] == "Aggregate"
     snaps = rec["ops"]["q_stall.0.0/Aggregate"]["snapshots"]
     assert len(snaps) >= 2

@@ -418,18 +418,27 @@ def test_spilled_join_zero_row_partitions(rng):
         "k": np.repeat(np.arange(3, dtype=np.int64), 800),
         "w": rng.normal(size=2_400)}))
     conn.add_table("pr", pd.DataFrame({
-        "j": rng.integers(0, 9, 20_000).astype(np.int64),
-        "v": rng.normal(size=20_000)}))
+        "j": rng.integers(0, 9, 4_000).astype(np.int64),
+        "v": rng.normal(size=4_000)}))
     cat.register("m", conn, default=True)
     sql = "select pr.v, bl.w from pr join bl on pr.j = bl.k"
     exp = LocalRunner(cat, ExecConfig(batch_rows=1 << 13)).run(sql)
+    # four partitions hold the case as surely as eight: the build's three
+    # keys leave partition 3 without a build row, and the probe's keys 3 and
+    # 7 land there
+    from presto_tpu.spiller import np_bucket_ids
+
+    build_parts = set(np_bucket_ids([(np.arange(3), None, None)], 4))
+    probe_parts = set(np_bucket_ids([(np.arange(9), None, None)], 4))
+    assert probe_parts - build_parts, "no build-empty partition is probed"
     limited = LocalRunner(cat, ExecConfig(
-        batch_rows=1 << 13, memory_pool_bytes=32 << 10, spill_partitions=8,
+        batch_rows=1 << 13, memory_pool_bytes=32 << 10, spill_partitions=4,
         join_spill_budget_bytes=64 << 10))
     qp = limited.plan(sql)
     ctx = ExecContext(cat, limited.config)
     got = run_plan(qp, ctx).to_pandas()
     assert ctx.spill_manager.spill_count >= 2, "join did not spill"
+    assert ctx.stats["spill.partitions"] >= 4
     assert_frames_match(got, exp, sort_by=["v", "w"])
 
 
@@ -446,8 +455,8 @@ def test_spilled_join_ndv_duplication_matrix(rng, ndv, dup):
     conn.add_table("bl", pd.DataFrame({"k": bk,
                                        "w": rng.normal(size=len(bk))}))
     conn.add_table("pr", pd.DataFrame({
-        "j": rng.integers(0, ndv, 12_000).astype(np.int64),
-        "v": rng.normal(size=12_000)}))
+        "j": rng.integers(0, ndv, 4_000).astype(np.int64),
+        "v": rng.normal(size=4_000)}))
     cat.register("m", conn, default=True)
     sql = "select pr.v, bl.w from pr join bl on pr.j = bl.k"
     exp = LocalRunner(cat, ExecConfig(batch_rows=1 << 13)).run(sql)
@@ -457,6 +466,7 @@ def test_spilled_join_ndv_duplication_matrix(rng, ndv, dup):
     ctx = ExecContext(cat, limited.config)
     got = run_plan(qp, ctx).to_pandas()
     assert ctx.spill_manager.spill_count >= 2, "join did not spill"
+    assert ctx.stats["spill.partitions"] >= 4
     assert_frames_match(got, exp, sort_by=["v", "w"])
 
 

@@ -365,15 +365,16 @@ def _tpch_queries():
     return mod.QUERIES
 
 
-def test_tpch_subset_hash_matches_sort(tpch_engines):
-    """Non-slow representative subset: agg-only (q1), join-heavy (q3),
-    filter+agg (q6), outer-join agg (q13), large-fanout agg (q18)."""
+@pytest.mark.parametrize("name", ["q1", "q3", "q6", "q13", "q18"])
+def test_tpch_subset_hash_matches_sort(tpch_engines, name):
+    """Non-slow representative subset, a case a query: agg-only (q1),
+    join-heavy (q3), filter+agg (q6), outer-join agg (q13), large-fanout
+    agg (q18)."""
     control, test = tpch_engines
-    queries = _tpch_queries()
-    picks = [(k, queries[k]) for k in ("q1", "q3", "q6", "q13", "q18")]
-    v = Verifier(control, test)
-    outcomes = v.run_suite(picks)
-    assert all(o.ok for o in outcomes), report(outcomes)
+    outcome = Verifier(control, test).verify(_tpch_queries()[name], name)
+    assert outcome.ok, report([outcome])
+    assert test.last_stats["breaker.engine_hash"] >= 1
+    assert "breaker.engine_hash" not in control.last_stats
 
 
 @pytest.mark.slow

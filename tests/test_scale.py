@@ -14,19 +14,32 @@ SF = 0.1
 
 
 @pytest.fixture(scope="module")
-def reference():
+def catalog():
+    return tpch_catalog(SF)
+
+
+# Every runner of this module takes the sort engine: it is the one the chip
+# runs for these statements. Under `auto` XLA:CPU answers Q1's four groups
+# with the Pallas hash engine, which here is the interpreter (ROADMAP D2):
+# it took nine tenths of this module's minutes, most of them in the
+# comfortable reference, and is not what "scale stress" stresses. The hash
+# engine keeps its own file (test_pallas_breakers.py).
+@pytest.fixture(scope="module")
+def reference(catalog):
     """Baseline results from a comfortably-sized engine."""
-    return LocalRunner(tpch_catalog(SF), ExecConfig(batch_rows=1 << 20))
+    return LocalRunner(catalog, ExecConfig(batch_rows=1 << 20,
+                                           breaker_engine="sort"))
 
 
 @pytest.fixture(scope="module")
-def stressed():
+def stressed(catalog):
     """Same data, hostile knobs: 8k-row batches, 128-slot group tables,
     2-partition spill."""
     return LocalRunner(
-        tpch_catalog(SF),
+        catalog,
         ExecConfig(batch_rows=1 << 13, agg_capacity=128,
-                   spill_partitions=2, agg_pipeline_depth=2))
+                   spill_partitions=2, agg_pipeline_depth=2,
+                   breaker_engine="sort"))
 
 
 Q1 = """
@@ -48,9 +61,16 @@ group by l_orderkey, o_orderdate, o_shippriority
 order by revenue desc, o_orderdate limit 10
 """
 
+# Every predicate holds for (nearly) every order, and the CBO discounts each
+# one: it expects a few hundred groups and sizes the table at 512 slots
+# where 5,000 custkeys arrive. Without them the table is sized from
+# o_custkey's NDV and never grows.
 GROWTH = """
 select o_custkey, count(*) as n, sum(o_totalprice) as s
-from orders group by o_custkey order by n desc, o_custkey limit 20
+from orders
+where o_comment like '%e%' and o_clerk like 'Clerk%'
+  and o_orderpriority <> 'x'
+group by o_custkey order by n desc, o_custkey limit 20
 """
 
 
@@ -68,37 +88,50 @@ def _same(a, b):
 
 def test_q1_under_stress(reference, stressed):
     _same(stressed.run(Q1), reference.run(Q1))
+    # many batches per scan: the 600k rows went through in 8k-row batches
+    assert stressed.last_stats["fragment.fused_batches"] >= 64
 
 
 def test_q3_multibatch_join(reference, stressed):
     _same(stressed.run(Q3), reference.run(Q3))
+    # the group table behind the join outgrew its size more than once, and
+    # the join's build side spilled
+    assert stressed.last_stats["breaker.replay_waves"] >= 2
+    assert stressed.last_stats["spill.partitions"] >= 2
 
 
 def test_group_table_growth_ladder(reference, stressed):
-    # ~10k distinct custkeys vs a 128-slot initial table: multiple
-    # growth/replay rounds (CBO pre-sizing is bypassed by the stressed
-    # capacity only when stats under-estimate; either path must be exact)
+    # 5,000 custkeys against a table the CBO sized at 512 slots: overflow,
+    # growth and replay from the checkpoint must be exact
     _same(stressed.run(GROWTH), reference.run(GROWTH))
+    assert stressed.last_stats["breaker.replay_waves"] >= 1
 
 
-def test_spill_with_tiny_pool():
+def test_spill_with_tiny_pool(catalog, reference):
     r = LocalRunner(
-        tpch_catalog(SF),
+        catalog,
         ExecConfig(batch_rows=1 << 13, agg_capacity=1 << 10,
-                   memory_pool_bytes=24 << 20, spill_partitions=4))
-    ref = LocalRunner(tpch_catalog(SF), ExecConfig(batch_rows=1 << 20))
-    _same(r.run(GROWTH), ref.run(GROWTH))
+                   memory_pool_bytes=1 << 20, spill_partitions=4,
+                   breaker_engine="sort"))
+    _same(r.run(GROWTH), reference.run(GROWTH))
+    # a 24 MiB pool held the whole table: nothing spilled. 1 MiB does not.
+    assert r.last_stats["spill.partitions"] >= 4
 
 
-def test_skewed_distributed_partitions(reference):
+def test_skewed_distributed_partitions(catalog, reference):
     """2-worker cluster with skew: most lineitems hash to few orders."""
+    from presto_tpu.obs import trace
     from presto_tpu.server.coordinator import DistributedRunner
 
-    dist = DistributedRunner(reference.catalog, n_workers=2,
+    dist = DistributedRunner(catalog, n_workers=2,
                              config=ExecConfig(batch_rows=1 << 13,
-                                               agg_capacity=1 << 8))
+                                               agg_capacity=1 << 8,
+                                               breaker_engine="sort"))
     try:
         got = dist.run(Q1)
-        _same(got, reference.run(Q1))
+        phases = trace.summaries()[-1]["phases"]
     finally:
         dist.close()
+    _same(got, reference.run(Q1))
+    # the 74 batches of 8k rows, read by the two workers' tasks
+    assert phases["scan-prefetch"]["scan_read"]["n"] >= 64

@@ -5,7 +5,13 @@ the independent oracle, like presto-verifier's control cluster).
 
 Queries are the spec's logic adapted to the generator's column surface
 (engine dialect == sqlite dialect here; decimal columns are loaded into
-sqlite as floats at the same scale so identical SQL compares)."""
+sqlite as floats at the same scale so identical SQL compares).
+
+The same corpus then replays on a 2-worker DistributedRunner against the
+LocalRunner that sqlite has just vouched for (presto-verifier's two-cluster
+replay, order-insensitive checksums). It lives in this module so that the
+control side is the module's runner: one catalog, one ExecConfig, and the
+local programs are compiled once for both tests."""
 
 import sqlite3
 
@@ -30,8 +36,10 @@ _TABLES = (
 @pytest.fixture(scope="module")
 def engines():
     cat = tpcds_catalog(0.01)
-    runner = LocalRunner(cat, ExecConfig(batch_rows=1 << 15,
-                                         agg_capacity=1 << 14))
+    # 8k-row batches: the fact tables (28,804 store_sales rows) are several
+    # batches and several splits, so both workers of the cluster scan
+    runner = LocalRunner(cat, ExecConfig(batch_rows=1 << 13,
+                                         agg_capacity=1 << 12))
     conn: TpcdsConnector = cat.connectors["tpcds"]
     db = sqlite3.connect(":memory:")
     for t in _TABLES:
@@ -353,3 +361,21 @@ order by w_warehouse_name, sm_type, cc_name limit 100
 @pytest.mark.parametrize("name", sorted(Q))
 def test_tpcds_vs_sqlite(engines, name):
     _compare(engines, Q[name])
+
+
+@pytest.fixture(scope="module")
+def cluster(engines):
+    from presto_tpu.server.coordinator import DistributedRunner
+
+    runner = engines[0]
+    with DistributedRunner(runner.catalog, n_workers=2,
+                           config=runner.config) as dist:
+        yield dist
+
+
+@pytest.mark.parametrize("name", sorted(Q))
+def test_tpcds_distributed_matches_local(engines, cluster, name):
+    from presto_tpu.verifier import Verifier, report
+
+    outcome = Verifier(engines[0], cluster).verify(Q[name], name)
+    assert outcome.ok, report([outcome])
