@@ -2931,8 +2931,10 @@ def _extract_equi_keys(conjs, lsyms, rsyms):
 
 def _derives_unique(node: PlanNode, keys: List[str]) -> bool:
     """True if `keys` are unique on node's output (primary key of a scan,
-    or grouping keys of an aggregation) — enables the single-match probe
-    fast path (analog of knowing the build has no PositionLinks chains)."""
+    grouping keys of an aggregation, or either carried up the probe side
+    of a join whose build is unique) — enables the single-match probe
+    fast path (analog of knowing the build has no PositionLinks chains).
+    A proof from the plan's structure alone: a wrong True loses rows."""
     if isinstance(node, Aggregate):
         return set(node.group_keys) <= set(keys)
     if isinstance(node, Filter):
@@ -2948,6 +2950,14 @@ def _derives_unique(node: PlanNode, keys: List[str]) -> bool:
         if pk is None:
             return False
         return set(pk) <= set(keys)
+    if isinstance(node, HashJoin):
+        # inner/left with a unique build: a probe row survives at most
+        # once, so what is unique on the probe side stays unique (FULL's
+        # tail emits build rows with NULL probe columns)
+        if node.kind not in ("inner", "left") or not node.build_unique:
+            return False
+        probe_keys = [k for k in keys if k in node.left.out_names]
+        return bool(probe_keys) and _derives_unique(node.left, probe_keys)
     return False
 
 

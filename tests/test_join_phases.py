@@ -1,11 +1,14 @@
 """The join path's engine phases (`exec/runtime.py`: `_join_with_spill`,
 `_JoinProber`): TPC-H Q3 at SF 0.01 through the served path leaves
 `join_build`, `join_probe` and the `host_sync:join_*` sites in the
-statement's summary, as often as the plan has joins and probe batches; with
-`tracing=false` the answer is the same and nothing is recorded; a statement
-without a join records none of them. And the one thing the tracer learned
-for it: an occurrence that a generator leaves before a `yield` and enters
-again after counts once."""
+statement's summary, as often as the plan has joins and probe batches; the
+general path alone reads `join_total` once a batch, so `join_probe`'s `n` less
+`host_sync:join_total`'s is the batches that took the single-match path
+(every batch of Q3, none of a join that fans out); with `tracing=false` the
+answer is the same and nothing is recorded; a statement without a join
+records none of them. And the one thing the tracer learned for it: an
+occurrence that a generator leaves before a `yield` and enters again after
+counts once."""
 
 import json
 import math
@@ -23,6 +26,13 @@ JOIN_PHASES = ("join_build", "join_probe", "join_search",
                "host_sync:join_build_rows",
                "host_sync:join_total", "host_sync:join_overflow",
                "host_sync:join_output_rows", "host_sync:join_selectivity")
+
+
+# a join that fans out: the build (`lineitem`, as written) holds an order's
+# key several times, so its probe takes the general path; two months' orders
+FAN_OUT = ("select count(*) as n, sum(l_quantity) as q from orders "
+           "join lineitem on o_orderkey = l_orderkey "
+           "where o_orderdate < date '1992-03-01'")
 
 
 def query_text(qid):
@@ -57,6 +67,13 @@ def all_phases(summary):
             for name, agg in by_name.items()}
 
 
+def unique_batches(task):
+    """Probe batches on the single-match path: the general path reads
+    `total` exactly once a batch, the single-match path never."""
+    general = task.get("host_sync:join_total", {"n": 0})["n"]
+    return task["join_probe"]["n"] - general
+
+
 def test_q3_records_a_phase_for_every_join_and_every_probe_batch(url):
     (orders,), = statement(url, "select count(*) from orders")[0]
     (lineitem,), = statement(url, "select count(*) from lineitem")[0]
@@ -66,19 +83,19 @@ def test_q3_records_a_phase_for_every_join_and_every_probe_batch(url):
     rows_again, second = statement(url, query_text("q3"))
     assert rows == rows_again and len(rows) == 10
     task = first["phases"]["task"]
-    # two joins: customer built and probed by orders (a unique build), that
-    # join's output built and probed by lineitem (the general path)
+    # two joins, both with a unique build: customer built and probed by
+    # orders, that join's output (an order survives it at most once) built
+    # and probed by lineitem
     assert task["join_build"]["n"] == 2
     assert task["join_build"]["items"] >= 2      # build batches drained
     assert task["host_sync:join_build_rows"]["n"] == 2
     assert task["host_sync:join_selectivity"]["n"] == 2
-    # one occurrence a probe batch, one chunk each at the shipped
-    # join_out_capacity
+    # one occurrence a probe batch, one chunk each
     assert task["join_probe"]["n"] == task["join_probe"]["items"] == batches
-    # the general path reads `total` and the overflow count once a batch
-    general = math.ceil(lineitem / BATCH)
-    assert task["host_sync:join_total"]["n"] == general
-    assert task["host_sync:join_overflow"]["n"] == general
+    # every batch took the single-match path: no `total`, no overflow count
+    assert unique_batches(task) == batches
+    assert "host_sync:join_total" not in task
+    assert "host_sync:join_overflow" not in task
     # and each join's output is counted once a batch where it is merged
     assert task["host_sync:join_output_rows"]["n"] == batches
     # the program calls and the reads are the phases' children: what is
@@ -118,15 +135,40 @@ def test_tracing_off_gives_the_same_answer_and_records_nothing(url):
     assert none is None
 
 
+def test_a_join_that_fans_out_counts_no_unique_batch(url):
+    (orders,), = statement(url, "select count(*) from orders")[0]
+    (n, _), = statement(url, "select count(*), sum(l_quantity) from lineitem "
+                             "where l_orderkey in (select o_orderkey from orders "
+                             "where o_orderdate < date '1992-03-01')")[0]
+    rows, first = statement(url, FAN_OUT)
+    rows_again, second = statement(url, FAN_OUT)
+    assert rows == rows_again and rows[0][0] == n > 0
+    task = first["phases"]["task"]
+    general = math.ceil(orders / BATCH)
+    assert task["join_probe"]["n"] == task["join_probe"]["items"] == general
+    # the general path reads `total` and the overflow count once a batch
+    assert task["host_sync:join_total"]["n"] == general
+    assert task["host_sync:join_overflow"]["n"] == general
+    assert unique_batches(task) == 0
+    counts = {k: (v["n"], v.get("items")) for k, v in task.items()}
+    assert counts == {k: (v["n"], v.get("items"))
+                      for k, v in second["phases"]["task"].items()}
+
+
 def test_a_small_join_out_capacity_adds_chunks_not_occurrences(url):
-    rows, whole = statement(url, query_text("q3"))
-    rows_cut, cut = statement(url, query_text("q3"), join_out_capacity="16")
+    rows, whole = statement(url, FAN_OUT)
+    rows_cut, cut = statement(url, FAN_OUT, join_out_capacity="16")
     assert rows_cut == rows
     probe, probe_cut = (d["phases"]["task"]["join_probe"] for d in (whole, cut))
     # the general path's batches now yield their matches 16 rows at a time:
     # the phase is left before each chunk and entered again after it
     assert probe_cut["n"] == probe["n"] == probe["items"]
     assert probe_cut["items"] > probe_cut["n"]
+    # the single-match path has one chunk a batch whatever the capacity
+    _, q3 = statement(url, query_text("q3"), join_out_capacity="16")
+    unique = q3["phases"]["task"]
+    assert unique["join_probe"]["items"] == unique["join_probe"]["n"] \
+        == unique_batches(unique)
 
 
 @pytest.mark.parametrize("qid", ["q6", "q1"])

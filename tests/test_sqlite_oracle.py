@@ -9,6 +9,7 @@ misunderstanding of SQL semantics between our engine and a hand-written
 pandas oracle cannot pass here.
 """
 
+import dataclasses
 import re
 import sqlite3
 
@@ -18,6 +19,7 @@ import pytest
 
 from presto_tpu.catalog.tpch import tpch_catalog
 from presto_tpu.exec import ExecConfig, LocalRunner
+from presto_tpu.exec.runtime import execute_node
 from presto_tpu.types import DecimalType
 
 SF = 0.01
@@ -25,6 +27,7 @@ SF = 0.01
 # ---------------------------------------------------------------------------
 # queries (engine dialect; sqlite text derived mechanically)
 
+from test_derives_unique import UNIQUE_ABOVE_A_JOIN, hash_joins  # noqa: E402
 from test_tpch import QUERIES  # noqa: E402  (the 22 canonical texts)
 
 
@@ -119,9 +122,7 @@ def _normalize(df: pd.DataFrame) -> pd.DataFrame:
     return pd.DataFrame(out)
 
 
-@pytest.mark.parametrize("name", sorted(QUERIES, key=lambda s: int(s[1:])))
-def test_tpch_vs_sqlite(engines, name):
-    runner, db = engines
+def assert_matches_sqlite(runner, db, name):
     sql = QUERIES[name]
     got = _normalize(runner.run(sql))
     cur = db.execute(to_sqlite_sql(sql))
@@ -142,3 +143,47 @@ def test_tpch_vs_sqlite(engines, name):
                 rtol=1e-6, atol=1e-9, err_msg=f"{name}.{c}")
         else:
             assert list(gv) == list(ev), f"{name}.{c}"
+
+
+@pytest.mark.parametrize("name", sorted(QUERIES, key=lambda s: int(s[1:])))
+def test_tpch_vs_sqlite(engines, name):
+    assert_matches_sqlite(*engines, name)
+
+
+# -- the planner's `unique` flag, held to executed data ----------------------
+# `plan/builder.py:_derives_unique` marks a build unique from the plan's
+# structure, through a join below it too (tests/test_derives_unique.py); the
+# probe then returns one match a row, so a wrong flag loses rows in silence.
+
+ABOVE_A_JOIN = list(UNIQUE_ABOVE_A_JOIN)
+
+
+@pytest.mark.parametrize("name", ABOVE_A_JOIN)
+def test_a_build_marked_unique_holds_distinct_keys(engines, name):
+    """Every HashJoin the plan marks `unique`, its build side executed at
+    this scale: the live rows' non-null keys are distinct."""
+    runner, _ = engines
+    joins = [j for j in hash_joins(runner.plan(QUERIES[name]).root)
+             if j.build_unique]
+    assert len(joins) == UNIQUE_ABOVE_A_JOIN[name][1]
+    for j in joins:
+        ctx = runner._new_ctx()
+        keys = pd.concat([b.to_pandas()[list(j.right_keys)]
+                          for b in execute_node(j.right, ctx)]).dropna()
+        assert len(keys) > 0, (name, j.right_keys)
+        assert not keys.duplicated().any(), (name, j.right_keys)
+
+
+@pytest.fixture(scope="module")
+def sort_runner(engines):
+    """The chip's engine (`auto` answers `hash` on the CPU): the sorted
+    build and `probe_unique`, which trusts the flag."""
+    runner, _ = engines
+    return LocalRunner(runner.catalog, dataclasses.replace(
+        runner.config, breaker_engine="sort"))
+
+
+@pytest.mark.parametrize("name", ABOVE_A_JOIN)
+def test_unique_above_a_join_vs_sqlite_under_the_sort_engine(
+        engines, sort_runner, name):
+    assert_matches_sqlite(sort_runner, engines[1], name)
