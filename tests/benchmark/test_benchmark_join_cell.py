@@ -4,7 +4,6 @@ a run of the harness with the join path broken underneath comes out not
 correct - once for each thing a join cell can get wrong that a scan cell
 cannot - and the four readers of the join's phases hold a hand's numbers."""
 
-import json
 import os
 import sys
 
@@ -18,6 +17,7 @@ if ROOT not in sys.path:
 from benchmark import data as bdata, run as brun, traffic  # noqa: E402
 from benchmark.control import control_verdict  # noqa: E402
 from benchmark.refutil import day  # noqa: E402
+from benchmark_shared import addition, declared  # noqa: E402,F401
 from presto_tpu.obs import trace  # noqa: E402
 
 CELL, SF = "sf1_q3", 0.01
@@ -295,14 +295,15 @@ def test_reader_has_nothing_to_read_without_the_phases(name, planted, monkeypatc
     assert read(a_run([("a", 20.0), ("b", 30.0)], None)) is None
 
 
-def test_the_join_metrics_are_declared_for_the_join_cell_alone():
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-        bench = json.load(f)
-    declared = {m["name"]: m for m in bench["per_layer"]}
+def test_the_join_metrics_are_declared_for_the_join_cell_alone(declared):
+    bench, root = declared
+    by_name = {m["name"]: m for m in bench["per_layer"]}
     for name in JOIN_METRICS:
-        m = declared[name]
-        assert m["workloads"] == [CELL] and m["moves"] == "statement_s"
+        m = by_name[name]
+        assert CELL in m["workloads"] and m["moves"] == "statement_s"
         assert m["layer"] == "scheduler + operators" and m["better"] == "lower"
+        assert os.path.isfile(
+            os.path.join(root, "benchmark", "layer_metrics", name + ".py"))
     cell = next(w for w in bench["workloads"] if w["name"] == CELL)
     assert (cell["config"], cell["traffic"], cell["chips"]) == \
         ("tpch_sf1_join", "q3_repeat", 1)

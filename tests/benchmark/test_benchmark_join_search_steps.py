@@ -1,9 +1,9 @@
 """`join_search_steps` off the chip: its reader holds a hand's numbers and has
 nothing to read where the program records no `join_search` (the parent
 commit), the cell's traced rehearsal (CPU, SF 0.01) carries it in its line,
-and `BENCHMARK.json` declares it for the join cell alone."""
+and `BENCHMARK.json` declares it for the join cell, as committed and with an
+addition appended behind it."""
 
-import json
 import os
 import sys
 
@@ -14,36 +14,10 @@ if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
 from benchmark import run as brun, traffic  # noqa: E402
+from benchmark_shared import a_run, addition, declared, summary  # noqa: E402,F401
 from presto_tpu.obs import trace  # noqa: E402
 
 CELL, SF, NAME = "sf1_q3", 0.01, "join_search_steps"
-
-
-def agg(n, busy, **more):
-    return {"n": n, "busy_s": busy, "self_s": busy, "max_s": busy / n, **more}
-
-
-def summary(query_id, k, search=True, steps=7):
-    """One statement's summary, its times stretched by `k`: two builds a
-    statement however long it runs, `steps` halving rounds between them."""
-    task = {"exchange_wait": agg(3 * k, 0.8 * k, wait=True),
-            "join_build": agg(2, 1.5 * k, items=3 * k),
-            "host_sync:join_build_rows": agg(2, 0.02 * k)}
-    other = {"program_call:Project": agg(k, 0.002 * k)}
-    if search:
-        task["join_search"] = agg(1, 1e-5 * k, items=steps - 2)
-        # a build may be observed from another thread too
-        other["join_search"] = agg(1, 1e-5 * k, items=2)
-    return {"queryId": query_id, "wall_s": 9.0 * k, "tasks": 5,
-            "task_wall_s": 12.0 * k, "exchange_wait_s": 8.0 * k,
-            "spans": 70 * k, "dropped": 0,
-            "phases": {"task": task, "fragment-window-producer": other}}
-
-
-def a_run(ids_and_starts, profiler_stopped_at):
-    return {"traced": {"t1": profiler_stopped_at},
-            "completed": [{"query_id": q, "t0": t0, "t1": t0 + 1.0}
-                          for q, t0 in ids_and_starts]}
 
 
 @pytest.fixture
@@ -98,15 +72,18 @@ def test_the_traced_rehearsal_reports_it(monkeypatch):
     # halving rounds inside a bucket, not log2 of a build's capacity
     assert 0 < got[NAME]["value"] <= 6
     assert got["join_probe_batches_per_stmt"]["value"] == 2
+    # both of Q3's builds print `unique`: every batch on the single-match path
+    assert got["join_unique_probe_pct"]["value"] == 100.0
 
 
-def test_the_metric_is_declared_for_the_join_cell_alone():
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-        bench = json.load(f)
+def test_the_metric_is_declared_for_the_join_cell_alone(declared):
+    bench, root = declared
     m = next(m for m in bench["per_layer"] if m["name"] == NAME)
-    assert bench["per_layer"][-1] is m  # an addition at the end of its list
-    assert m == {"name": NAME, "unit": "count", "better": "lower",
-                 "source": "program_counter", "layer": "kernels",
-                 "moves": "statement_s", "workloads": [CELL]}
+    # found by name: where it stands, and which other cells read it, is not
+    # what it is
+    assert CELL in m["workloads"]
+    assert {k: v for k, v in m.items() if k != "workloads"} == {
+        "name": NAME, "unit": "count", "better": "lower",
+        "source": "program_counter", "layer": "kernels", "moves": "statement_s"}
     assert os.path.isfile(
-        os.path.join(ROOT, "benchmark", "layer_metrics", NAME + ".py"))
+        os.path.join(root, "benchmark", "layer_metrics", NAME + ".py"))

@@ -13,6 +13,7 @@ if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
 from benchmark import run as brun  # noqa: E402
+from benchmark_shared import addition, declared  # noqa: E402,F401
 from presto_tpu.obs import trace  # noqa: E402
 
 
@@ -106,13 +107,10 @@ def test_reader_has_nothing_to_read_without_summaries(name, planted, monkeypatch
     assert read(a_run([("a", 20.0), ("b", 30.0)], None)) is None
 
 
-def test_every_new_metric_is_declared_for_every_cell():
-    import json
-
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-        bench = json.load(f)
-    declared = {m["name"]: m for m in bench["per_layer"]}
+def test_every_new_metric_is_declared_for_every_cell(declared):
+    bench, _ = declared
+    by_name = {m["name"]: m for m in bench["per_layer"]}
     for name in EXPECTED:
-        m = declared[name]
+        m = by_name[name]
         assert "workloads" not in m and m["moves"] == "statement_s"
         assert m["layer"] == "scheduler + operators" and m["better"] == "lower"

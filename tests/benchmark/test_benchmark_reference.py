@@ -99,8 +99,9 @@ def test_reference_matches_second_formulation(data, qid, loop):
     assert len(got[0]) == len(meta["result_columns"])
 
 
-# sf1_q3 is not a cell of BENCHMARK.json (PERF.md, Open questions); its
-# reference and control are held here through the query's own files
+# sf1_q3's control and exact reference are held, cell and all, in
+# test_benchmark_join_cell.py; here Q3's reference and its float32 control
+# are held through the query's own files (below)
 @pytest.mark.parametrize("cell", ["sf10_q6", "sf10_q1", "sf1_q6_qgen"])
 @pytest.mark.parametrize("seed", [11, 2147484002, 3000000019])
 def test_float32_control_is_judged_not_correct(cell, seed):
