@@ -21,6 +21,7 @@ for XLA:
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import time
 from functools import partial
 from types import SimpleNamespace
@@ -2118,6 +2119,11 @@ def _agg_steps(node: Aggregate, engine: str = "sort") -> SimpleNamespace:
     return memo
 
 
+def _presized(groups: float) -> int:
+    """Group-table capacity for an estimated group count."""
+    return round_up_capacity(int(min(groups * 1.25, float(1 << 23))))
+
+
 def _agg_presize(node: Aggregate, ctx: "ExecContext"):
     """CBO group-table pre-sizing + grace decision for an Aggregate,
     shared by the executor and the install-time breaker warmers (the
@@ -2140,6 +2146,14 @@ def _agg_presize(node: Aggregate, ctx: "ExecContext"):
         except Exception:
             _st = None
         rows = _st.rows if (_st is not None and _st.rows) else None
+        # behind its exchange a final step derives nothing: where the
+        # fragmenter left it its partial step's estimate, one past the
+        # ceiling takes the partial's decision (that went passthrough and
+        # merged nothing, this goes grace from the start) instead of
+        # climbing a ladder of capacities set by the first pages' groups
+        # to the same end. A smaller one sizes nothing, as before.
+        partial_rows = (node.partial_groups
+                        if rows is None and node.step == "final" else None)
         if getattr(ctx.config, "hbo", "observe") == "correct":
             # HBO: a previous run of this structure measured the real
             # group count — presize from the high-water mark instead of
@@ -2162,8 +2176,10 @@ def _agg_presize(node: Aggregate, ctx: "ExecContext"):
                 # grouped execution: one bucket holds ~1/lifespans of the
                 # groups — size the table for a bucket, not the table
                 rows = rows / ctx.lifespans
-            want = round_up_capacity(int(min(rows * 1.25, float(1 << 23))))
-            cap = max(cap, want)
+            cap = max(cap, _presized(rows))
+        elif (partial_rows and can_spill and _presized(
+                partial_rows / (ctx.lifespans or 1)) > ceiling):
+            return cap, ceiling, can_spill, True
     # Past the ceiling a fixed-capacity table stops being the right tool
     # (every merge sorts `capacity + batch` rows, nearly all of them dead):
     # go grace from the start — raw input hash-partitions to spill and each
@@ -2268,16 +2284,25 @@ def _inflight_spill_hook(node: PlanNode, ctx: "ExecContext"):
 
 def _bump_replay_wave(node: PlanNode, ctx: "ExecContext",
                       hbo_obs: Optional[dict] = None,
-                      cap_to: Optional[int] = None) -> None:
+                      cap_to: Optional[int] = None,
+                      spilled_leaf: bool = False) -> None:
     """Account one overflow-replay wave: a stats-sized capacity proved too
     small and a breaker re-merged from a checkpoint at a bigger size.
     Plain telemetry (ctx.stats + process counter + zero-width span), not
-    gated on hbo — the wave happened regardless of who is watching."""
+    gated on hbo — the wave happened regardless of who is watching.
+    `spilled_leaf`: the wave re-merged a spilled aggregation's leaf, whose
+    table finalize_leaf sizes from the leaf's rows so that there is none:
+    one `agg_replay_wave` occurrence (no time of its own) and the process
+    counter `agg_replay_waves` say when that stops being so."""
     from presto_tpu.scan import metrics as _scan_metrics
 
     ctx.stats["breaker.replay_waves"] = (
         ctx.stats.get("breaker.replay_waves", 0) + 1)
     _scan_metrics.record("breaker_replay_waves", 1)
+    if spilled_leaf:
+        with ctx.tracer.phase("agg_replay_wave", items=1):
+            pass
+        _scan_metrics.record("agg_replay_waves", 1)
     if hbo_obs is not None:
         hbo_obs["replays"] += 1
     if ctx.tracer.enabled:
@@ -3139,7 +3164,7 @@ def _execute_aggregate(node: Aggregate, ctx: ExecContext) -> Iterator[Batch]:
                 key_syms, grace_P, "agg-raw",
                 on_grow=lambda child, pp: _note_spill_repartition(
                     node, ctx, child, pp),
-                on_spill=_inflight_spill_hook(node, ctx))
+                on_spill=_inflight_spill_hook(node, ctx), phases="agg")
             ctx.track_spill(state["raw_spiller"])
         return state["raw_spiller"]
 
@@ -3155,7 +3180,7 @@ def _execute_aggregate(node: Aggregate, ctx: ExecContext) -> Iterator[Batch]:
                 key_syms, grace_P, "agg",
                 on_grow=lambda child, pp: _note_spill_repartition(
                     node, ctx, child, pp),
-                on_spill=_inflight_spill_hook(node, ctx))
+                on_spill=_inflight_spill_hook(node, ctx), phases="agg")
             ctx.track_spill(state["spiller"])
         state["spiller"].spill(acc0)
         freed = mctx.bytes
@@ -3258,7 +3283,8 @@ def _execute_aggregate(node: Aggregate, ctx: ExecContext) -> Iterator[Batch]:
                 if mode != "grow" and want2 > ceiling:
                     _ceiling_overflow(mode, entries)
                 cap = want2
-                _bump_replay_wave(node, ctx, hbo_obs, cap_to=cap)
+                _bump_replay_wave(node, ctx, hbo_obs, cap_to=cap,
+                                  spilled_leaf=not allow_spill)
                 for i, e in enumerate(entries):
                     b = e[1]
                     for _ in range(ctx.config.max_growth_retries):
@@ -3337,14 +3363,21 @@ def _execute_aggregate(node: Aggregate, ctx: ExecContext) -> Iterator[Batch]:
                     mctx.set_bytes(out_bytes)
             confirm(block=True, site="breaker_finish")
 
-        def grace_ingest(stream):
+        def grace_ingest(stream, unmerged=()):
             """Hash-partition chained input batches straight to spill (the
             grace-hash build phase; host-side, so dynamic row counts are
-            free). No device merge happens until the per-partition phase."""
-            raw = mk_raw_spiller()
-            for b in stream:
-                raw.spill(jit_chain(b))
-            ctx.spill_manager.record(raw.spilled_bytes)
+            free). No device merge happens until the per-partition phase.
+            `unmerged`: what a table that outgrew the ceiling mid-stream
+            had pulled and not merged; its confirmed state goes first, as
+            state pages. One `agg_partition` occurrence an aggregate, from
+            the first pull to the last page written, `items` its batches."""
+            with ctx.tracer.phase("agg_partition") as ph:
+                do_spill()
+                raw = mk_raw_spiller()
+                for b in itertools.chain(unmerged, stream):
+                    raw.spill(jit_chain(b))
+                    ph.items += 1
+                ctx.spill_manager.record(raw.spilled_bytes)
 
         def absorb_fused(stream):
             """Whole-fragment ingest: consecutive same-structure batches
@@ -3522,14 +3555,12 @@ def _execute_aggregate(node: Aggregate, ctx: ExecContext) -> Iterator[Batch]:
                     # (fresh @h-forked cache keys) at a capacity sized to
                     # the observed count — instead of replaying the loser
                     # wider and paying the same overflow again next wave
-                    import itertools as _it
-
                     _bind_engine(fl.engine)
                     want = round_up_capacity(int(fl.groups))
                     cap = min(want, ceiling) if can_spill else want
                     # rebind in_stream so a later _GraceOverflow's
                     # grace_ingest still sees the un-pulled remainder
-                    in_stream = _it.chain(iter(fl.batches), in_stream)
+                    in_stream = itertools.chain(fl.batches, in_stream)
                     if frag_why is None:
                         absorb_fused(in_stream)
                     else:
@@ -3537,14 +3568,10 @@ def _execute_aggregate(node: Aggregate, ctx: ExecContext) -> Iterator[Batch]:
             except _GraceOverflow as ov:
                 # the table outgrew the ceiling mid-stream: spill the
                 # confirmed accumulator as state pages, the unmerged window
-                # + the rest of the input as raw partitions
-                do_spill()
-                raw = mk_raw_spiller()
-                # entries are raw-batch triples from expand() or 4-tuple
-                # window entries (batch at [1] either way)
-                for e in ov.entries:
-                    raw.spill(jit_chain(e[1]))
-                grace_ingest(in_stream)
+                # + the rest of the input as raw partitions. Entries are
+                # raw-batch triples from expand() or 4-tuple window entries
+                # (batch at [1] either way)
+                grace_ingest(in_stream, [e[1] for e in ov.entries])
 
         if state["spiller"] is None and state["raw_spiller"] is None:
             if ctx.lifespans is None:
@@ -3582,31 +3609,56 @@ def _execute_aggregate(node: Aggregate, ctx: ExecContext) -> Iterator[Batch]:
         max_sdepth = max(0, ctx.config.spill_max_depth)
 
         def finalize_leaf(rsp, asp, p, sdepth):
+            """One `agg_replay` occurrence a leaf begun (`items` = batches
+            merged), one `agg_repartition` a leaf split."""
             nonlocal cap
-            state["acc"] = None
-            # each bucket holds ~1/P of the groups — shrink the table back
-            # (it regrows geometrically if a bucket is skewed)
-            cap = ctx.config.agg_capacity
-            try:
-                mode = "grace" if sdepth < max_sdepth else "fail"
-                if rsp is not None:
-                    absorb(rsp.read_partition(p), jit_step_raw,
-                           jit_step0_raw, allow_spill=False, on_ceiling=mode)
-                if asp is not None:
-                    absorb(asp.read_partition(p), jit_accstep, jit_accstep0,
-                           allow_spill=False, on_ceiling=mode)
-            except _GraceOverflow:
-                # replay outgrew the ceiling: the partition's files are
-                # still intact on disk, so drop the partial merge, split
-                # by the next hash bits, and finalize the children (raw
-                # and state-page trees split in lockstep → co-partitioned)
+            ph = ctx.tracer.phase("agg_replay")
+            with ph:
                 state["acc"] = None
-                mctx.set_bytes(0)
-                sub_r = rsp.grow_partition(p) if rsp is not None else None
-                sub_a = (asp.grow_partition(
-                    p, fanout=(sub_r.n_partitions if sub_r is not None
-                               else None))
-                    if asp is not None else None)
+                # The spiller counted the leaf's rows on the host. Groups
+                # never outnumber rows, so a table sized from them once
+                # merges the leaf with no overflow wave, and its pages come
+                # back packed into whole batches of one capacity: the scan's,
+                # or the power of two above a smaller leaf's rows. Neither
+                # shape follows the data. A leaf with more rows than the
+                # ceiling is tried at the ceiling and splits if its groups
+                # do not fit.
+                rows = sum(sp.partition_rows(p) for sp in (rsp, asp)
+                           if sp is not None)
+                fit = max(ctx.config.agg_capacity, round_up_capacity(rows))
+                cap = min(ceiling, fit)
+                batch_cap = min(round_up_capacity(ctx.config.batch_rows), fit)
+
+                def counted(batches):
+                    for b in batches:
+                        ph.items += 1
+                        yield b
+
+                mode = "grace" if sdepth < max_sdepth else "fail"
+                try:
+                    for sp, step_fn, step0_fn in (
+                            (rsp, jit_step_raw, jit_step0_raw),
+                            (asp, jit_accstep, jit_accstep0)):
+                        if sp is not None:
+                            absorb(counted(sp.read_batches(p, batch_cap)),
+                                   step_fn, step0_fn, allow_spill=False,
+                                   on_ceiling=mode)
+                    split = False
+                except _GraceOverflow:
+                    # the leaf's groups outnumber the ceiling: its files are
+                    # still intact on disk, so drop the partial merge
+                    split = True
+                    state["acc"] = None
+                    mctx.set_bytes(0)
+            if split:
+                # split by the next hash bits and finalize the children (raw
+                # and state-page trees split in lockstep → co-partitioned)
+                with ctx.tracer.phase("agg_repartition"):
+                    sub_r = rsp.grow_partition(p) if rsp is not None else None
+                    sub_a = (asp.grow_partition(
+                        p, fanout=(sub_r.n_partitions if sub_r is not None
+                                   else None))
+                        if asp is not None else None)
                 fanout = (sub_r or sub_a).n_partitions
                 for q in range(fanout):
                     yield from finalize_leaf(sub_r, sub_a, q, sdepth + 1)
@@ -3615,11 +3667,12 @@ def _execute_aggregate(node: Aggregate, ctx: ExecContext) -> Iterator[Batch]:
             if acc is None:
                 return
             _spill_stats_for(node, ctx)["partitions"] += 1
-            if node.step == "partial":
-                yield acc
-            else:
-                yield _finalize_aggregate(node, acc, layout, key_syms,
-                                          key_types, state_types, in_types)
+            if node.step != "partial":
+                with ph:
+                    acc = _finalize_aggregate(node, acc, layout, key_syms,
+                                              key_types, state_types,
+                                              in_types)
+            yield acc
             mctx.set_bytes(0)
 
         for p in range((raw_spiller or spiller).n_partitions):

@@ -131,12 +131,15 @@ def node_to_json(n: PlanNode) -> Dict[str, Any]:
         return {"k": "project", "child": node_to_json(n.child),
                 "exprs": [[s, expr_to_json(e)] for s, e in n.exprs]}
     if isinstance(n, Aggregate):
-        return {"k": "agg", "child": node_to_json(n.child),
-                "keys": list(n.group_keys), "step": n.step,
-                "aggs": [{"symbol": a.symbol, "fn": a.fn, "arg": a.arg,
-                          "t": _t(a.type), "distinct": a.distinct,
-                          "arg2": a.arg2, "param": a.param}
-                         for a in n.aggs]}
+        d = {"k": "agg", "child": node_to_json(n.child),
+             "keys": list(n.group_keys), "step": n.step,
+             "aggs": [{"symbol": a.symbol, "fn": a.fn, "arg": a.arg,
+                       "t": _t(a.type), "distinct": a.distinct,
+                       "arg2": a.arg2, "param": a.param}
+                      for a in n.aggs]}
+        if n.partial_groups is not None:
+            d["partial_groups"] = float(n.partial_groups)
+        return d
     if isinstance(n, HashJoin):
         return {"k": "join", "kind": n.kind,
                 "left": node_to_json(n.left), "right": node_to_json(n.right),
@@ -264,6 +267,7 @@ def node_from_json(d: Dict[str, Any]) -> PlanNode:
                      bool(a.get("distinct", False)), a.get("arg2"),
                      a.get("param")) for a in d["aggs"]],
             step=d.get("step", "single"),
+            partial_groups=d.get("partial_groups"),
         )
     if k == "join":
         return HashJoin(

@@ -273,6 +273,25 @@ class _Fragmenter:
         rs.__dict__["_node_stats"] = st
         return rs
 
+    def _groups_its_task_derives(self, partial: Aggregate) -> Optional[float]:
+        """The group estimate a partial step's own task will size itself
+        from: what derives here, unless an exchange lies below the step (a
+        RemoteSource's stamp, `cut`, does not travel, so there the task
+        derives nothing and neither step is sized from an estimate)."""
+        def behind_exchange(n: PlanNode) -> bool:
+            return isinstance(n, RemoteSource) or any(
+                behind_exchange(c) for c in n.children())
+
+        if behind_exchange(partial):
+            return None
+        try:
+            from presto_tpu.plan.stats import derive
+
+            st = derive(partial, self.catalog)
+        except Exception:
+            return None
+        return float(st.rows) if st is not None and st.rows else None
+
     # returns (node-in-current-fragment, partitioning of current fragment)
     def process(self, node: PlanNode) -> Tuple[PlanNode, str]:
         if isinstance(node, TableScan):
@@ -298,9 +317,11 @@ class _Fragmenter:
                 return node, SINGLE
             partial = Aggregate(child, node.group_keys, node.aggs, step="partial")
             if node.group_keys:
+                groups = self._groups_its_task_derives(partial)
                 rs = self.cut(partial, cpart, OUT_HASH, node.group_keys,
                               radix_align=True)
-                final = Aggregate(rs, node.group_keys, node.aggs, step="final")
+                final = Aggregate(rs, node.group_keys, node.aggs, step="final",
+                                  partial_groups=groups)
                 return final, HASH
             rs = self.cut(partial, cpart, OUT_GATHER)
             final = Aggregate(rs, [], node.aggs, step="final")

@@ -88,6 +88,11 @@ class Aggregate(PlanNode):
     # fragmenter splits into PARTIAL (emits state columns) / FINAL (merges
     # state columns arriving through the exchange)
     step: str = "single"
+    # on a FINAL step: the groups its PARTIAL step estimates, where that
+    # step's own task can derive them (no exchange below it). Nothing
+    # derives behind an exchange, so this is how the final step takes the
+    # decision its partial takes (exec/runtime.py: _agg_presize)
+    partial_groups: Optional[float] = None
 
     @property
     def output(self):

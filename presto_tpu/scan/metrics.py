@@ -37,6 +37,8 @@ _DISPATCH_COUNTER_NAMES = (
     # regrow / fanout-widening replay a breaker executed — the direct cost
     # of estimate error that HBO correction exists to eliminate
     "breaker_replay_waves",
+    # those of them that re-merged a spilled aggregation's leaf
+    "agg_replay_waves",
     # dynamic hybrid hash spill plane (spiller.py + exec/runtime.py):
     # partition-tree leaves created, next-hash-bits repartition events,
     # per-partition build/probe role reversals, and pool-pressure
@@ -93,6 +95,10 @@ _HELP = {
         "overflow-replay waves executed by pipeline breakers (capacity "
         "regrows and join fanout widenings) — the runtime cost of "
         "estimate error, driven to zero by hbo=correct on warm structures",
+    "agg_replay_waves":
+        "overflow-replay waves inside the replay of a spilled aggregation's "
+        "leaf partition, whose table is sized from the leaf's row count so "
+        "that none is expected (exec/runtime.py: finalize_leaf)",
     "spill_partitions":
         "spill partition-tree leaves finalized by hybrid hash join/agg "
         "replays (the dynamic partition count actually used)",

@@ -241,7 +241,10 @@ def serialize_batch(b: Batch, compress: bool = True,
 def deserialize_batch(data: bytes, capacity: Optional[int] = None,
                       device_put: bool = False,
                       dict_resolver: Optional[Callable[[str], List[str]]]
-                      = None) -> Batch:
+                      = None, host: bool = False) -> Batch:
+    """`host=True` keeps every plane a numpy array of exactly the page's
+    rows (no padding, nothing uploaded): what a consumer that packs pages
+    into batches of its own capacity wants (spiller.pack_pages)."""
     assert data[:4] == _MAGIC, "bad page magic"
     flags, hlen, plen = struct.unpack_from("<BII", data, 4)
     off = 4 + 9
@@ -250,10 +253,12 @@ def deserialize_batch(data: bytes, capacity: Optional[int] = None,
     if flags & _FLAG_ZSTD:
         payload = _zd().decompress(payload)
     n = header["n"]
-    cap = capacity or round_up_capacity(max(n, 1))
+    cap = capacity or (n if host else round_up_capacity(max(n, 1)))
     names = header["names"]
     types = [parse_type(s) for s in header["types"]]
     import jax.numpy as jnp
+
+    put = (lambda a: a) if host else jnp.asarray
 
     cols = []
     pos = 0
@@ -279,7 +284,7 @@ def deserialize_batch(data: bytes, capacity: Optional[int] = None,
             pos += vb
             vbuf = np.zeros(cap, dtype=bool)
             vbuf[:n] = valid
-            valid_arr = jnp.asarray(vbuf)
+            valid_arr = put(vbuf)
         else:
             valid_arr = None
         hi_arr = None
@@ -288,7 +293,7 @@ def deserialize_batch(data: bytes, capacity: Optional[int] = None,
             pos += n * 8
             hbuf = np.zeros(cap, dtype=np.int64)
             hbuf[:n] = hi
-            hi_arr = jnp.asarray(hbuf)
+            hi_arr = put(hbuf)
         sizes_arr = evalid_arr = keys_arr = None
         if st is not None:
             _, has_ev, has_k, kdt = st
@@ -296,22 +301,22 @@ def deserialize_batch(data: bytes, capacity: Optional[int] = None,
             pos += n * 4
             sbuf = np.zeros(cap, np.int32)
             sbuf[:n] = sizes
-            sizes_arr = jnp.asarray(sbuf)
+            sizes_arr = put(sbuf)
             if has_ev:
                 eb = (n * w + 7) // 8
                 ev = _unpack_bits(payload[pos:pos + eb], n * w)
                 pos += eb
                 ebuf = np.zeros((cap, w), bool)
                 ebuf[:n] = ev.reshape(n, w)
-                evalid_arr = jnp.asarray(ebuf)
+                evalid_arr = put(ebuf)
             if has_k:
                 kd = np.dtype(kdt)
                 keys = np.frombuffer(payload, kd, count=n * w, offset=pos)
                 pos += n * w * kd.itemsize
                 kbuf = np.zeros((cap, w), kd)
                 kbuf[:n] = keys.reshape(n, w)
-                keys_arr = jnp.asarray(kbuf)
-        cols.append(Column(jnp.asarray(buf), valid_arr, hi_arr,
+                keys_arr = put(kbuf)
+        cols.append(Column(put(buf), valid_arr, hi_arr,
                            sizes_arr, evalid_arr, keys_arr))
     live = np.zeros(cap, dtype=bool)
     live[:n] = True
@@ -335,10 +340,10 @@ def deserialize_batch(data: bytes, capacity: Optional[int] = None,
             dicts[k] = intern_dictionary(np.asarray(v, dtype=object))
     rd = header.get("radix")
     if rd is not None:
-        b = TaggedBatch(names, types, cols, jnp.asarray(live), dicts,
+        b = TaggedBatch(names, types, cols, put(live), dicts,
                         (int(rd[0]), int(rd[1]), tuple(rd[2])))
     else:
-        b = Batch(names, types, cols, jnp.asarray(live), dicts)
+        b = Batch(names, types, cols, put(live), dicts)
     if device_put:
         import jax
 

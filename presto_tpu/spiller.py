@@ -31,7 +31,8 @@ from typing import Callable, Dict, Iterator, List, Optional, Sequence
 
 import numpy as np
 
-from presto_tpu.batch import Batch
+from presto_tpu.batch import Batch, Column
+from presto_tpu.obs import trace as _obs_trace
 from presto_tpu.serde import deserialize_batch, serialize_batch
 
 # Process-monotonic spill-file ids: `id(self)` is recycled after GC, so two
@@ -75,9 +76,14 @@ class SpillFile:
     verified on every read so disk bit-rot or a truncated write surfaces
     as a structured SpillCorruption, not silently wrong results."""
 
-    def __init__(self, path: str, manager: Optional["SpillManager"] = None):
+    def __init__(self, path: str, manager: Optional["SpillManager"] = None,
+                 phases: Optional[str] = None):
         self.path = path
         self.manager = manager
+        # "<phases>_spill_write" / "<phases>_spill_read" round every page
+        # (`items` = its bytes, header included), on the current tracer;
+        # None (a join's files, sort runs) records nothing
+        self.phases = phases
         self._f = open(path, "wb")
         self.pages = 0
         self.bytes = 0
@@ -89,9 +95,10 @@ class SpillFile:
         n = len(page) + _PAGE_HEADER
         if self.manager is not None:
             self.manager.charge(n)
-        self._f.write(len(page).to_bytes(8, "little"))
-        self._f.write(zlib.crc32(page).to_bytes(4, "little"))
-        self._f.write(page)
+        with _phase(self.phases, "{}_spill_write", n):
+            self._f.write(len(page).to_bytes(8, "little"))
+            self._f.write(zlib.crc32(page).to_bytes(4, "little"))
+            self._f.write(page)
         self.pages += 1
         self.bytes += n
         if rows is None:
@@ -103,7 +110,9 @@ class SpillFile:
             self._f.close()
             self._f = None
 
-    def read(self) -> Iterator[Batch]:
+    def read(self, host: bool = False) -> Iterator[Batch]:
+        """The pages in the order written; `host=True` as numpy arrays of
+        each page's own rows (serde.deserialize_batch)."""
         self.finish_writing()
         if self.pages == 0:
             return
@@ -117,17 +126,23 @@ class SpillFile:
                     raise SpillCorruption(self.path, page,
                                           "truncated page header")
                 n = int.from_bytes(head, "little")
-                crc_raw = f.read(4)
-                if len(crc_raw) < 4:
-                    raise SpillCorruption(self.path, page, "truncated crc")
-                payload = f.read(n)
-                if len(payload) < n:
-                    raise SpillCorruption(
-                        self.path, page,
-                        f"truncated page: want {n} bytes, got {len(payload)}")
-                if zlib.crc32(payload) != int.from_bytes(crc_raw, "little"):
-                    raise SpillCorruption(self.path, page, "crc32 mismatch")
-                yield deserialize_batch(payload)
+                with _phase(self.phases, "{}_spill_read", n + _PAGE_HEADER):
+                    crc_raw = f.read(4)
+                    if len(crc_raw) < 4:
+                        raise SpillCorruption(self.path, page,
+                                              "truncated crc")
+                    payload = f.read(n)
+                    if len(payload) < n:
+                        raise SpillCorruption(
+                            self.path, page,
+                            f"truncated page: want {n} bytes, "
+                            f"got {len(payload)}")
+                    if zlib.crc32(payload) != int.from_bytes(crc_raw,
+                                                             "little"):
+                        raise SpillCorruption(self.path, page,
+                                              "crc32 mismatch")
+                    batch = deserialize_batch(payload, host=host)
+                yield batch
                 page += 1
 
     def close(self):
@@ -141,6 +156,13 @@ class SpillFile:
             os.unlink(self.path)
         except OSError:
             pass
+
+
+def _phase(phases: Optional[str], name: str, items: int = 0):
+    """One occurrence of `name` (its `{}` filled with `phases`) on the
+    thread's tracer; a spiller or file without `phases` records nothing."""
+    tracer = _obs_trace.current() if phases else _obs_trace.NOOP
+    return tracer.phase(name.format(phases), items=items)
 
 
 def _strhash_lut(d) -> np.ndarray:
@@ -213,6 +235,71 @@ def np_bucket_ids(cols, n_buckets: int, divisor: int = 1) -> np.ndarray:
     return (h % np.uint64(n_buckets)).astype(np.int64)
 
 
+_PLANES = ("values", "validity", "hi", "sizes", "evalid", "keys")
+
+
+def _page_shape(page: Batch) -> tuple:
+    """What two host pages must share to be concatenated plane by plane:
+    schema, dictionaries (interned by content, so identity is equality),
+    which planes each column carries and a structural column's width."""
+    return (page.names, tuple(str(t) for t in page.types),
+            tuple(sorted((k, id(d)) for k, d in page.dicts.items())),
+            tuple(tuple(None if getattr(c, f) is None
+                        else (getattr(c, f).dtype.str,
+                              getattr(c, f).shape[1:])
+                        for f in _PLANES) for c in page.columns))
+
+
+def pack_pages(pages: Iterator[Batch], capacity: int) -> Iterator[Batch]:
+    """Host pages (`SpillFile.read(host=True)`: numpy planes of each page's
+    own rows, every row live) packed into device batches of exactly
+    `capacity`: full ones while rows last, then one padded with dead rows.
+    Every batch of a run has the same shape whatever the pages' sizes
+    were, so the programs that merge them do not depend on the data. A
+    page that cannot be concatenated with the run before it (another
+    dictionary, a wider array column) closes that run with a padded batch
+    and starts the next at the same capacity."""
+    import jax.numpy as jnp
+
+    def rows_of(run: List[Batch], lo: int, hi: int, put) -> List[Column]:
+        """Rows [lo, hi) of the run's concatenation, plane by plane."""
+        return [Column(*(
+            None if getattr(c0, f) is None else put(np.concatenate(
+                [getattr(pg.columns[i], f) for pg in run])[lo:hi])
+            for f in _PLANES)) for i, c0 in enumerate(run[0].columns)]
+
+    def padded(rows: np.ndarray):
+        buf = np.zeros((capacity,) + rows.shape[1:], rows.dtype)
+        buf[:len(rows)] = rows
+        return jnp.asarray(buf)
+
+    def drain(run: List[Batch], final: bool) -> Iterator[Batch]:
+        """Full batches off the front of `run`, with `final` the rest too;
+        what is not emitted stays in `run` as one page."""
+        rows = sum(pg.capacity for pg in run)
+        lo = 0
+        while rows - lo >= capacity or (final and lo < rows):
+            hi = min(lo + capacity, rows)
+            yield Batch(run[0].names, run[0].types,
+                        rows_of(run, lo, hi, padded),
+                        padded(np.ones(hi - lo, dtype=bool)), run[0].dicts)
+            lo = hi
+        if lo:
+            run[:] = [Batch(run[0].names, run[0].types,
+                            rows_of(run, lo, rows, lambda a: a),
+                            np.ones(rows - lo, dtype=bool),
+                            run[0].dicts)] if lo < rows else []
+
+    run: List[Batch] = []
+    for page in pages:
+        if run and _page_shape(page) != _page_shape(run[0]):
+            yield from drain(run, final=True)
+        run.append(page)
+        if sum(pg.capacity for pg in run) >= capacity:
+            yield from drain(run, final=False)
+    yield from drain(run, final=True)
+
+
 class PartitioningSpiller:
     """Routes batch rows to per-partition spill files by hash(keys)
     (GenericPartitioningSpiller analog), with dynamic hybrid-hash growth:
@@ -235,7 +322,8 @@ class PartitioningSpiller:
                  max_depth: int = 0,
                  on_grow: Optional[Callable[["PartitioningSpiller", int],
                                             None]] = None,
-                 on_spill: Optional[Callable[[int, int], None]] = None):
+                 on_spill: Optional[Callable[[int, int], None]] = None,
+                 phases: Optional[str] = None):
         self.spill_dir = spill_dir
         self.key_names = tuple(key_names)
         self.n_partitions = n_partitions
@@ -250,6 +338,11 @@ class PartitioningSpiller:
         # (spilled_bytes, max_leaf_depth) after each routed batch on the
         # ROOT spiller only — children report through their root
         self.on_spill = on_spill
+        # an aggregate's spiller names its phases ("agg"): the read of a
+        # routed batch's keys is "host_sync:<phases>_spill_rows", its
+        # files' pages "<phases>_spill_write" / "_read" (SpillFile);
+        # children inherit it
+        self.phases = phases
         # per-row device-byte width (schema-static), estimated lazily from
         # the first spilled batch and inherited by children on grow
         self._row_width: Optional[int] = None
@@ -257,23 +350,25 @@ class PartitioningSpiller:
         self.files: List[SpillFile] = [
             SpillFile(os.path.join(
                 spill_dir, f"{tag}-p{p}-{next_file_id()}.bin"),
-                manager=manager)
+                manager=manager, phases=phases)
             for p in range(n_partitions)
         ]
 
-    def _partition_ids(self, batch: Batch) -> np.ndarray:
-        return np_bucket_ids(
-            [(np.asarray(batch.column(k).values), batch.dicts.get(k),
-              batch.column(k).validity)
-             for k in self.key_names],
-            self.n_partitions, divisor=self.divisor,
-        )
+    def _pull_keys(self, batch: Batch):
+        """The key columns and `live` as host arrays: the one device-to-host
+        read of routing a batch (nothing moves for a page read back)."""
+        with _phase(self.phases, "host_sync:{}_spill_rows"):
+            keys = [(np.asarray(batch.column(k).values), batch.dicts.get(k),
+                     None if batch.column(k).validity is None
+                     else np.asarray(batch.column(k).validity))
+                    for k in self.key_names]
+            return keys, np.asarray(batch.live)
 
     def spill(self, batch: Batch):
         if self._row_width is None:
             self._row_width = _est_row_bytes(batch)
-        pid = self._partition_ids(batch)
-        live = np.asarray(batch.live)
+        keys, live = self._pull_keys(batch)
+        pid = np_bucket_ids(keys, self.n_partitions, divisor=self.divisor)
         for p in range(self.n_partitions):
             mask = live & (pid == p)
             if not mask.any():
@@ -319,10 +414,11 @@ class PartitioningSpiller:
             divisor=self.divisor * self.n_partitions,
             depth=self.depth + 1, manager=self.manager,
             partition_budget_bytes=self.partition_budget_bytes,
-            max_depth=self.max_depth, on_grow=self.on_grow)
+            max_depth=self.max_depth, on_grow=self.on_grow,
+            phases=self.phases)
         child._row_width = self._row_width
         self.children[p] = child
-        for b in self.files[p].read():
+        for b in self.files[p].read(host=True):  # host to host: no upload
             child.spill(b)
         self.files[p].close()
         if self.on_grow is not None:
@@ -343,13 +439,20 @@ class PartitioningSpiller:
                 child = self.grow_partition(p, fanout=oc.n_partitions)
             child.align_to(oc)
 
-    def read_partition(self, p: int) -> Iterator[Batch]:
+    def read_partition(self, p: int, host: bool = False) -> Iterator[Batch]:
         child = self.children.get(p)
         if child is not None:
             for q in range(child.n_partitions):
-                yield from child.read_partition(q)
+                yield from child.read_partition(q, host)
             return
-        yield from self.files[p].read()
+        yield from self.files[p].read(host)
+
+    def read_batches(self, p: int, capacity: int) -> Iterator[Batch]:
+        """Partition p as whole batches of ONE capacity (`pack_pages`): a
+        page is what one input batch left in this partition, so a replay
+        that merged page by page paid one merge, and one program shape,
+        for every few thousand rows."""
+        return pack_pages(self.read_partition(p, host=True), capacity)
 
     def partition_bytes(self, p: int) -> int:
         child = self.children.get(p)
@@ -436,14 +539,17 @@ class SpillManager:
                              tag: str = "spill",
                              partition_budget_bytes: Optional[int] = None,
                              max_depth: int = 0,
-                             on_grow=None, on_spill=None) -> PartitioningSpiller:
+                             on_grow=None, on_spill=None,
+                             phases: Optional[str] = None,
+                             ) -> PartitioningSpiller:
         d = self.dir
         with self._lock:
             self.spill_count += 1
         return PartitioningSpiller(
             d, key_names, n_partitions, tag, manager=self,
             partition_budget_bytes=partition_budget_bytes,
-            max_depth=max_depth, on_grow=on_grow, on_spill=on_spill)
+            max_depth=max_depth, on_grow=on_grow, on_spill=on_spill,
+            phases=phases)
 
     def charge(self, bytes_: int):
         with self._lock:

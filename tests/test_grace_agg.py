@@ -118,18 +118,24 @@ def test_grace_depth_bound_fails_structured(cat):
 
 
 def test_midstream_overflow_switches_to_grace(cat):
-    """A small initial capacity grows via replay until it crosses the
-    ceiling mid-stream (_GraceOverflow): the confirmed accumulator spills
-    as state pages, the unmerged window + remaining input as raw rows."""
+    """A table that outgrows the ceiling mid-stream (_GraceOverflow): the
+    confirmed accumulator spills as state pages, the unmerged window +
+    remaining input as raw rows, and each leaf merges both. The key is an
+    expression, so the CBO guesses 4,000 groups and sizes the table at the
+    ceiling instead of going grace from the start as every plain `group by
+    g` of this file does; 8,880 arrive. (Before PR 33 this test asserted
+    two replay waves and never left the grace-from-start path: the waves
+    were the leaves', which climbed from agg_capacity one by one and are
+    now sized from their rows.)"""
     base = _baseline(cat)
-    # ceiling low enough that growth crosses it, capacity lower still
     r = LocalRunner(cat, ExecConfig(batch_rows=1 << 12,
                                     agg_capacity=1 << 8,
-                                    agg_cap_ceiling=1 << 10,
-                                    spill_partitions=4))
-    _check(r.run(SQL), base)
-    # the table grew by replay before it crossed the ceiling and spilled
-    assert r.last_stats["breaker.replay_waves"] >= 2
+                                    agg_cap_ceiling=1 << 13,
+                                    spill_partitions=4,
+                                    breaker_engine="sort"))
+    _check(r.run(SQL.replace("select g,", "select g + 0 as g,")
+                 .replace("group by g", "group by g + 0")), base)
+    assert "breaker.replay_waves" not in r.last_stats
     assert r.last_stats["spill.partitions"] >= 4
 
 
@@ -197,7 +203,8 @@ def test_midstream_overflow_with_pool_and_revocation(cat):
         memory_pool_bytes=16_000_000,
         memory_revoking_threshold=0.5, memory_revoking_target=0.2))
     _check(r.run(SQL), base)
-    assert r.last_stats["breaker.replay_waves"] >= 2
+    # grace from the start, and no leaf climbs from agg_capacity any more
+    assert "breaker.replay_waves" not in r.last_stats
     assert r.last_stats["spill.partitions"] >= 8
 
 
@@ -263,3 +270,145 @@ def test_grace_one_hot_group_skew(cat):
     assert a.g.tolist() == b.g.tolist()
     assert a.c.tolist() == b.c.tolist()
     assert a.s.tolist() == b.s.tolist()
+
+
+# ---- PR 33: TPC-H Q18 through the served path; a spilled leaf replays as
+# whole batches at one capacity, so its programs do not follow the seed -----
+
+Q18_SF, Q18_QUANTITY = 0.01, "250"  # QUANTITY 300 leaves no order at SF 0.01
+# 15,000 groups against a ceiling of 2,048: the inner aggregate's final step
+# goes grace from its first page (its partial step's decision), four
+# partitions of about 15,000 rows whose 3,750 groups outnumber the ceiling,
+# so each splits once and its children fit
+Q18_CONFIG = dict(batch_rows=1 << 13, agg_capacity=1 << 8,
+                  agg_cap_ceiling=1 << 11, spill_partitions=4,
+                  breaker_engine="sort")
+
+
+Q18_SEEDS = (2147484018, 3000000019)
+
+
+def _q18_served(seed):
+    """Q18 at SF 0.01 on the seed's data through a DistributedRunner: its
+    rows as the reference prints them, the reference's, the statement's
+    phases by name (thread roles summed), the leaves as read back (rows,
+    batch capacity, batches), the spill files it made, the aggregation's
+    programs minted so far in the process and the waves counted so far."""
+    from benchmark import data as bdata, run as brun, traffic
+    from benchmark.refutil import date_str
+    from presto_tpu import spiller
+    from presto_tpu.exec import programs
+    from presto_tpu.obs import trace
+    from presto_tpu.scan import metrics as scan_metrics
+    from presto_tpu.server.__main__ import build_catalog
+    from presto_tpu.server.coordinator import DistributedRunner
+
+    query = traffic.load_query("q18")
+    data = bdata.generate(Q18_SF, seed, sorted(query["tables"]))
+    catalog = build_catalog([f"tpch:sf={Q18_SF:g}"])
+    bdata.install(catalog, Q18_SF, seed, data)
+    want = brun.load_reference("q18")(data, {"quantity": Q18_QUANTITY})
+
+    leaves, files = [], []
+    read_batches = spiller.PartitioningSpiller.read_batches
+    init = spiller.SpillFile.__init__
+
+    def read_noting(self, p, capacity):
+        leaf = [self.partition_rows(p), capacity, 0]
+        leaves.append(leaf)
+        for b in read_batches(self, p, capacity):
+            assert b.capacity == capacity
+            leaf[2] += 1
+            yield b
+
+    def init_noting(self, path, *a, **kw):
+        files.append(path)
+        init(self, path, *a, **kw)
+
+    with pytest.MonkeyPatch.context() as mp, DistributedRunner(
+            catalog, n_workers=1, config=ExecConfig(**Q18_CONFIG)) as dist:
+        mp.setattr(spiller.PartitioningSpiller, "read_batches", read_noting)
+        mp.setattr(spiller.SpillFile, "__init__", init_noting)
+        df = dist.run(query["template"].format(quantity=Q18_QUANTITY))
+    got = [[r[0], r[1], r[2], date_str(r[3]), r[4], int(r[5])]
+           for r in df.values.tolist()]
+    phases = {}
+    for by_name in trace.summaries()[-1]["phases"].values():
+        for name, agg in by_name.items():
+            tot = phases.setdefault(name, {"n": 0, "items": 0})
+            tot["n"] += agg["n"]
+            tot["items"] += agg.get("items", 0)
+    minted = {e.fp: e.compiles for e in programs.entries()
+              if "|Aggregate|" in str(e.fp)}
+    return dict(got=got, want=want, phases=phases, leaves=leaves, files=files,
+                minted=minted,
+                waves=scan_metrics.snapshot()["agg_replay_waves"])
+
+
+@pytest.fixture(scope="module")
+def q18_runs():
+    """The statement once on each of two seeds' data, in that order."""
+    from presto_tpu.scan import metrics as scan_metrics
+
+    waves0 = scan_metrics.snapshot()["agg_replay_waves"]
+    return waves0, [_q18_served(seed) for seed in Q18_SEEDS]
+
+
+@pytest.mark.parametrize("i", range(len(Q18_SEEDS)))
+def test_q18_served_equals_the_reference(q18_runs, i):
+    run = q18_runs[1][i]
+    assert run["got"] == run["want"] and len(run["want"]) > 10
+
+
+def test_q18_phases_carry_their_counts(q18_runs):
+    """The phases of ISSUE 33's table, with their `n` and `items`."""
+    run = q18_runs[1][0]
+    phases, leaves = run["phases"], run["leaves"]
+    assert phases["agg_partition"] == {"n": 1, "items": 8}  # 59,997 rows / 2^13
+    # a leaf whose groups outnumber the ceiling still splits, by four, and
+    # every leaf begun is an occurrence, the four at the root included
+    splits = phases["agg_repartition"]["n"]
+    assert splits >= 1
+    assert max(rows for rows, _, _ in leaves) > Q18_CONFIG["agg_cap_ceiling"]
+    assert phases["agg_replay"]["n"] == len(leaves) == 4 + 4 * splits
+    assert phases["agg_replay"]["items"] == sum(n for _, _, n in leaves)
+    # a batch routed, and a page re-routed when its leaf split
+    assert phases["host_sync:agg_spill_rows"]["n"] >= 8
+    assert phases["agg_spill_write"]["n"] >= 8
+    assert phases["agg_spill_write"]["items"] > 100_000  # bytes
+    # every page is read back once, a split leaf's once more by its split
+    assert phases["agg_spill_read"]["n"] > phases["agg_spill_write"]["n"]
+    assert phases["agg_spill_read"]["items"] > phases["agg_spill_write"]["items"]
+
+
+@pytest.mark.parametrize("i", range(len(Q18_SEEDS)))
+def test_q18_leaf_is_whole_batches_at_one_capacity(q18_runs, i):
+    """A leaf is at most ceil(rows / capacity) merges (one that overflowed
+    stopped there), the capacity one power of two from its rows, and no
+    leaf is merged again at a bigger table."""
+    waves0, runs = q18_runs
+    for rows, capacity, batches in runs[i]["leaves"]:
+        assert capacity == min(1 << 13, max(1 << 8, 1 << (rows - 1).bit_length()))
+        assert batches <= -(-rows // capacity)
+        if rows <= Q18_CONFIG["agg_cap_ceiling"]:  # its groups fit for sure
+            assert batches == -(-rows // capacity) <= 1
+    assert "agg_replay_wave" not in runs[i]["phases"]
+    assert runs[i]["waves"] == waves0
+
+
+def test_q18_programs_do_not_follow_the_seed(q18_runs):
+    """Another seed's data mints no aggregation program: same keys, same
+    shapes (a join's build is as wide as the orders that pass the HAVING)."""
+    first, second = q18_runs[1]
+    assert sum(first["minted"].values()) >= 4
+    assert second["minted"] == first["minted"]
+    assert {c for _, c, _ in second["leaves"]} == {c for _, c, _ in first["leaves"]}
+    assert second["phases"]["agg_replay"]["n"] == first["phases"]["agg_replay"]["n"]
+
+
+@pytest.mark.parametrize("i", range(len(Q18_SEEDS)))
+def test_q18_spill_files_are_gone_with_the_statement(q18_runs, i):
+    import os
+
+    files = q18_runs[1][i]["files"]
+    assert files and not [f for f in files if os.path.exists(f)]
