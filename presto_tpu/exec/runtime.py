@@ -70,6 +70,7 @@ from presto_tpu.ops.join import (
 from presto_tpu.ops.sort import (
     SortKey,
     compact,
+    compact_permutation,
     limit_batch,
     permute_batch,
     sort_batch,
@@ -558,25 +559,43 @@ def execute_node(node: PlanNode, ctx: ExecContext) -> Iterator[Batch]:
     breakers fuse the chain *below* them into their own stepping programs
     via _fused_child."""
     base, down = collapse_chain(node)
+    stream = _operator_stream(base, ctx)
+    if down is not None:
+        jfn = _node_jit(node, "down", lambda: down)
+        # the chain needs a real batch: a join's pending output is gathered
+        # at the probe's capacity first, and counted after the chain
+        stream = (jfn(_gathered(b)) for b in stream)
+    yield from _merged(stream, base, ctx)
+
+
+def _operator_stream(base: PlanNode, ctx: ExecContext) -> Iterator:
+    """A chain's base as a stream, under the statistics and the span that
+    are switched on. A join with a unique build hands on
+    `_PendingJoinOutput`s where it would a `Batch`: `_merging_output`
+    gathers them once their live count is read, anything else takes
+    `_gathered(b)`."""
     stream = _execute_base(base, ctx)
     if ctx.config.collect_stats:
         stream = _instrumented(stream, base, ctx)
     if ctx.tracer.enabled:
         stream = _traced(stream, base, ctx)
-    if down is not None:
-        jfn = _node_jit(node, "down", lambda: down)
-        stream = (jfn(b) for b in stream)
-    if ctx.config.merge_sparse_output and isinstance(
-            base, (HashJoin, MultiwayJoin, SemiJoin, NestedLoopJoin,
-                   IndexJoin)):
-        # selective operators emit batches at probe CAPACITY whose live
-        # occupancy can be ~1%; every downstream per-batch cost (sorts,
-        # merges, probes) is capacity-shaped, so coalesce before fanning
-        # out (reference: operator/project/MergingPageOutput.java)
-        stream = _merging_output(stream, ctx.config.batch_rows,
-                                 bucket=ctx.config.shape_bucketing != "off",
-                                 tracer=ctx.tracer)
-    yield from stream
+    return stream
+
+
+def _merged(stream: Iterator, base: PlanNode, ctx: ExecContext) -> Iterator:
+    """Selective operators emit batches at probe CAPACITY whose live
+    occupancy can be ~1%; every downstream per-batch cost (sorts, merges,
+    probes) is capacity-shaped, so coalesce before fanning out (reference:
+    operator/project/MergingPageOutput.java). Any other base's stream is
+    handed on as it is."""
+    if not isinstance(base, (HashJoin, MultiwayJoin, SemiJoin,
+                             NestedLoopJoin, IndexJoin)):
+        return stream
+    if not ctx.config.merge_sparse_output:
+        return map(_gathered, stream)  # nobody reads a count
+    return _merging_output(stream, ctx.config.batch_rows,
+                           bucket=ctx.config.shape_bucketing != "off",
+                           tracer=ctx.tracer)
 
 
 def _pad_batch(b: Batch, cap: int) -> Batch:
@@ -602,16 +621,23 @@ def _pad_batch(b: Batch, cap: int) -> Batch:
     return Batch(b.names, b.types, cols, padp(b.live, False), b.dicts)
 
 
-def _merging_output(stream: Iterator[Batch], target_cap: int,
+def _merging_output(stream: Iterator, target_cap: int,
                     bucket: bool = False,
                     tracer=_obs_trace.NOOP) -> Iterator[Batch]:
-    """MergingPageOutput analog: compact sparse batches (live rows to the
-    front), slice them to their power-of-two bucket, and concatenate until
-    a full batch accumulates. Dense batches pass through untouched; empty
-    batches are dropped. Costs one host sync per input batch (num_live:
-    the phase `host_sync:join_output_rows`) — repaid many times over by the
-    capacity-shaped work it removes downstream on selective multi-join
-    plans.
+    """MergingPageOutput analog: bring each sparse batch down to the
+    power-of-two bucket of its live count `n` (live rows to the front,
+    `round_up_capacity(n)` lanes) and concatenate until a full batch
+    accumulates. Dense batches (`2 n >= capacity`) pass through untouched;
+    empty batches are dropped. Costs one host sync per input batch (its
+    live count: the phase `host_sync:join_output_rows`) — repaid many times
+    over by the capacity-shaped work it removes downstream on selective
+    multi-join plans — and the count it pays for sizes the work: a sparse
+    `Batch` is compacted by a program that gathers `round_up_capacity(n)`
+    lanes of every plane, not the batch's capacity, and a
+    `_PendingJoinOutput` (a unique probe's matches, nothing gathered yet)
+    is gathered at that size, dense or not at all (`emit(n)`); what it
+    hands back compacted is neither compacted nor counted again. Every
+    batch so materialised is one `join_emit` occurrence, `items` its lanes.
 
     ``bucket`` (shape_bucketing=pow2) additionally pads every flush —
     including the single-batch passthrough — up to the stream's pow2
@@ -641,9 +667,16 @@ def _merging_output(stream: Iterator[Batch], target_cap: int,
         nonlocal pending_live
         if n == 0:
             return None
-        if 2 * n >= b.capacity:
-            return b  # dense: pass through (flushing pending first)
-        pending.append(_truncate(_JIT_COMPACT(b), round_up_capacity(n)))
+        compacted = False
+        if isinstance(b, _PendingJoinOutput):
+            b, compacted = b.emit(n)
+        if not compacted:
+            if 2 * n >= b.capacity:
+                return b  # dense: pass through (flushing pending first)
+            out_cap = round_up_capacity(n)
+            with _emit_phase(tracer, min(out_cap, b.capacity)):
+                b = _JIT_COMPACT(b, out_cap=out_cap)
+        pending.append(b)
         pending_live += n
         return None
 
@@ -667,7 +700,9 @@ def _merging_output(stream: Iterator[Batch], target_cap: int,
                 yield flush()
 
     for b in stream:
-        cnt = jnp.sum(b.live)
+        # a pending join output brings its count from the probe's program
+        cnt = (b.count if isinstance(b, _PendingJoinOutput)
+               else jnp.sum(b.live))
         try:
             cnt.copy_to_host_async()
         except Exception:
@@ -677,6 +712,18 @@ def _merging_output(stream: Iterator[Batch], target_cap: int,
     yield from drain(block_all=True)
     if pending:
         yield flush()
+
+
+def _emit_phase(tracer, lanes: int):
+    """One `join_emit` occurrence: a selective operator's output batch
+    materialised at `lanes` lanes (a pending join output gathered, a sparse
+    batch compacted). `items` and the process counter `join_emit_lanes`
+    carry the lanes: over the rows out they say how often the output was
+    sized by what matched."""
+    from presto_tpu.scan import metrics as _scan_metrics
+
+    _scan_metrics.record("join_emit_lanes", lanes)
+    return tracer.phase("join_emit", items=lanes)
 
 
 def _instrumented(stream: Iterator[Batch], node: PlanNode, ctx: ExecContext):
@@ -689,7 +736,7 @@ def _instrumented(stream: Iterator[Batch], node: PlanNode, ctx: ExecContext):
     while True:
         t0 = _time.perf_counter()
         try:
-            b = next(stream)
+            b = _gathered(next(stream))
         except StopIteration:
             return
         rows = int(jnp.sum(b.live))  # forces device sync
@@ -734,19 +781,9 @@ def _fused_child(node: PlanNode, ctx: ExecContext):
     """(raw input stream, chain-to-apply-inside-your-jit) for a breaker's
     child — the ScanFilterAndProject fusion point."""
     base, up = collapse_chain(node)
-    stream = _execute_base(base, ctx)
-    if ctx.config.collect_stats:
-        stream = _instrumented(stream, base, ctx)
-    if ctx.tracer.enabled:
-        stream = _traced(stream, base, ctx)
-    if ctx.config.merge_sparse_output and isinstance(
-            base, (HashJoin, MultiwayJoin, SemiJoin, NestedLoopJoin,
-                   IndexJoin)):
-        # breakers pull children through here, not execute_node — apply
-        # the same sparse-output coalescing before the consumer's chain
-        stream = _merging_output(stream, ctx.config.batch_rows,
-                                 bucket=ctx.config.shape_bucketing != "off",
-                                 tracer=ctx.tracer)
+    # breakers pull children through here, not execute_node — apply the
+    # same sparse-output coalescing before the consumer's chain
+    stream = _merged(_operator_stream(base, ctx), base, ctx)
     return stream, (up or (lambda b: b))
 
 
@@ -3886,7 +3923,7 @@ def _cat_batches(bs: List[Batch]) -> Batch:
 
 # module-level jit wrappers: trace caches persist across queries
 _JIT_CAT = jax.jit(_cat_batches)
-_JIT_COMPACT = jax.jit(compact)
+_JIT_COMPACT = jax.jit(compact, static_argnames=("out_cap",))
 _JIT_LIMIT = jax.jit(limit_batch)
 
 
@@ -4566,6 +4603,45 @@ def _observe_build_table(ctx: "ExecContext", table) -> float:
     return float(rows)
 
 
+class _PendingJoinOutput:
+    """What a unique probe knows of one batch before the join's output is
+    gathered: the chained probe batch, each row's build index and match
+    bit, and `count`, the rows the join hands on, as a device scalar from
+    the probe's own program. `emit(n)` gathers the output once the count
+    is on the host (`_merging_output`); `_gathered` does where nobody
+    reads it. One occurrence of `join_emit` either way."""
+
+    __slots__ = ("prober", "pb", "idx", "matched", "count")
+
+    def __init__(self, prober, pb, idx, matched, count):
+        self.prober, self.pb, self.idx = prober, pb, idx
+        self.matched, self.count = matched, count
+
+    def emit(self, n: Optional[int]):
+        """(batch, compacted) once the live count is `n`, or is not known
+        (None). Nothing is gathered for no row; an inner join's sparse
+        output (`2 n < capacity`, `_merging_output`'s rule) is gathered
+        compacted at `round_up_capacity(n)` lanes; everything else —
+        dense, LEFT / FULL, a count nobody read — at the probe's capacity
+        with the probe's columns handed on."""
+        if n == 0:
+            return None, False
+        prober, cap = self.prober, self.pb.capacity
+        sparse = (n is not None and prober.node.kind == "inner"
+                  and 2 * n < cap)
+        out_cap = min(round_up_capacity(n), cap) if sparse else None
+        with _emit_phase(prober.ctx.tracer, out_cap or cap):
+            out = prober.jemit(prober.table, self.pb, self.idx,
+                               self.matched, out_cap=out_cap)
+        return out, sparse
+
+
+def _gathered(b) -> Batch:
+    """`b` as a `Batch`: a pending join output gathered at the probe's
+    capacity, its count unread."""
+    return b.emit(None)[0] if isinstance(b, _PendingJoinOutput) else b
+
+
 class _JoinProber:
     """One build table, probed incrementally.
 
@@ -4575,7 +4651,10 @@ class _JoinProber:
     probe stream can only be consumed once, so probing cannot restart per
     partition. `probe_batch` yields the matches for one probe batch
     (LEFT/FULL null-extension included); `tail` yields the FULL OUTER
-    build remainder.
+    build remainder. With a unique build what a probe batch yields is one
+    `_PendingJoinOutput`: the probe's program stops at each row's build
+    index and the count of rows handed on, and its `emit` gathers the
+    output once that count is read, at the size of what matched.
     """
 
     def __init__(self, node: HashJoin, ctx: ExecContext,
@@ -4670,6 +4749,9 @@ class _JoinProber:
         if node.build_unique:
 
             def probe_fn(table, pb: Batch, bm):
+                """Which build row each probe row matches, and how many
+                rows the join will hand on: nothing of the output is
+                gathered before that count is read (`emit_fn`)."""
                 pb = chain(pb)
                 pba = align_probe_strings(pb, tuple(node.left_keys), table, tuple(node.right_keys))
                 if engine == "hash":
@@ -4678,27 +4760,43 @@ class _JoinProber:
                         _join_plan_cdt(node))
                 else:
                     idx, matched = probe_unique(table, pba, tuple(node.left_keys), tuple(node.right_keys))
-                out = gather_join_output(
-                    pb, table, jnp.arange(pb.capacity, dtype=jnp.int32), idx,
-                    pb.live, lsyms, rsyms,
-                )
                 if bm is not None:
                     bm = bm.at[idx].max(matched & pb.live, mode="drop")
-                n_probe = jnp.sum(pb.live).astype(jnp.int64)
+                # left/full outer keep every probe row
+                keep = pb.live & matched if node.kind == "inner" else pb.live
+                return (pb, idx, matched, bm,
+                        jnp.sum(pb.live).astype(jnp.int64), jnp.sum(keep))
+
+            def emit_fn(table, pb: Batch, idx, matched, out_cap):
+                """The join's output for one probed batch. `out_cap` None:
+                at the probe's capacity, the probe's columns handed on as
+                they are and the build's gathered through `idx`. An
+                `out_cap` (inner joins): compacted to that many lanes,
+                each plane gathered once through the head of the
+                compaction order."""
+                live = pb.live & matched if node.kind == "inner" else pb.live
+                rows = None
+                if out_cap is not None:
+                    rows = compact_permutation(live, out_cap)
+                    idx, live = idx[rows], live[rows]
+                out = gather_join_output(pb, table, rows, idx, live,
+                                         lsyms, rsyms)
                 if node.kind == "inner":
-                    return out.with_live(out.live & matched), bm, n_probe
-                # left/full outer: keep probe rows; null out build columns
-                # where unmatched
+                    return out
+                # left/full outer: null out build columns where unmatched
                 cols = list(out.columns)
                 for i, nme in enumerate(out.names):
                     if nme in rsyms:
                         c = cols[i]
-                        valid = c.validity if c.validity is not None else jnp.ones(out.capacity, bool)
-                        cols[i] = Column(c.values, valid & matched, c.hi)
-                return (Batch(out.names, out.types, cols, out.live,
-                              out.dicts), bm, n_probe)
+                        cols[i] = Column(
+                            c.values,
+                            matched if c.validity is None else c.validity & matched,
+                            c.hi, c.sizes, c.evalid, c.keys)
+                return Batch(out.names, out.types, cols, out.live, out.dicts)
 
             self.jfn = _node_jit(node, _ek(jkey + "probe"), lambda: probe_fn)
+            self.jemit = _node_jit(node, _ek(jkey + "emit"), lambda: emit_fn,
+                                   static_argnames=("out_cap",))
             return
 
         # general fanout join (inner / left): counts pass + chunked
@@ -4837,11 +4935,13 @@ class _JoinProber:
         ph = self.ctx.tracer.phase("join_probe")
         with ph:
             if node.build_unique:
-                out, self.bm, n_probe = self.jfn(table, pb_raw, self.bm)
+                pb, idx, matched, self.bm, n_probe, count = self.jfn(
+                    table, pb_raw, self.bm)
                 self._n_probe = self._n_probe + n_probe
-                self._n_out = self._n_out + jnp.sum(out.live)
+                self._n_out = self._n_out + count
                 ph.items = 1
-                return ("u", out)
+                return ("u", _PendingJoinOutput(self, pb, idx, matched,
+                                                count))
             pb, pba = self.chain_j(table, pb_raw)
             self._n_probe = self._n_probe + jnp.sum(pb.live)
             lo, counts, offsets, total, _, ovf = self.counts_fn(table, pba)
@@ -5420,6 +5520,8 @@ def _mw_binary_cascade(node: MultiwayJoin, ctx: ExecContext,
     for i, shim in enumerate(shims):
         leg_chain = chain if i == 0 else ident
         jkey = f"mwb{i}_"
+        # a leg probes batches: the leg before may hand on pending outputs
+        stream = map(_gathered, stream) if i else stream
         if pressure_at is None or i < pressure_at:
             build_in = (_collect_concat(iter(collected[i]))
                         if i < len(collected) else

@@ -730,21 +730,24 @@ def multiway_probe_unique(tables, probe: Batch, specs, probe_cols,
 def gather_join_output(
     probe: Batch,
     table: BuildTable,
-    probe_row: jnp.ndarray,
+    probe_row: Optional[jnp.ndarray],
     build_idx: jnp.ndarray,
     out_live: jnp.ndarray,
     probe_cols: Sequence[str],
     build_cols: Sequence[str],
     build_prefix: str = "",
 ) -> Batch:
-    """Materialize an inner-join output batch from index vectors."""
+    """Materialize an inner-join output batch from index vectors.
+    `probe_row` None: the output is row-aligned with the probe batch, whose
+    columns are handed on as they are."""
     names, types, cols = [], [], []
     dicts = {}
     for c in probe_cols:
         names.append(c)
         types.append(probe.type_of(c))
         # Column.gather preserves validity AND the long-decimal hi limb
-        cols.append(probe.column(c).gather(probe_row))
+        col = probe.column(c)
+        cols.append(col if probe_row is None else col.gather(probe_row))
         if c in probe.dicts:
             dicts[c] = probe.dicts[c]
     for c in build_cols:

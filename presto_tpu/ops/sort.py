@@ -77,14 +77,29 @@ def sort_batch(b: Batch, keys: Sequence[SortKey], limit: Optional[int] = None) -
     return out
 
 
-def compact(b: Batch) -> Batch:
-    """Move live rows to the front (stable). Dead lanes become trailing."""
-    n = b.capacity
-    perm_in = jnp.arange(n, dtype=jnp.int32)
-    out = jax.lax.sort(
-        [(~b.live).astype(jnp.int32), perm_in], num_keys=1, is_stable=True
-    )
-    return permute_batch(b, out[-1])
+def compact_permutation(live: jnp.ndarray,
+                        out_cap: Optional[int] = None) -> jnp.ndarray:
+    """Lanes of `live` in compaction order (live lanes first, both groups
+    in their original order): a stable sort on the dead bit. With a static
+    `out_cap` only the first `out_cap` entries, which is all that a result
+    of that many lanes is gathered through."""
+    n = live.shape[0]
+    perm = jax.lax.sort(
+        [(~live).astype(jnp.int32), jnp.arange(n, dtype=jnp.int32)],
+        num_keys=1, is_stable=True)[-1]
+    return perm if out_cap is None else perm[:min(out_cap, n)]
+
+
+def compact(b: Batch, out_cap: Optional[int] = None) -> Batch:
+    """Move live rows to the front (stable). Dead lanes become trailing.
+
+    With a static `out_cap` the result has `min(out_cap, capacity)` lanes:
+    every plane is gathered through the head of the permutation alone, so
+    what is moved is sized by what is kept and not by the input's
+    capacity. Plane for plane it is the whole compaction cut to `out_cap`
+    lanes; the caller gives a capacity of at least the live count, or
+    live rows are dropped."""
+    return permute_batch(b, compact_permutation(b.live, out_cap))
 
 
 def limit_batch(b: Batch, n: int) -> Batch:

@@ -16,7 +16,7 @@ COUNTER_NAMES = (
     # (radix-partitioned breakers + join fanout estimation, PR 3)
     "join_fanout_overflow_rows", "radix_partitions_spilled",
     "radix_spill_bytes", "radix_aligned_batches",
-    "join_search_steps",
+    "join_search_steps", "join_emit_lanes",
 )
 
 # dispatch-count counters for whole-fragment fusion (exec/fragment_jit.py):
@@ -68,6 +68,10 @@ _HELP = {
         "halving rounds a sort-engine join probe runs inside one bucket of "
         "its build's directory, summed over the builds observed "
         "(ops/join.py: search_steps)",
+    "join_emit_lanes":
+        "lanes of the batches a join or semi-join materialised once their "
+        "live count was read: a pending join output gathered, a sparse "
+        "batch compacted (exec/runtime.py: _emit_phase)",
     "fragment_dispatches":
         "fused whole-fragment device dispatches (one lax.scan program "
         "covering a stacked window of batches)",

@@ -6,7 +6,8 @@ general path alone reads `join_total` once a batch, so `join_probe`'s `n` less
 `host_sync:join_total`'s is the batches that took the single-match path
 (every batch of Q3, none of a join that fans out); with `tracing=false` the
 answer is the same and nothing is recorded; a statement without a join
-records none of them. And the one thing the tracer learned for it: an
+records none of them; `join_emit` is one occurrence a batch whose output was
+gathered once its count was read, `items` its lanes. And the one thing the tracer learned for it: an
 occurrence that a generator leaves before a `yield` and enters again after
 counts once."""
 
@@ -22,7 +23,7 @@ from presto_tpu.obs import trace as obs_trace
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BATCH = 8192
-JOIN_PHASES = ("join_build", "join_probe", "join_search",
+JOIN_PHASES = ("join_build", "join_probe", "join_search", "join_emit",
                "host_sync:join_build_rows",
                "host_sync:join_total", "host_sync:join_overflow",
                "host_sync:join_output_rows", "host_sync:join_selectivity")
@@ -98,6 +99,11 @@ def test_q3_records_a_phase_for_every_join_and_every_probe_batch(url):
     assert "host_sync:join_overflow" not in task
     # and each join's output is counted once a batch where it is merged
     assert task["host_sync:join_output_rows"]["n"] == batches
+    # and gathered after it, at most once a batch (never for no row), at
+    # lanes that repeat exactly
+    emit, emit_again = (d["phases"]["task"]["join_emit"] for d in (first, second))
+    assert 0 < emit["n"] <= batches
+    assert (emit["n"], emit["items"]) == (emit_again["n"], emit_again["items"])
     # the program calls and the reads are the phases' children: what is
     # left to `join_build` and `join_probe` is the host's own share
     for name in ("join_build", "join_probe"):

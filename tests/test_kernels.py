@@ -363,6 +363,55 @@ class TestSortCompact:
         np.testing.assert_allclose(d["v"], v[live])
 
 
+CAP = 256
+
+
+def planes_batch(rng, n):
+    """A batch of capacity 512 with `n` live rows scattered over it and one
+    column of every kind of plane: validity, a long decimal's `hi`, an
+    array's 2-D `values` and `evalid` with `sizes`, a map's `keys`."""
+    from presto_tpu.types import ArrayType, DecimalType, MapType
+
+    cap = 512
+    live = np.zeros(cap, bool)
+    live[rng.choice(cap, n, replace=False)] = True
+    ints = lambda *shape: jnp.asarray(rng.integers(0, 1 << 30, shape))  # noqa: E731
+    bools = lambda *shape: jnp.asarray(rng.random(shape) < 0.7)  # noqa: E731
+    cols = [Column(ints(cap), None),
+            Column(ints(cap), bools(cap)),
+            Column(ints(cap), bools(cap), hi=ints(cap)),
+            Column(ints(cap, 3), bools(cap), sizes=ints(cap) % 4,
+                   evalid=bools(cap, 3)),
+            Column(ints(cap, 3), None, sizes=ints(cap) % 4, keys=ints(cap, 3))]
+    types = [BIGINT, BIGINT, DecimalType(30, 2), ArrayType(BIGINT),
+             MapType(BIGINT, BIGINT)]
+    return Batch(["a", "v", "d", "arr", "m"], types, cols, jnp.asarray(live), {})
+
+
+@pytest.mark.parametrize("n,cap", [(0, 128), (1, 128), (CAP, CAP), (40, CAP),
+                                   (300, 1024)])
+def test_compact_at_an_output_capacity_is_the_whole_compaction_cut(rng, n, cap):
+    """`compact(b, out_cap)` gathers `out_cap` lanes of every plane: what
+    comes out is `_truncate(compact(b), out_cap)` plane for plane, for no
+    live row, one, exactly `out_cap`, fewer, and a capacity past the
+    batch's own (which keeps the batch's)."""
+    from presto_tpu.exec.runtime import _JIT_COMPACT, _truncate
+
+    b = planes_batch(rng, n)
+    got, want = _JIT_COMPACT(b, out_cap=cap), _truncate(compact(b), cap)
+    assert got.capacity == want.capacity == min(cap, b.capacity)
+    assert int(got.num_live()) == n
+    np.testing.assert_array_equal(np.asarray(got.live), np.asarray(want.live))
+    for name in b.names:
+        g, w = got.column(name), want.column(name)
+        for plane in Column.__slots__:
+            gp, wp = getattr(g, plane), getattr(w, plane)
+            assert (gp is None) == (wp is None), (name, plane)
+            if gp is not None:
+                np.testing.assert_array_equal(np.asarray(gp), np.asarray(wp),
+                                              err_msg=f"{name}.{plane}")
+
+
 class TestPartition:
     def test_counts_and_overflow(self, rng):
         n = 2000

@@ -187,3 +187,117 @@ def sort_runner(engines):
 def test_unique_above_a_join_vs_sqlite_under_the_sort_engine(
         engines, sort_runner, name):
     assert_matches_sqlite(sort_runner, engines[1], name)
+
+
+# -- a unique probe's output, gathered at the size of what matched ------------
+# `exec/runtime.py`: the probe's program stops at each row's build index and
+# the count of rows the join hands on; `_merging_output` reads that count (its
+# one read a batch) and the output is gathered compacted at
+# `round_up_capacity(n)` lanes (inner, sparse), at the probe's capacity with
+# the probe's columns handed on (dense, LEFT / FULL), or not at all (no row).
+
+FEW_ORDERS = "o_orderdate < date '1992-02-01'"
+LO = "lineitem {} join orders on l_orderkey = o_orderkey"
+OC = ("(select o_orderkey, o_custkey, o_totalprice from orders{}) o "
+      "full join customer on o_custkey = c_custkey")
+JOIN_EMIT = {          # (kind, density of the join's output): FROM ... WHERE
+    ("inner", "sparse"): LO.format("") + f" where {FEW_ORDERS}",
+    ("inner", "dense"): LO.format(""),
+    # orders of January 1992 ship no line in the second half of 1998
+    ("inner", "empty"): LO.format("") + f" where {FEW_ORDERS} "
+                                        "and l_shipdate > date '1998-06-01'",
+    ("left", "sparse"): LO.format("left") + f" and {FEW_ORDERS} "
+                                            "where l_shipdate < date '1992-03-01'",
+    ("left", "dense"): LO.format("left") + f" and {FEW_ORDERS}",
+    ("left", "empty"): LO.format("left") + " where l_shipdate < date '1990-01-01'",
+    ("full", "sparse"): OC.format(f" where {FEW_ORDERS}"),
+    ("full", "dense"): OC.format(""),
+    ("full", "empty"): OC.format(" where o_orderdate < date '1990-01-01'"),
+}
+# `grouped`: an aggregate pulls the join through `_fused_child`, so the count is
+# read before anything is gathered; `rows`: the projection above the join needs
+# a real batch, gathered at the probe's capacity before the count is read
+SELECT = {
+    ("grouped", "full"): "select c_nationkey, count(*) as n, count(o_orderkey) as m, "
+                         "sum(o_totalprice) as p, sum(c_acctbal) as b from {} "
+                         "group by c_nationkey",
+    ("rows", "full"): "select o_orderkey, o_totalprice, c_custkey, c_acctbal from {}",
+    ("grouped", None): "select l_linenumber, count(*) as n, count(o_orderdate) as m, "
+                       "sum(l_quantity) as q, sum(o_totalprice) as p, "
+                       "min(o_orderdate) as d from {} group by l_linenumber",
+    ("rows", None): "select l_orderkey, l_linenumber, l_quantity, o_orderdate, "
+                    "o_totalprice from {}",
+}
+DRIVERS = {"plain": {}, "radix": {"radix_partitions": 4},
+           "spilled": {"memory_pool_bytes": 100 << 10, "spill_partitions": 4},
+           "unmerged": {"merge_sparse_output": False}}
+JOIN_EMIT_CASES = (
+    [(k, d, e, "plain", "grouped") for (k, d) in JOIN_EMIT for e in ("sort", "hash")]
+    + [(k, d, "sort", "plain", "rows") for (k, d) in JOIN_EMIT]
+    + [(k, d, "sort", "radix", "grouped") for (k, d) in JOIN_EMIT]
+    + [(k, "sparse", "sort", d, "grouped") for k in ("inner", "left", "full")
+       for d in ("spilled", "unmerged")])
+
+
+@pytest.fixture(scope="module")
+def emit_runners(engines):
+    runner, _ = engines
+    made = {}
+
+    def get(engine, driver):
+        if (engine, driver) not in made:
+            made[engine, driver] = LocalRunner(runner.catalog, dataclasses.replace(
+                runner.config, breaker_engine=engine, **DRIVERS[driver]))
+        return made[engine, driver]
+
+    return get
+
+
+@pytest.mark.parametrize("kind,density,engine,driver,form", JOIN_EMIT_CASES)
+def test_a_unique_probes_output_vs_sqlite(engines, emit_runners, kind, density,
+                                          engine, driver, form):
+    from presto_tpu.obs import trace as obs_trace
+
+    runner, joined = emit_runners(engine, driver), JOIN_EMIT[kind, density]
+    sql = SELECT[form, kind if kind == "full" else None].format(joined)
+    (join,) = hash_joins(runner.plan(sql).root)
+    assert join.build_unique and join.kind == kind
+    QUERIES["join_emit"] = sql
+    try:
+        assert_matches_sqlite(runner, engines[1], "join_emit")
+    finally:
+        del QUERIES["join_emit"]
+    rows = engines[1].execute(
+        to_sqlite_sql(f"select count(*) from {joined}")).fetchone()[0]
+    assert (rows == 0) == (density == "empty" and kind != "full")
+    phases = {}                  # every thread role's occurrences summed
+    for by_name in obs_trace.phases_by_role(runner.last_trace.spans()).values():
+        for name, agg in by_name.items():
+            total = phases.setdefault(name, {"n": 0, "items": 0})
+            total["n"] += agg["n"]
+            total["items"] += agg.get("items", 0)
+    assert "host_sync:join_total" not in phases
+    if driver == "spilled":
+        assert runner.last_stats["spill.partitions"] > 0
+        return
+    if kind == "full":           # each prober's tail is a batch more
+        return
+    probes = phases.get("join_probe", {"n": 0})["n"]
+    if driver == "unmerged":     # nobody reads a count: all at capacity
+        assert "host_sync:join_output_rows" not in phases
+        assert phases["join_emit"]["n"] == probes
+        return
+    # one read of the live count a probe batch
+    assert phases.get("host_sync:join_output_rows", {"n": 0})["n"] == probes
+    emit = phases.get("join_emit", {"n": 0, "items": 0})
+    if form == "rows":
+        assert emit["n"] >= probes           # gathered before the projection
+    elif rows == 0:
+        assert emit["n"] == 0                # nothing is gathered for no row
+    elif (kind, density) == ("inner", "sparse"):
+        # every batch came compacted at the bucket of its own count
+        assert 0 < emit["n"] <= probes
+        assert emit["items"] <= 2 * rows + 128 * emit["n"]
+    elif density == "dense":
+        # at the probe's capacity: one occurrence a batch, never compacted
+        assert emit["n"] == probes
