@@ -726,6 +726,25 @@ def _emit_phase(tracer, lanes: int):
     return tracer.phase("join_emit", items=lanes)
 
 
+def _expand_phases(tracer, rows: int, lanes: int, overflow: int) -> None:
+    """What one probe batch on the join's general path expanded to, from
+    numbers the host has already read: `join_expand` (`items` = the batch's
+    `total`, its output rows), `join_expand_lanes` (`items` = chunks handed
+    on x `out_cap`: what was gathered, rows or not) and, where a probe row's
+    candidates passed the counting scan, `join_fanout_overflow` (`items` =
+    those rows). One occurrence each, no time of their own; the process
+    counter `join_expand_rows` carries the rows."""
+    from presto_tpu.scan import metrics as _scan_metrics
+
+    _scan_metrics.record("join_expand_rows", rows)
+    named = [("join_expand", rows), ("join_expand_lanes", lanes)]
+    if overflow:
+        named.append(("join_fanout_overflow", overflow))
+    for name, items in named:
+        with tracer.phase(name, items=items):
+            pass
+
+
 def _instrumented(stream: Iterator[Batch], node: PlanNode, ctx: ExecContext):
     """OperatorStats collection (reference: OperationTimer stamping every
     addInput/getOutput into OperatorStats, Driver.java:277)."""
@@ -5045,6 +5064,7 @@ class _JoinProber:
                 nb = self.jnull(table, pb, exists_acc)
                 self._n_out = self._n_out + jnp.sum(nb.live)
                 ph.items = 1
+        _expand_phases(self.ctx.tracer, tot, base, ovn)
         if nb is not None:
             yield nb
 
@@ -5392,6 +5412,7 @@ class _MultiwayProber:
                                out_cap)
             with phase("host_sync:join_overflow"):
                 ovn = np.asarray(ovfs)
+            ov_rows = 0
             if int(ovn.sum()):
                 # hash-leg fanout overflow: counts are EXACT but that leg's
                 # match matrix truncated — the dispatched chunk 0 would
@@ -5435,6 +5456,7 @@ class _MultiwayProber:
                 ph.items = 1
             yield out
             base += out_cap
+        _expand_phases(ctx.tracer, tot, base, ov_rows)
 
     def _note_overflow(self, ov_rows: int, _ovn) -> None:
         """Per-table overflow accounting into the same counters the binary

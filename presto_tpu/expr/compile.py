@@ -74,6 +74,15 @@ def _div_half_away(v, f: int):
     return jnp.sign(v) * ((av + f // 2) // f)
 
 
+def like_table(d: Dictionary, pattern, escape: str | None = None) -> np.ndarray:
+    """Which of a dictionary's values a LIKE pattern matches, indexed by
+    code + 1 (slot 0, NULL, is False); memoised on the dictionary, so the
+    planner's estimate (plan/stats.py) and the filter share one pass."""
+    rx = re.compile(like_to_regex(str(pattern), escape))
+    return d.int_lut(("like", pattern, escape),
+                     lambda s: rx.match(s) is not None, dtype=np.bool_)
+
+
 def like_to_regex(pattern: str, escape: str | None = None) -> str:
     """SQL LIKE pattern → anchored python regex (reference:
     operator/scalar/StringFunctions.java likePattern / LikeFunctions)."""
@@ -1082,9 +1091,7 @@ def _eval_call(e: Call, ctx: CompileContext):
         d = ctx.dict_for(val)
         if d is None:
             raise ValueError("LIKE on non-dictionary column")
-        rx = re.compile(like_to_regex(str(pat.value), escape))
-        table = d.int_lut(("like", pat.value, escape),
-                          lambda s: rx.match(s) is not None, dtype=np.bool_)
+        table = like_table(d, pat.value, escape)
         vv, vvalid = _eval(val, ctx)
         out = jnp.asarray(table)[vv + 1]
         return out, vvalid

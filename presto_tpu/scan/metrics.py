@@ -16,7 +16,7 @@ COUNTER_NAMES = (
     # (radix-partitioned breakers + join fanout estimation, PR 3)
     "join_fanout_overflow_rows", "radix_partitions_spilled",
     "radix_spill_bytes", "radix_aligned_batches",
-    "join_search_steps", "join_emit_lanes",
+    "join_search_steps", "join_emit_lanes", "join_expand_rows",
 )
 
 # dispatch-count counters for whole-fragment fusion (exec/fragment_jit.py):
@@ -72,6 +72,10 @@ _HELP = {
         "lanes of the batches a join or semi-join materialised once their "
         "live count was read: a pending join output gathered, a sparse "
         "batch compacted (exec/runtime.py: _emit_phase)",
+    "join_expand_rows":
+        "rows the probe batches on a join's general path (a build that "
+        "fans out) expanded to: each batch's total, read once by the host "
+        "(exec/runtime.py: _expand_phases)",
     "fragment_dispatches":
         "fused whole-fragment device dispatches (one lax.scan program "
         "covering a stacked window of batches)",

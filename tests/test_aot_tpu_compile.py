@@ -86,7 +86,8 @@ def test_grouped_sums_kernel_compiles(one_chip, groups, n_states):
 
 
 @pytest.mark.parametrize("program", [
-    "probe_unique", "probe_expand", "bucket_directory"])
+    "probe_unique", "probe_expand", "probe_expand_on_two_keys",
+    "bucket_directory"])
 def test_directory_probe_compiles(one_chip, program):
     """The sort engine's join probe (ops/join.py) at sf1_q3's shapes: a
     batch of 2^17 int64 keys against a sorted build of twelve such batches
@@ -94,7 +95,9 @@ def test_directory_probe_compiles(one_chip, program):
     the collision scan; the slot-to-row scatter and running sum; and the
     directory itself over hashes already sorted (the build's sort stays out
     of this file, as above; so does probe_counts, the same probe as
-    probe_unique with a scan of 8 candidates that compiles for 17 s)."""
+    probe_unique with a scan of 8 candidates that compiles for 17 s). On
+    two keys it is sf1_q9's expand: partsupp's batch against lineitem's
+    chain of 2^17 lanes."""
     from presto_tpu.batch import Batch, Column
     from presto_tpu.ops import join
     from presto_tpu.types import BIGINT
@@ -111,14 +114,16 @@ def test_directory_probe_compiles(one_chip, program):
             _sds((), jnp.int64, one_chip)).compile()
         assert compiled.memory_analysis() is not None
         return
-    table = jax.eval_shape(lambda b: join.build_side(b, ["bk"]),
-                           batch(["bk", "payload"], 12 * N))
-    probe = batch(["pk", "v"], N)
-    if program == "probe_expand":
+    two = program == "probe_expand_on_two_keys"
+    pk, bk = (["pk", "pk2"], ["bk", "bk2"]) if two else (["pk"], ["bk"])
+    table = jax.eval_shape(lambda b: join.build_side(b, bk),
+                           batch(bk + ["payload"], N if two else 12 * N))
+    probe = batch(pk + ["v"], N)
+    if program.startswith("probe_expand"):
         lo, counts, offsets, *_ = jax.eval_shape(
-            lambda t, p: join.probe_counts(t, p, ["pk"], ["bk"]), table, probe)
+            lambda t, p: join.probe_counts(t, p, pk, bk), table, probe)
         fn = lambda t, p, lo, c, o, base: join.probe_expand(  # noqa: E731
-            t, p, ["pk"], ["bk"], lo, c, o, base, N)
+            t, p, pk, bk, lo, c, o, base, N)
         args = (table, probe, lo, counts, offsets,
                 jax.ShapeDtypeStruct((), jnp.int64))
     else:
@@ -129,7 +134,7 @@ def test_directory_probe_compiles(one_chip, program):
     assert compiled.memory_analysis() is not None
     # no binary search of the whole build or of the prefix sums is left:
     # the one loop is the halving inside a bucket
-    assert compiled.as_text().count(" while(") == (program != "probe_expand")
+    assert compiled.as_text().count(" while(") == (program == "probe_unique")
 
 
 def test_q6_scan_filter_aggregate_chain_compiles(one_chip, monkeypatch):

@@ -28,8 +28,11 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 # The TPC-H texts whose plans the rule changes: a build that holds a join
 # and is unique all the same (hash joins marked unique, before -> after).
+# Q9 since PR 35 (a LIKE's share is read from the dictionary, so the 2 % of
+# lineitem under green parts builds, and orders and partsupp probe it): two
+# of its five joins fan out, whichever way the rule answers.
 UNIQUE_ABOVE_A_JOIN = {"q2": (3, 6), "q3": (1, 2), "q5": (1, 2), "q7": (2, 5),
-                       "q8": (3, 4), "q9": (3, 4), "q10": (1, 3),
+                       "q8": (3, 4), "q9": (2, 3), "q10": (1, 3),
                        "q11": (1, 2), "q18": (1, 2), "q21": (1, 2)}
 
 
@@ -194,7 +197,7 @@ def test_the_tpch_plans_it_changes(runner, monkeypatch):
                for name, flags in after.items() if flags != before[name]}
     assert changed == UNIQUE_ABOVE_A_JOIN
     assert [sum(map(len, d.values())) for d in (before, after)] == [52, 52]
-    assert [sum(map(sum, d.values())) for d in (before, after)] == [23, 38]
+    assert [sum(map(sum, d.values())) for d in (before, after)] == [22, 37]
 
 
 def test_explain_marks_both_of_q3s_joins_and_none_of_a_fan_out(runner):

@@ -9,7 +9,11 @@ answer is the same and nothing is recorded; a statement without a join
 records none of them; `join_emit` is one occurrence a batch whose output was
 gathered once its count was read, `items` its lanes. And the one thing the tracer learned for it: an
 occurrence that a generator leaves before a `yield` and enters again after
-counts once."""
+counts once. A batch on the general path also leaves `join_expand` (`items` =
+its rows), `join_expand_lanes` (chunks x `out_cap`) and, where a key's matches
+pass the counting scan of 8, `join_fanout_overflow`; the single-match path
+leaves none. TPC-H Q9 through the same path, its two-key join general in the
+plan, answers as `benchmark/reference/q9.py` does."""
 
 import json
 import math
@@ -23,8 +27,9 @@ from presto_tpu.obs import trace as obs_trace
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BATCH = 8192
+EXPAND_PHASES = ("join_expand", "join_expand_lanes", "join_fanout_overflow")
 JOIN_PHASES = ("join_build", "join_probe", "join_search", "join_emit",
-               "host_sync:join_build_rows",
+               *EXPAND_PHASES, "host_sync:join_build_rows",
                "host_sync:join_total", "host_sync:join_overflow",
                "host_sync:join_output_rows", "host_sync:join_selectivity")
 
@@ -110,6 +115,8 @@ def test_q3_records_a_phase_for_every_join_and_every_probe_batch(url):
         assert 0 <= task[name]["self_s"] < task[name]["busy_s"]
     named = sum(agg["self_s"] for agg in task.values())
     assert 0 < named <= first["task_wall_s"] * 1.01
+    # the single-match path expands nothing
+    assert not set(EXPAND_PHASES) & set(all_phases(first))
     # the counts repeat exactly
     counts = {k: (v["n"], v.get("items")) for k, v in task.items()}
     assert counts == {k: (v["n"], v.get("items"))
@@ -175,6 +182,98 @@ def test_a_small_join_out_capacity_adds_chunks_not_occurrences(url):
     unique = q3["phases"]["task"]
     assert unique["join_probe"]["items"] == unique["join_probe"]["n"] \
         == unique_batches(unique)
+
+
+# a key with more matches than the counting pass scans (8): the build
+# (`customer`, as written) holds some sixty customers a nation
+OVERFLOWS = ("select count(*) as n from nation join customer "
+             "on n_nationkey = c_nationkey")
+
+
+@pytest.mark.parametrize("out_capacity", [None, "16"])
+def test_a_general_batch_records_what_it_expanded_to(url, out_capacity):
+    from presto_tpu.scan import metrics
+
+    more = {} if out_capacity is None else {"join_out_capacity": out_capacity}
+    before = metrics.snapshot()["join_expand_rows"]
+    rows, summary = statement(url, FAN_OUT, **more)
+    task = summary["phases"]["task"]
+    general = task["host_sync:join_total"]["n"]
+    expand, lanes = task["join_expand"], task["join_expand_lanes"]
+    # one occurrence a general batch, with no time of its own
+    assert expand["n"] == lanes["n"] == general == task["join_probe"]["n"]
+    assert expand["busy_s"] + lanes["busy_s"] < 0.01 * task["join_probe"]["busy_s"]
+    # `items`: the rows the batches' chunks hold live - every one reaches
+    # the count(*) - and what the chunks gathered, rows or not
+    assert expand["items"] == rows[0][0] > 0
+    assert metrics.snapshot()["join_expand_rows"] - before == expand["items"]
+    assert lanes["items"] == task["join_probe"]["items"] * int(out_capacity or BATCH)
+    assert expand["items"] <= lanes["items"]
+    # an order has seven lines at the most: no key passes the scan of 8
+    assert "join_fanout_overflow" not in task
+
+
+def test_a_key_with_more_matches_than_the_scan_records_an_overflow(url):
+    from presto_tpu.scan import metrics
+
+    (customers,), = statement(url, "select count(*) from customer")[0]
+    before = metrics.snapshot()
+    rows, summary = statement(url, OVERFLOWS, breaker_engine="sort")
+    assert rows == [[customers]]
+    task = summary["phases"]["task"]
+    assert task["host_sync:join_total"]["n"] == task["join_expand"]["n"] == 1
+    assert task["join_expand"]["items"] == customers
+    # each of the 25 nations' candidates passed the scan
+    overflow = task["join_fanout_overflow"]
+    assert (overflow["n"], overflow["items"]) == (1, 25)
+    after = metrics.snapshot()
+    assert after["join_fanout_overflow_rows"] - before["join_fanout_overflow_rows"] == 25
+    assert after["join_expand_rows"] - before["join_expand_rows"] == customers
+    # with tracing off the answer is the same and nothing is recorded
+    rows_off, none = statement(url, OVERFLOWS, breaker_engine="sort", tracing="false")
+    assert rows_off == rows and none is None
+
+
+@pytest.mark.parametrize("seed", [9, 2147483909])
+def test_q9_answers_as_its_reference_with_its_two_key_join_general(seed):
+    """Its own cluster a seed: the reference reads the arrays the catalog
+    serves (`benchmark/data.py`), which the module's catalog did not get."""
+    import sys
+
+    from presto_tpu.server.__main__ import build_catalog
+    from presto_tpu.server.coordinator import DistributedRunner
+
+    if ROOT not in sys.path:
+        sys.path.insert(0, ROOT)
+    from benchmark import data as bdata, run as brun, traffic
+
+    query = traffic.load_query("q9")
+    data = bdata.generate(0.01, seed, sorted(query["tables"]))
+    catalog = build_catalog(["tpch:sf=0.01"])
+    bdata.install(catalog, 0.01, seed, data)
+    sql = query_text("q9")
+    with DistributedRunner(catalog, n_workers=1) as dr:
+        joins = [line for line in dr.explain_distributed(sql).splitlines()
+                 if "HashJoin" in line]
+        rows, summary = statement(dr.coordinator.url, sql, breaker_engine="sort")
+    # five joins, three of them on a unique build; orders' build fans out,
+    # and so does the one on two keys that partsupp probes: lineitem's chain
+    assert len(joins) == 5 and sum("unique" in j for j in joins) == 3
+    (two_keys,) = [j for j in joins if "ps_partkey" in j]
+    assert "['ps_suppkey', 'ps_partkey'] = ['l_suppkey', 'l_partkey']" in two_keys
+    assert "unique" not in two_keys
+    expected = brun.load_reference("q9")(data, query["params"]["fixed"])
+    assert [[n, y, str(p)] for n, y, p in expected] == rows and len(rows) > 100
+    task = summary["phases"]["task"]
+    # orders' batches and partsupp's take the general path, lineitem's
+    # (under part) and the two small joins' single batches the other
+    general = sum(math.ceil(len(data[t][k]) / BATCH) for t, k in (
+        ("orders", "o_orderkey"), ("partsupp", "ps_partkey")))
+    assert task["host_sync:join_total"]["n"] == task["join_expand"]["n"] == general
+    assert unique_batches(task) == math.ceil(
+        len(data["lineitem"]["l_orderkey"]) / BATCH) + 2
+    assert task["join_expand"]["items"] < task["join_expand_lanes"]["items"] \
+        == general * BATCH
 
 
 @pytest.mark.parametrize("qid", ["q6", "q1"])

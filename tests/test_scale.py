@@ -64,11 +64,12 @@ order by revenue desc, o_orderdate limit 10
 # Every predicate holds for (nearly) every order, and the CBO discounts each
 # one: it expects a few hundred groups and sizes the table at 512 slots
 # where 5,000 custkeys arrive. Without them the table is sized from
-# o_custkey's NDV and never grows.
+# o_custkey's NDV and never grows. (Function calls, not LIKEs: a LIKE's share
+# is read from the column's dictionary since PR 35, and is about 1 here.)
 GROWTH = """
 select o_custkey, count(*) as n, sum(o_totalprice) as s
 from orders
-where o_comment like '%e%' and o_clerk like 'Clerk%'
+where length(o_comment) > 0 and strpos(o_clerk, 'Clerk') = 1
   and o_orderpriority <> 'x'
 group by o_custkey order by n desc, o_custkey limit 20
 """

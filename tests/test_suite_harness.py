@@ -1,6 +1,7 @@
 """The suite's own harness (tests/conftest.py): a test's limit and the
 bound on a worker's memory mappings."""
 
+import gc
 import os
 import subprocess
 import sys
@@ -54,6 +55,9 @@ def test_dropping_compiled_programs_gives_their_mappings_back():
         pytest.skip("no /proc/self/maps here")
     fns = [jax.jit(lambda x, i=i: jnp.cumsum(jnp.sort(x)) + i)
            for i in range(12)]
+    # an earlier test's garbage may still hold programs: a collection in the
+    # middle of this one gave their mappings back and read as -1,655 grown
+    gc.collect()
     before = live_maps()
     for i, f in enumerate(fns):
         f(jnp.arange(64 + i))
