@@ -4609,16 +4609,20 @@ def _join_plan_cdt(node) -> tuple:
 
 def _observe_build_table(ctx: "ExecContext", table) -> float:
     """A built join table's live row count, read once from the device; the
-    sorted engine's bucket-search steps ride in the same transfer and are
-    reported as `items` of one `join_search` occurrence a build."""
+    sorted engine's bucket-search steps and unique-probe verify width ride
+    in the same transfer and are reported as `items` of one `join_search`
+    and one `join_verify` occurrence a build."""
     with ctx.tracer.phase("host_sync:join_build_rows"):
-        rows, steps = table_stats(table)
+        rows, steps, width = table_stats(table)
     if steps is not None:
         from presto_tpu.scan import metrics as _scan_metrics
 
-        with ctx.tracer.phase("join_search", items=steps):
-            pass
-        _scan_metrics.record("join_search_steps", steps)
+        for phase, counter, items in (
+                ("join_search", "join_search_steps", steps),
+                ("join_verify", "join_verify_width", width)):
+            with ctx.tracer.phase(phase, items=items):
+                pass
+            _scan_metrics.record(counter, items)
     return float(rows)
 
 

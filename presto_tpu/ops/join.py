@@ -16,6 +16,12 @@ give. Range semantics replace PositionLinks. Because we join on the hash,
 candidates are verified against the actual key columns (exact semantics
 even under hash collisions).
 
+The single-match probe needs no range end: it searches its bucket on `fp`,
+the 32 hash bits below the bucket's (one 32-bit gather a round where the
+64-bit hash costs the TPU two), and verifies lanes from there while any row
+is unresolved, at most `verify_width` of them: the widest run of lanes
+sharing bucket and `fp`, which a build of distinct keys holds to one.
+
 Fanout handling (the LookupJoinPageBuilder analog): a counts pass computes
 per-probe match counts and a prefix sum; materialization maps each output
 slot i back to (probe_row, ordinal) by counting the prefix sums' ends at
@@ -59,6 +65,12 @@ class BuildTable(NamedTuple):
     # probe's loop bound, a device scalar the host reads only where it
     # reads n_rows (table_stats)
     search_steps: jnp.ndarray
+    # fp[i] = the 32 bits of hashes[i] just below its bucket's k, unsigned:
+    # ordered within a bucket as hashes are
+    fp: jnp.ndarray  # uint32[cap]
+    # the widest run of live lanes sharing bucket and fp: lanes a unique
+    # probe may verify (table_stats reads it beside search_steps)
+    verify_width: jnp.ndarray
 
 
 _SENTINEL = jnp.iinfo(jnp.int64).max
@@ -114,9 +126,8 @@ def build_side(batch: Batch, key_names: Sequence[str]) -> BuildTable:
     sorted_h, sperm = jax.lax.sort([h, perm], num_keys=1)
     sorted_batch = permute_batch(batch.with_live(live), sperm)
     n = jnp.sum(live.astype(jnp.int64))
-    dir_, run_end, steps = _bucket_directory(sorted_h, n)
     return BuildTable(sorted_h, sorted_batch, n, batch.live[sperm],
-                      dir_, run_end, steps)
+                      *_bucket_directory(sorted_h, n))
 
 
 def _bucket_shift(cap: int) -> int:
@@ -139,39 +150,61 @@ def _running_sum(v: jnp.ndarray, width: int = 1024) -> jnp.ndarray:
     return (within + before[:, None]).reshape(-1)[:n]
 
 
+def _fingerprint(h: jnp.ndarray, shift: int) -> jnp.ndarray:
+    """The 32 bits of a hash just below its bucket's: shift >= 32 for every
+    capacity below 2^31."""
+    return ((h >> (shift - 32)) & 0xFFFFFFFF).astype(jnp.uint32)
+
+
+def _run_ids(v: jnp.ndarray):
+    """(first, run) of a sorted vector: whether a lane starts a run of equal
+    values, and its run's number, counted from 1."""
+    first = jnp.concatenate([jnp.ones((1,), bool), v[1:] != v[:-1]])
+    return first, _running_sum(first.astype(jnp.int32))
+
+
 def _bucket_directory(sorted_h: jnp.ndarray, n_rows):
-    """(dir, run_end, search_steps) of BuildTable over hashes already
-    sorted: histograms of the lanes' bucket and run ids and running sums —
-    no search."""
+    """(dir, run_end, search_steps, fp, verify_width) of BuildTable over
+    hashes already sorted: histograms of the lanes' bucket and run ids and
+    running sums — no search."""
     cap = sorted_h.shape[0]
     shift = _bucket_shift(cap)
     pos = jnp.arange(cap, dtype=jnp.int32)
+    live = (pos < n_rows).astype(jnp.int32)
     bucket = (sorted_h >> shift).astype(jnp.int32)
     sizes = jnp.zeros(1 << (63 - shift), jnp.int32).at[bucket].add(
-        (pos < n_rows).astype(jnp.int32), indices_are_sorted=True,
-        mode="promise_in_bounds")
+        live, indices_are_sorted=True, mode="promise_in_bounds")
     steps = 32 - jax.lax.clz(jnp.max(sizes))
     dir_ = jnp.concatenate([jnp.zeros(1, jnp.int32), _running_sum(sizes)])
-    # runs of equal hashes, numbered from 1: a run ends where the next one
-    # starts (slot 0 takes the writes of the lanes that start none)
-    first = jnp.concatenate(
-        [jnp.ones((1,), bool), sorted_h[1:] != sorted_h[:-1]])
-    run = _running_sum(first.astype(jnp.int32))
+    # runs of equal hashes: a run ends where the next one starts (slot 0
+    # takes the writes of the lanes that start none)
+    first, run = _run_ids(sorted_h)
     starts = jnp.full(cap + 2, cap, jnp.int32).at[
         jnp.where(first, run, 0)].set(pos, mode="promise_in_bounds")
-    return dir_, starts[run + 1], steps
+    # runs of equal bucket and fingerprint, their live lanes counted
+    _, fp_run = _run_ids(sorted_h >> (shift - 32))
+    widths = jnp.zeros(cap + 1, jnp.int32).at[fp_run].add(
+        live, indices_are_sorted=True, mode="promise_in_bounds")
+    return (dir_, starts[run + 1], steps, _fingerprint(sorted_h, shift),
+            jnp.max(widths))
 
 
-def _probe_ranges(table: BuildTable, probe: Batch, key_names: Sequence[str]):
-    """Candidate range [lo, hi) of every probe row in the sorted build:
-    what searching `table.hashes` for the row's hash from the left and from
-    the right would give."""
+def _probe_hash(probe: Batch, key_names: Sequence[str]):
+    """(hash, live) of the probe rows: a row with a NULL key is not live."""
     h = join_hash(probe, key_names)
     live = probe.live
     for k in key_names:
         v = probe.column(k).validity
         if v is not None:
             live = live & v
+    return h, live
+
+
+def _probe_ranges(table: BuildTable, probe: Batch, key_names: Sequence[str]):
+    """Candidate range [lo, hi) of every probe row in the sorted build:
+    what searching `table.hashes` for the row's hash from the left and from
+    the right would give."""
+    h, live = _probe_hash(probe, key_names)
     h = jnp.where(live, h, _SENTINEL - 1)  # never matches a real hash*
     hashes = table.hashes
     cap = hashes.shape[0]
@@ -226,34 +259,58 @@ def probe_unique(
     probe: Batch,
     probe_keys: Sequence[str],
     build_keys: Sequence[str],
-    collision_scan: int = 4,
 ):
     """Fast path: build keys are unique (dimension tables — the dominant
     TPC-H shape). Each probe row matches <= 1 build row.
 
-    A range [lo, hi) wider than 1 can only come from distinct build keys
-    sharing a 64-bit hash; `collision_scan` candidates are verified so the
-    exactness guarantee survives collisions (beyond-scan collisions of 4+
-    distinct keys on one hash are beyond astronomically unlikely, but are
-    counted and surfaced by callers that care via hi-lo).
+    The halving search inside the row's bucket runs on `fp`: `lo` is the
+    bucket's first lane whose fingerprint is not below the row's. Lanes
+    lo, lo + 1, ... are then verified against the key columns: a row is
+    resolved once it matched or once a lane's fingerprint differs from its
+    own, and the loop stops when every live row is resolved or after
+    `verify_width` lanes, past which no lane shares the row's bucket and
+    fingerprint. One round in a build of distinct keys; exact wherever
+    distinct keys share a hash (float keys hash by integer truncation) or
+    a key repeats (set-op membership).
 
     Returns (build_idx int32[cap], matched bool[cap]).
     """
-    _, lo, hi, live = _probe_ranges(table, probe, probe_keys)
+    h, live = _probe_hash(probe, probe_keys)
     cap = table.hashes.shape[0]
-    width = hi - lo
-    idx = jnp.clip(lo, 0, cap - 1).astype(jnp.int32)
-    matched = jnp.zeros(lo.shape, dtype=bool)
-    for j in range(collision_scan):
-        cand = jnp.clip(lo + j, 0, cap - 1).astype(jnp.int32)
-        ok = (
-            (j < width)
-            & ~matched
-            & _keys_equal(table, cand, probe, probe_keys, build_keys)
-        )
-        idx = jnp.where(ok, cand, idx)
-        matched = matched | ok
-    return idx, matched & live
+    shift = _bucket_shift(cap)
+    bucket = (h >> shift).astype(jnp.int32)
+    f = _fingerprint(h, shift)
+    fp = table.fp
+
+    def halve(_, ends):
+        lo, hi = ends
+        mid = (lo + hi) >> 1
+        below = (lo < hi) & (fp[mid] < f)
+        return jnp.where(below, mid + 1, lo), jnp.where(below, hi, mid)
+
+    lo, _ = jax.lax.fori_loop(
+        0, table.search_steps, halve,
+        (table.dir[bucket], table.dir[bucket + 1]))
+
+    def unresolved(state):
+        j, _, _, open_ = state
+        return (j < table.verify_width) & jnp.any(open_)
+
+    def verify(state):
+        j, idx, matched, open_ = state
+        at = lo + j
+        cand = jnp.minimum(at, cap - 1)
+        inside = at < table.n_rows
+        ok = open_ & inside & _keys_equal(table, cand, probe, probe_keys,
+                                          build_keys)
+        open_ = open_ & ~ok & inside & (fp[cand] == f)
+        return (j + 1, jnp.where(ok, cand, idx), matched | ok, open_)
+
+    _, idx, matched, _ = jax.lax.while_loop(
+        unresolved, verify,
+        (jnp.int32(0), jnp.minimum(lo, cap - 1), jnp.zeros(lo.shape, bool),
+         live))
+    return idx, matched
 
 
 @jax.named_scope("join_probe")
@@ -761,12 +818,15 @@ def gather_join_output(
 
 
 def table_stats(table):
-    """Host-synced (live row count, search steps) of a built join table:
-    ``n_rows`` of a BuildTable or HashJoinTable and, in the same transfer,
-    a BuildTable's ``search_steps`` (None for the hash engine's table,
-    which has no bucket search). One sync; the HBO observation path calls
-    it after the build phase has already materialized the table, so the
-    transfer is of ready scalars."""
-    rows, steps = jax.device_get(
-        (table.n_rows, getattr(table, "search_steps", None)))
-    return int(rows), None if steps is None else int(steps)  # lint: allow(host-sync)
+    """Host-synced (live row count, search steps, verify width) of a built
+    join table: ``n_rows`` of a BuildTable or HashJoinTable and, in the same
+    transfer, a BuildTable's ``search_steps`` and ``verify_width`` (None for
+    the hash engine's table, which has no bucket search). One sync; the HBO
+    observation path calls it after the build phase has already
+    materialized the table, so the transfer is of ready scalars."""
+    rows, steps, width = jax.device_get(
+        (table.n_rows, getattr(table, "search_steps", None),
+         getattr(table, "verify_width", None)))
+    if steps is None:
+        return int(rows), None, None  # lint: allow(host-sync)
+    return int(rows), int(steps), int(width)  # lint: allow(host-sync)

@@ -16,7 +16,8 @@ COUNTER_NAMES = (
     # (radix-partitioned breakers + join fanout estimation, PR 3)
     "join_fanout_overflow_rows", "radix_partitions_spilled",
     "radix_spill_bytes", "radix_aligned_batches",
-    "join_search_steps", "join_emit_lanes", "join_expand_rows",
+    "join_search_steps", "join_verify_width", "join_emit_lanes",
+    "join_expand_rows",
 )
 
 # dispatch-count counters for whole-fragment fusion (exec/fragment_jit.py):
@@ -68,6 +69,10 @@ _HELP = {
         "halving rounds a sort-engine join probe runs inside one bucket of "
         "its build's directory, summed over the builds observed "
         "(ops/join.py: search_steps)",
+    "join_verify_width":
+        "build lanes a sort-engine unique probe may verify past its bucket "
+        "search, the widest run sharing bucket and fingerprint, summed over "
+        "the builds observed (ops/join.py: verify_width)",
     "join_emit_lanes":
         "lanes of the batches a join or semi-join materialised once their "
         "live count was read: a pending join output gathered, a sparse "

@@ -91,8 +91,10 @@ def test_grouped_sums_kernel_compiles(one_chip, groups, n_states):
 def test_directory_probe_compiles(one_chip, program):
     """The sort engine's join probe (ops/join.py) at sf1_q3's shapes: a
     batch of 2^17 int64 keys against a sorted build of twelve such batches
-    — the directory gathers, the halving loop bounded by a device scalar,
-    the collision scan; the slot-to-row scatter and running sum; and the
+    — the directory gathers, the halving loop over 32-bit fingerprints
+    bounded by a device scalar, the verification of the lanes from there
+    bounded by another (one fingerprint and one key a round, the key's 64
+    bits as two 32-bit gathers); the slot-to-row scatter and running sum; and the
     directory itself over hashes already sorted (the build's sort stays out
     of this file, as above; so does probe_counts, the same probe as
     probe_unique with a scan of 8 candidates that compiles for 17 s). On
@@ -133,8 +135,13 @@ def test_directory_probe_compiles(one_chip, program):
     compiled = jax.jit(fn).lower(*_placed(args, one_chip)).compile()
     assert compiled.memory_analysis() is not None
     # no binary search of the whole build or of the prefix sums is left:
-    # the one loop is the halving inside a bucket
-    assert compiled.as_text().count(" while(") == (program == "probe_unique")
+    # the unique probe's loops are the halving inside a bucket and the
+    # verification; the expand has none
+    text = compiled.as_text()
+    assert text.count(" while(") == 2 * (program == "probe_unique")
+    if program == "probe_unique":
+        # every gather is of the whole batch: 15 before the fingerprint
+        assert text.count(" gather(") <= 6
 
 
 def test_q6_scan_filter_aggregate_chain_compiles(one_chip, monkeypatch):

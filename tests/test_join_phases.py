@@ -28,7 +28,8 @@ from presto_tpu.obs import trace as obs_trace
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BATCH = 8192
 EXPAND_PHASES = ("join_expand", "join_expand_lanes", "join_fanout_overflow")
-JOIN_PHASES = ("join_build", "join_probe", "join_search", "join_emit",
+JOIN_PHASES = ("join_build", "join_probe", "join_search", "join_verify",
+               "join_emit",
                *EXPAND_PHASES, "host_sync:join_build_rows",
                "host_sync:join_total", "host_sync:join_overflow",
                "host_sync:join_output_rows", "host_sync:join_selectivity")
@@ -138,6 +139,26 @@ def test_a_builds_search_steps_ride_with_its_row_count(url):
     assert 2 <= search["items"] <= 2 * 6
     assert metrics.snapshot()["join_search_steps"] - before == search["items"]
     assert search["busy_s"] < 0.01 * task["join_build"]["busy_s"]
+
+
+def test_a_builds_verify_width_rides_with_its_row_count(url):
+    """`join_verify`: one occurrence a build beside `join_build_rows`, its
+    `items` the table's `verify_width` - the lanes a unique probe may verify
+    past its bucket search, one in each of Q3's builds of distinct keys -
+    and the process counter alike; with tracing off the answer is the same
+    and no phase is recorded."""
+    from presto_tpu.scan import metrics
+
+    before = metrics.snapshot()["join_verify_width"]
+    rows, summary = statement(url, query_text("q3"))
+    task = summary["phases"]["task"]
+    verify = task["join_verify"]
+    assert verify["n"] == task["host_sync:join_build_rows"]["n"] == 2
+    assert verify["items"] == 2
+    assert metrics.snapshot()["join_verify_width"] - before == verify["items"]
+    assert verify["busy_s"] < 0.01 * task["join_build"]["busy_s"]
+    rows_off, none = statement(url, query_text("q3"), tracing="false")
+    assert rows_off == rows and none is None
 
 
 def test_tracing_off_gives_the_same_answer_and_records_nothing(url):

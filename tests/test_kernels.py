@@ -178,6 +178,27 @@ def _edge_hashes(batch, key_names):
     return jnp.asarray(np.array([_SENT, _SENT - 1, 7, 0], np.int64))[v % 4]
 
 
+def _pair_hashes(batch, key_names):
+    """join_hash under which keys 2i and 2i + 1 share bucket and fingerprint
+    (every bit from 44 up) and differ in the lowest bit alone: a unique
+    probe verifies two lanes to find the second."""
+    v = batch.column(key_names[0]).values.astype(jnp.int64)
+    return ((v // 2) << 44) | (v % 2)
+
+
+def _shifted_hashes(batch, key_names):
+    """join_hash that keeps the keys' order: one bucket, and a key above the
+    live ones searches to the first dead lane."""
+    return batch.column(key_names[0]).values.astype(jnp.int64) << 50
+
+
+def _double_batch(keys):
+    """A batch of exactly len(keys) lanes with one DOUBLE key column `id`."""
+    keys = np.asarray(keys, np.float64)
+    return Batch(["id"], [DOUBLE], [Column(jnp.asarray(keys), None)],
+                 jnp.ones(len(keys), bool), {})
+
+
 def _range_cases():
     r = np.random.default_rng(28)
     some = lambda n, p: r.random(n) < p  # noqa: E731
@@ -218,6 +239,22 @@ def _range_cases():
         "capacity_not_a_power_of_two": (
             _key_batch(r.integers(0, 900, 3000), some(3000, .9)),
             _key_batch(r.integers(0, 1000, 777), some(777, .9)), None),
+        # distinct keys whose hashes differ below the fingerprint alone
+        "pairs_share_bucket_and_fingerprint": (
+            _key_batch(r.permutation(nb)),
+            _key_batch(r.integers(0, nb + 64, npr)), _pair_hashes),
+        # distinct keys with one full hash: a float hashes by its integer part
+        "floats_share_a_hash_by_truncation": (
+            _double_batch(r.permutation(np.arange(nb)) / 10),
+            _double_batch(np.round(r.uniform(-5, nb / 10 + 5, npr), 1)), None),
+        # the dead lanes, where a search past the live ones ends, hold the key
+        "dead_lanes_hold_a_key_past_the_live_ones": (
+            _key_batch(np.r_[0:4, np.full(nb - 4, 5)], np.arange(nb) < 4),
+            _key_batch(r.integers(0, 7, npr)), _shifted_hashes),
+        # what set-op membership sends: one key, its every row
+        "one_key_repeated_1000_times": (
+            _key_batch(np.full(1000, 42)),
+            _key_batch(r.integers(40, 45, npr), some(npr, .9)), None),
     }
 
 
@@ -252,6 +289,52 @@ def test_probe_ranges_are_numpy_searchsorted_left_and_right(case, monkeypatch):
     assert (h[dead] == _SENT - 1).all()
     if case == "every_row_one_key":  # one bucket holds the build
         assert int(tbl.search_steps) == 11 and (hi - lo).max() == 1024
+
+
+@pytest.mark.parametrize("case", sorted(_RANGE_CASES))
+def test_probe_unique_is_a_numpy_lookup_among_the_live_build_keys(
+        case, monkeypatch):
+    """(idx, matched) against the live build keys, `idx` wherever a row
+    matched; `fp` and `verify_width` against the sorted hashes: the widest
+    run of live lanes sharing bucket and fingerprint."""
+    build, probe, patched_hash = _RANGE_CASES[case]
+    if patched_hash is not None:
+        monkeypatch.setattr(join_ops, "join_hash", patched_hash)
+    tbl = build_side(build, ("id",))
+    idx, matched = probe_unique(tbl, probe, ("id",), ("id",))
+    assert idx.dtype == jnp.int32 and matched.dtype == jnp.bool_
+
+    def live_keys(b):
+        c = b.column("id")
+        ok = np.asarray(b.live)
+        if c.validity is not None:
+            ok = ok & np.asarray(c.validity)
+        return np.asarray(c.values), ok
+
+    bkeys, blive = live_keys(build)
+    pkeys, plive = live_keys(probe)
+    want = plive & np.isin(pkeys, bkeys[blive])
+    if patched_hash is _edge_hashes:
+        # a live row hashed to the dead lanes' sentinel is not told from them
+        want &= np.asarray(patched_hash(probe, ("id",))) != _SENT
+        matched = np.asarray(matched) & want
+    np.testing.assert_array_equal(matched, want)
+    n = int(tbl.n_rows)
+    idx = np.asarray(idx)[want]
+    assert (idx < n).all()
+    np.testing.assert_array_equal(
+        np.asarray(tbl.batch.column("id").values)[idx], pkeys[want])
+    hashes = np.asarray(tbl.hashes)
+    shift = 63 - max(build.capacity - 1, 0).bit_length()
+    np.testing.assert_array_equal(
+        tbl.fp, ((hashes >> (shift - 32)) & 0xFFFFFFFF).astype(np.uint32))
+    _, runs = np.unique(hashes[:n] >> (shift - 32), return_counts=True)
+    assert int(tbl.verify_width) == (runs.max() if n else 0)
+    widths = {"distinct_keys_full_build": 1, "one_key_repeated_1000_times": 1000,
+              "pairs_share_bucket_and_fingerprint": 2,
+              "floats_share_a_hash_by_truncation": 10}
+    if case in widths:
+        assert int(tbl.verify_width) == widths[case]
 
 
 def _ends_cases():
@@ -308,11 +391,13 @@ def _loops(jaxpr):
 
 
 @pytest.mark.parametrize("program,loops", [
-    ("probe_counts", 1), ("probe_unique", 1), ("probe_expand", 0)])
+    ("probe_counts", 1), ("probe_unique", 2), ("probe_expand", 0)])
 def test_probe_programs_loop_only_in_the_bounded_finish(program, loops):
     """No binary search of the whole build or of the prefix sums is left:
-    the one loop of a probe is the halving inside a bucket, bounded by the
-    table's `search_steps`; mapping slots to rows has none."""
+    a probe's loops are the halving inside a bucket, bounded by the table's
+    `search_steps`, and, in the unique probe, the verification of the lanes
+    from there, bounded by its `verify_width`; mapping slots to rows has
+    none."""
     build = _key_batch(np.arange(1000) % 300)
     probe = _key_batch(np.arange(256))
     tbl = build_side(build, ("id",))
@@ -326,7 +411,7 @@ def test_probe_programs_loop_only_in_the_bounded_finish(program, loops):
         fn = {"probe_counts": probe_counts, "probe_unique": probe_unique}[program]
         jaxpr = jax.make_jaxpr(lambda t, p: fn(t, p, keys, keys))(tbl, probe)
     # a loop with a static trip count would be a `scan`: a `while` here is
-    # the one bounded by the table's device scalar
+    # one bounded by a device scalar of the table
     assert len(_loops(jaxpr.jaxpr)) == loops
     assert "sort" not in str(jaxpr).replace("indices_are_sorted", "")
 
