@@ -54,11 +54,14 @@ no-op inside jax). Wait phases stay out of the xplane: a thread blocked on
 a queue is not what the host was doing. The thread role is the thread's
 name with the task id cut off (`task`, `scan-prefetch`,
 `fragment-window-producer`); the thread that opens a trace's `query` span
-is `coordinator`. A dump carries each aggregate as one span of kind
-`phase`; when the `query` span closes, the phases (the absorbed tasks'
-too), the task and query walls and the span count are folded into one
-small summary per statement, kept for the last 1,024 statements of the
-process and read with `summaries()` — after the cluster is gone too.
+is `coordinator`, and a caller may name the role itself (`http`: a
+worker's request threads, whose names say nothing). A dump carries each
+aggregate as one span of kind `phase`; when the `query` span closes, the
+phases (the absorbed tasks' too), the task and query walls and the span
+count are folded into one small summary per statement (13 KB in memory for
+a three-table join's 31 phases over four roles, so about 50 MB when all
+are kept), kept for the last 4,096 statements of the process and read with
+`summaries()` — after the cluster is gone too.
 
 Everything is allocation-light: tracing disabled means every call site
 talks to the module NOOP singleton (`enabled=False` short-circuits before
@@ -268,21 +271,23 @@ class Tracer:
         self._phase_threads: List[_ThreadPhases] = []
         self._phase_ids: Dict[Tuple[str, str], str] = {}
 
-    def _phases_here(self) -> _ThreadPhases:
+    def _phases_here(self, role: Optional[str] = None) -> _ThreadPhases:
         st = getattr(self._tls, "phases", None)
         if st is None:
             st = self._tls.phases = _ThreadPhases(
-                _thread_role(threading.current_thread().name))
+                role or _thread_role(threading.current_thread().name))
             with self._lock:
                 self._phase_threads.append(st)
         return st
 
-    def phase(self, name: str, wait: bool = False, items: int = 0) -> _Phase:
+    def phase(self, name: str, wait: bool = False, items: int = 0,
+              role: Optional[str] = None) -> _Phase:
         """`with tracer.phase(name):` round one occurrence of an engine
         phase on this thread (module docstring). `wait=True` marks time
         spent blocked: aggregated, never annotated. `items` counts what
-        the occurrence handled (batches stacked)."""
-        return _Phase(self._phases_here(), name, not wait, items)
+        the occurrence handled (batches stacked). `role` names the
+        thread's role where its name does not, on its first phase."""
+        return _Phase(self._phases_here(role), name, not wait, items)
 
     def _stack(self) -> list:
         st = getattr(self._tls, "stack", None)
@@ -390,9 +395,9 @@ class Tracer:
 
     def summary(self) -> dict:
         """What one statement's trace comes to: its phases by thread role
-        (the absorbed tasks' included), the query and task walls, the
-        coordinator's wait on the root stream, and what the tracer itself
-        made. Small enough to keep after the trace is gone."""
+        (the absorbed tasks' included), the query and task walls, and what
+        the tracer itself made. Small enough to keep after the trace is
+        gone."""
         spans = self.spans()
         root = next((s for s in spans if s.span_id == self.root_id), None)
         tasks = [s.duration_s for s in spans if s.kind == "task"]
@@ -401,10 +406,6 @@ class Tracer:
             "wall_s": round(root.duration_s, 6) if root is not None else None,
             "tasks": len(tasks),
             "task_wall_s": round(sum(tasks), 6),
-            "exchange_wait_s": round(sum(
-                float((s.attrs or {}).get("wait_s") or 0.0) for s in spans
-                if s.kind == "exchange_wait"
-                and s.parent_id == self.root_id), 6),
             "spans": len(spans),
             "dropped": self.dropped,
             "phases": phases_by_role(spans),
@@ -443,7 +444,7 @@ class NoopTracer:
     def record(self, name, kind, start, end, parent_id=None, **attrs):
         return _NOOP_SPAN
 
-    def phase(self, name, wait=False, items=0):
+    def phase(self, name, wait=False, items=0, role=None):
         return _NOOP_PHASE
 
     def absorb(self, span_dicts, parent_map=None):
@@ -513,7 +514,7 @@ def phases_by_role(spans: List[Span]) -> Dict[str, Dict[str, dict]]:
 # per-statement summaries of the last statements traced in this process:
 # what the benchmark reads after the cluster is closed (the counterpart of
 # exec/programs.snapshot())
-_summaries: "deque[dict]" = deque(maxlen=1024)  # shared: guarded-by(_summaries_lock)
+_summaries: "deque[dict]" = deque(maxlen=4096)  # shared: guarded-by(_summaries_lock)
 _summaries_lock = threading.Lock()
 
 
@@ -523,7 +524,7 @@ def _keep_summary(doc: dict) -> None:
 
 
 def summaries() -> List[dict]:
-    """Tracer.summary() of the last 1,024 statements whose `query` span
+    """Tracer.summary() of the last 4,096 statements whose `query` span
     closed in this process, oldest first."""
     with _summaries_lock:
         return list(_summaries)

@@ -18,10 +18,13 @@ import json
 import struct
 from typing import Callable, List, Optional
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 from presto_tpu.batch import Batch, Column, round_up_capacity
 from presto_tpu.dictionary import Dictionary
+from presto_tpu.obs import trace as _obs_trace
 from presto_tpu.types import Type, parse_type
 
 _MAGIC = b"PTP1"
@@ -165,9 +168,28 @@ def _unpack_bits(data: bytes, n: int) -> np.ndarray:
     return np.unpackbits(np.frombuffer(data, np.uint8), count=n).astype(bool)
 
 
+def _planes(c: Column) -> tuple:
+    return (c.values, c.validity, c.hi, c.sizes, c.evalid, c.keys)
+
+
+def _flat(b: Batch) -> list:
+    """`b.live`, then each column's planes that are there."""
+    return [b.live] + [p for c in b.columns for p in _planes(c)
+                       if p is not None]
+
+
+def _regroup(flat: list, columns) -> tuple:
+    """(live, columns) again from `_flat`'s list, or from one of arrays made
+    from it in its order."""
+    it = iter(flat)
+    live = next(it)
+    return live, [Column(*(None if p is None else next(it)
+                           for p in _planes(c))) for c in columns]
+
+
 def serialize_batch(b: Batch, compress: bool = True,
                     radix: Optional[tuple] = None,
-                    dict_refs: bool = False) -> bytes:
+                    dict_refs: bool = False, tracer=None) -> bytes:
     """Compact live rows and serialize. Safe to call on device or host arrays.
 
     radix: (partition_id, num_partitions, key_names) — stamps the page so an
@@ -175,8 +197,32 @@ def serialize_batch(b: Batch, compress: bool = True,
     dict_refs: large dictionaries go on the wire as a content digest instead
     of their full value list; the consumer resolves a miss once through the
     /v1/dict side channel. Leave False for spill files, which must stay
-    self-contained."""
-    live = np.asarray(b.live)
+    self-contained.
+    tracer: an exchange's sink hands its task's tracer, and the page's trip
+    is three phases of it: `page_ready` (the device finishing the batch;
+    enabled tracers only, so a disabled one adds no call), `page_fetch`
+    (each plane copied to the host whole, `items` the bytes that came from
+    the device) and `page_encode` (the mask, the buffers, the header, zstd;
+    `items` the page's bytes)."""
+    tracer = tracer or _obs_trace.NOOP
+    device = _flat(b)
+    if tracer.enabled:
+        with tracer.phase("page_ready"):
+            jax.block_until_ready(device)
+    with tracer.phase("page_fetch") as ph:
+        host = [np.asarray(p) for p in device]
+        # np.asarray hands a host array back as it is: no copy, no bytes
+        ph.items = sum(h.nbytes for h, p in zip(host, device) if h is not p)
+    with tracer.phase("page_encode") as ph:
+        page = _encode(b, *_regroup(host, b.columns), radix, dict_refs,
+                       compress)
+        ph.items = len(page)
+    return page
+
+
+def _encode(b: Batch, live: np.ndarray, cols: List[Column], radix,
+            dict_refs: bool, compress: bool) -> bytes:
+    """The page of `b`, whose planes `live` and `cols` hold on the host."""
     n = int(live.sum())
     header = {"n": n, "names": list(b.names), "types": [str(t) for t in b.types],
               "validity": [], "limbs": [], "struct": [], "dicts": {}}
@@ -184,19 +230,17 @@ def serialize_batch(b: Batch, compress: bool = True,
         r, num, keys = radix
         header["radix"] = [int(r), int(num), list(keys)]
     buffers: List[bytes] = []
-    for name, t, c in zip(b.names, b.types, b.columns):
-        vals = np.asarray(c.values)[live]
-        buffers.append(np.ascontiguousarray(vals).tobytes())
+    for name, t, c in zip(b.names, b.types, cols):
+        buffers.append(np.ascontiguousarray(c.values[live]).tobytes())
         if c.validity is not None:
-            valid = np.asarray(c.validity)[live]
             header["validity"].append(True)
-            buffers.append(_pack_bits(valid))
+            buffers.append(_pack_bits(c.validity[live]))
         else:
             header["validity"].append(False)
         if c.hi is not None:
             # long-decimal high limb rides as a second int64 buffer
             header["limbs"].append(True)
-            buffers.append(np.ascontiguousarray(np.asarray(c.hi)[live]).tobytes())
+            buffers.append(np.ascontiguousarray(c.hi[live]).tobytes())
         else:
             header["limbs"].append(False)
         if c.sizes is not None:
@@ -208,14 +252,11 @@ def serialize_batch(b: Batch, compress: bool = True,
             header["struct"].append(
                 [w, has_ev, has_k,
                  str(c.keys.dtype) if has_k else None])
-            buffers.append(
-                np.ascontiguousarray(np.asarray(c.sizes)[live]).tobytes())
+            buffers.append(np.ascontiguousarray(c.sizes[live]).tobytes())
             if has_ev:
-                buffers.append(_pack_bits(
-                    np.asarray(c.evalid)[live].reshape(-1)))
+                buffers.append(_pack_bits(c.evalid[live].reshape(-1)))
             if has_k:
-                buffers.append(
-                    np.ascontiguousarray(np.asarray(c.keys)[live]).tobytes())
+                buffers.append(np.ascontiguousarray(c.keys[live]).tobytes())
         else:
             header["struct"].append(None)
         for dk in (name, name + "#keys"):
@@ -239,12 +280,34 @@ def serialize_batch(b: Batch, compress: bool = True,
 
 
 def deserialize_batch(data: bytes, capacity: Optional[int] = None,
-                      device_put: bool = False,
                       dict_resolver: Optional[Callable[[str], List[str]]]
-                      = None, host: bool = False) -> Batch:
+                      = None, host: bool = False, tracer=None) -> Batch:
     """`host=True` keeps every plane a numpy array of exactly the page's
     rows (no padding, nothing uploaded): what a consumer that packs pages
-    into batches of its own capacity wants (spiller.pack_pages)."""
+    into batches of its own capacity wants (spiller.pack_pages).
+    tracer: an exchange's consumer hands its tracer, and the page's trip
+    ends in two phases of it: `page_decode` (header, zstd, the padded host
+    planes, the dictionaries; `items` the page's bytes) and `page_upload`
+    (each plane put on the device; `items` the bytes put)."""
+    tracer = tracer or _obs_trace.NOOP
+    with tracer.phase("page_decode", items=len(data)):
+        b = _decode(data, capacity, dict_resolver, host)
+    if host:
+        return b
+    with tracer.phase("page_upload") as ph:
+        planes = _flat(b)
+        ph.items = sum(p.nbytes for p in planes)
+        live, cols = _regroup([jnp.asarray(p) for p in planes], b.columns)
+    if isinstance(b, TaggedBatch):
+        return TaggedBatch(b.names, b.types, cols, live, b.dicts, b.radix)
+    return Batch(b.names, b.types, cols, live, b.dicts)
+
+
+def _decode(data: bytes, capacity: Optional[int], dict_resolver,
+            host: bool) -> Batch:
+    """The page's batch with every plane a numpy array: padded to
+    `capacity` (the page's rows rounded up by default), or of exactly the
+    page's rows where `host`."""
     assert data[:4] == _MAGIC, "bad page magic"
     flags, hlen, plen = struct.unpack_from("<BII", data, 4)
     off = 4 + 9
@@ -256,9 +319,6 @@ def deserialize_batch(data: bytes, capacity: Optional[int] = None,
     cap = capacity or (n if host else round_up_capacity(max(n, 1)))
     names = header["names"]
     types = [parse_type(s) for s in header["types"]]
-    import jax.numpy as jnp
-
-    put = (lambda a: a) if host else jnp.asarray
 
     cols = []
     pos = 0
@@ -282,41 +342,36 @@ def deserialize_batch(data: bytes, capacity: Optional[int] = None,
             vb = (n + 7) // 8
             valid = _unpack_bits(payload[pos:pos + vb], n)
             pos += vb
-            vbuf = np.zeros(cap, dtype=bool)
-            vbuf[:n] = valid
-            valid_arr = put(vbuf)
+            valid_arr = np.zeros(cap, dtype=bool)
+            valid_arr[:n] = valid
         else:
             valid_arr = None
         hi_arr = None
         if has_hi:
             hi = np.frombuffer(payload, np.int64, count=n, offset=pos)
             pos += n * 8
-            hbuf = np.zeros(cap, dtype=np.int64)
-            hbuf[:n] = hi
-            hi_arr = put(hbuf)
+            hi_arr = np.zeros(cap, dtype=np.int64)
+            hi_arr[:n] = hi
         sizes_arr = evalid_arr = keys_arr = None
         if st is not None:
             _, has_ev, has_k, kdt = st
             sizes = np.frombuffer(payload, np.int32, count=n, offset=pos)
             pos += n * 4
-            sbuf = np.zeros(cap, np.int32)
-            sbuf[:n] = sizes
-            sizes_arr = put(sbuf)
+            sizes_arr = np.zeros(cap, np.int32)
+            sizes_arr[:n] = sizes
             if has_ev:
                 eb = (n * w + 7) // 8
                 ev = _unpack_bits(payload[pos:pos + eb], n * w)
                 pos += eb
-                ebuf = np.zeros((cap, w), bool)
-                ebuf[:n] = ev.reshape(n, w)
-                evalid_arr = put(ebuf)
+                evalid_arr = np.zeros((cap, w), bool)
+                evalid_arr[:n] = ev.reshape(n, w)
             if has_k:
                 kd = np.dtype(kdt)
                 keys = np.frombuffer(payload, kd, count=n * w, offset=pos)
                 pos += n * w * kd.itemsize
-                kbuf = np.zeros((cap, w), kd)
-                kbuf[:n] = keys.reshape(n, w)
-                keys_arr = put(kbuf)
-        cols.append(Column(put(buf), valid_arr, hi_arr,
+                keys_arr = np.zeros((cap, w), kd)
+                keys_arr[:n] = keys.reshape(n, w)
+        cols.append(Column(buf, valid_arr, hi_arr,
                            sizes_arr, evalid_arr, keys_arr))
     live = np.zeros(cap, dtype=bool)
     live[:n] = True
@@ -340,19 +395,6 @@ def deserialize_batch(data: bytes, capacity: Optional[int] = None,
             dicts[k] = intern_dictionary(np.asarray(v, dtype=object))
     rd = header.get("radix")
     if rd is not None:
-        b = TaggedBatch(names, types, cols, put(live), dicts,
-                        (int(rd[0]), int(rd[1]), tuple(rd[2])))
-    else:
-        b = Batch(names, types, cols, put(live), dicts)
-    if device_put:
-        import jax
-
-        if isinstance(b, TaggedBatch):
-            # TaggedBatch is not a registered pytree — move a plain view
-            moved = jax.device_put(Batch(b.names, b.types, b.columns,
-                                         b.live, b.dicts))
-            b = TaggedBatch(moved.names, moved.types, moved.columns,
-                            moved.live, moved.dicts, b.radix)
-        else:
-            b = jax.device_put(b)
-    return b
+        return TaggedBatch(names, types, cols, live, dicts,
+                           (int(rd[0]), int(rd[1]), tuple(rd[2])))
+    return Batch(names, types, cols, live, dicts)

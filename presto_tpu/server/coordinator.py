@@ -558,11 +558,12 @@ class DistributedScheduler:
             stream_w0 = time.time()
             waited = 0.0
             try:
-                it = client.batches()
+                it = client.pages()
                 while True:
                     w0 = time.monotonic()
                     try:
-                        b = next(it)
+                        with tracer.phase("exchange_wait", wait=True):
+                            page = next(it)
                     except StopIteration:
                         break
                     dt = time.monotonic() - w0
@@ -570,7 +571,7 @@ class DistributedScheduler:
                     if tracer.enabled:
                         _obs_metrics.EXCHANGE_WAIT.observe(
                             dt, plane="coordinator")
-                    yield b
+                    yield client.decode(page, tracer)
                 completed = True
             finally:
                 client.close()

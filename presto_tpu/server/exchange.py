@@ -175,9 +175,15 @@ class ExchangeClient:
             f"dictionary {digest[:12]} unresolvable from any upstream",
             task_error=True)
 
+    def decode(self, page: bytes, tracer=None) -> Batch:
+        """One page as a batch on the device; `tracer` records its
+        `page_decode` and `page_upload` (serde.deserialize_batch)."""
+        return deserialize_batch(page, dict_resolver=self._resolve_dict,
+                                 tracer=tracer)
+
     def batches(self) -> Iterator[Batch]:
         for page in self.pages():
-            yield deserialize_batch(page, dict_resolver=self._resolve_dict)
+            yield self.decode(page)
 
     def close(self):
         self.closed = True

@@ -232,12 +232,13 @@ class TaskExecution:
         return self._traced_exchange(client, fragment_id)
 
     def _traced_exchange(self, client: ExchangeClient, fragment_id: int):
-        """Exchange pull with consumer-blocked time accounted: each next()
-        wall goes to the exchange-wait histogram, and one exchange_wait
-        span records the stream envelope with total blocked seconds."""
+        """Exchange pull with consumer-blocked time accounted: each wait on
+        the page queue goes to the exchange-wait histogram, and one
+        exchange_wait span records the stream envelope with total blocked
+        seconds. Each page's decode and upload are phases of their own."""
         from presto_tpu.obs import metrics as _obs_metrics
 
-        it = client.batches()
+        it = client.pages()
         parent = self.tracer.current_parent()
         start = time.time()
         waited = 0.0
@@ -246,13 +247,13 @@ class TaskExecution:
                 w0 = time.perf_counter()
                 try:
                     with self.tracer.phase("exchange_wait", wait=True):
-                        b = next(it)
+                        page = next(it)
                 except StopIteration:
                     break
                 dt = time.perf_counter() - w0
                 waited += dt
                 _obs_metrics.EXCHANGE_WAIT.observe(dt, plane="worker")
-                yield b
+                yield client.decode(page, self.tracer)
         finally:
             self.tracer.record("exchange_wait", "exchange_wait", start,
                                time.time(), parent_id=parent,
@@ -434,7 +435,8 @@ class TaskExecution:
 
         def page_of(b: Batch, **kw):
             with phase("host_sync:sink_serialize"):
-                return serialize_batch(b, dict_refs=True, **kw)
+                return serialize_batch(b, dict_refs=True, tracer=self.tracer,
+                                       **kw)
 
         if f.output_partitioning == OUT_HASH and self.update.n_out_partitions > 1:
             pid_fn = _jit_partition_ids(
@@ -788,7 +790,14 @@ class Worker:
                         header = {"next_token": token, "complete": True,
                                   "task_state": t.state, "error": str(e)}
                         pages = []
-                    return self._bytes(encode_results_payload(header, pages))
+                    # a response with no page records nothing: the last one
+                    # a consumer reads is empty, so every page's phase has
+                    # closed before the task's trace is pulled
+                    tracer = t.tracer if pages else _obs_trace.NOOP
+                    with tracer.phase("page_serve", items=len(pages),
+                                      role="http"):
+                        return self._bytes(
+                            encode_results_payload(header, pages))
                 m = _ACK_RE.match(self.path)
                 if m:
                     t = worker.task_manager.get(m.group(1))

@@ -338,7 +338,9 @@ class TestPhases:
             coord.absorb(worker.to_json()["spans"])
         doc = obs_trace.summaries()[-1]
         assert doc["queryId"] == "T_sum" and doc["tasks"] == 1
-        assert doc["exchange_wait_s"] == 0.25 and doc["dropped"] == 0
+        # the exchange_wait spans stay in the trace, for the doctor; the
+        # summary no longer sums them
+        assert "exchange_wait_s" not in doc and doc["dropped"] == 0
         assert doc["spans"] == len(coord.spans())
         assert doc["phases"]["coordinator"]["schedule"]["n"] == 1
         role = obs_trace._thread_role(threading.current_thread().name)
@@ -563,9 +565,11 @@ def _run_statement(coord, sql):
 
 
 def _counts(summary):
+    # how a consumer's pulls group pages into responses follows the clock,
+    # so the request threads' `n` is left out
     return {(role, name): agg["n"]
             for role, by_name in summary["phases"].items()
-            for name, agg in by_name.items()}
+            for name, agg in by_name.items() if role != "http"}
 
 
 def test_statements_leave_phase_summaries_that_outlive_the_cluster():
@@ -605,6 +609,125 @@ def test_statements_leave_phase_summaries_that_outlive_the_cluster():
     assert "host_sync:breaker_finish" in by_id[ids["q1"][0]]["phases"]["task"]
 
 
+PAGE_PHASES = {"task": ("page_ready", "page_fetch", "page_encode",
+                        "page_decode", "page_upload"),
+               "http": ("page_serve",),
+               "coordinator": ("page_decode", "page_upload")}
+
+
+def _each(summary):
+    for role, by_name in summary["phases"].items():
+        for name, agg in by_name.items():
+            yield role, name, agg
+
+
+def _pages(summary, field):
+    return {(role, name): agg.get(field) for role, name, agg in _each(summary)
+            if name.startswith("page_")}
+
+
+def test_a_page_trip_is_six_phases():
+    """Q3 through one worker: a sink serializes each page in three phases
+    inside `host_sync:sink_serialize`, a request thread serves it, and its
+    consumer - a task, or the coordinator for the root stream - decodes and
+    uploads it, with `exchange_wait` round the queue alone."""
+    from presto_tpu.server.coordinator import DistributedRunner
+
+    with DistributedRunner(_lineitem_catalog(), n_workers=1) as dr:
+        sql = _query_text("q3")
+        ids = [_run_statement(dr.coordinator, sql) for _ in range(3)][1:]
+    by_id = {d["queryId"]: d for d in obs_trace.summaries()}
+    a, b = by_id[ids[0]], by_id[ids[1]]
+    for role, names in PAGE_PHASES.items():
+        for name in names:
+            assert a["phases"][role][name]["n"] >= 1, (role, name)
+    assert a["phases"]["coordinator"]["exchange_wait"]["wait"] is True
+    # the counts and the bytes repeat exactly; the request threads' `n`
+    # follows the clock (module `_counts`), the pages they served do not
+    assert _counts(a) == _counts(b)
+    assert _pages(a, "items") == _pages(b, "items")
+    # every page serialized is decoded once, the root stream's included
+    n = {name: sum(agg["n"] for _, nm, agg in _each(a) if nm == name)
+         for name in ("page_encode", "page_decode", "page_fetch")}
+    assert n["page_encode"] == n["page_decode"] == n["page_fetch"] >= 2
+    served = a["phases"]["http"]["page_serve"]
+    assert served["items"] == n["page_encode"] >= served["n"]
+    # the sink's three phases are inside its serialize, not beside it
+    task = a["phases"]["task"]
+    inside = sum(task[p]["busy_s"] for p in PAGE_PHASES["task"][:3])
+    assert inside <= task["host_sync:sink_serialize"]["busy_s"]
+    named = sum(agg["self_s"] for agg in task.values())
+    assert 0 < named <= a["task_wall_s"] * 1.01
+
+
+def test_spilled_pages_are_no_exchange_pages():
+    """A grace aggregation writes and reads its spill files through the
+    same serde, and records them as `agg_spill_write|read` alone."""
+    from presto_tpu.exec.runner import LocalRunner
+
+    r = LocalRunner(_lineitem_catalog(), ExecConfig(
+        batch_rows=1 << 13, agg_capacity=1 << 8, agg_cap_ceiling=1 << 11,
+        spill_partitions=4))
+    df = r.run("select l_orderkey, sum(l_quantity) as q from lineitem "
+               "group by l_orderkey")
+    assert len(df) > 1 << 11
+    names = {name for by_name in obs_trace.phases_by_role(
+        r.last_trace.spans()).values() for name in by_name}
+    assert {"agg_spill_write", "agg_spill_read"} <= names, names
+    assert not any(name.startswith("page_") for name in names), names
+
+
+def test_tracing_off_makes_the_device_calls_it_made(monkeypatch):
+    """With tracing off no phase is recorded and serde makes no wait of its
+    own on the device; on, a page waits once, and its bytes are the same."""
+    import jax
+    import jax.numpy as jnp
+
+    from presto_tpu import serde
+    from presto_tpu.batch import Batch, Column
+    from presto_tpu.server.coordinator import DistributedRunner
+    from presto_tpu.types import BIGINT, DOUBLE
+
+    waits = []
+    block = jax.block_until_ready
+
+    def counting(x):
+        if sys._getframe(1).f_code.co_filename == serde.__file__:
+            waits.append(1)
+        return block(x)
+
+    monkeypatch.setattr(jax, "block_until_ready", counting)
+    b = Batch(["k", "v"], [BIGINT, DOUBLE],
+              [Column(jnp.arange(8, dtype=jnp.int64),
+                      jnp.arange(8) % 3 > 0),
+               Column(jnp.linspace(0.0, 1.0, 8))],
+              jnp.arange(8) % 2 == 0)
+    plain = serde.serialize_batch(b)
+    assert waits == []
+    tr = obs_trace.Tracer()
+    assert serde.serialize_batch(b, tracer=tr) == plain
+    assert len(waits) == 1
+    back = serde.deserialize_batch(plain, tracer=tr)
+    assert np.asarray(back.columns[0].values)[:4].tolist() == [0, 2, 4, 6]
+    ph = obs_trace.phases_by_role(tr.spans())
+    (mine,) = ph.values()
+    # the planes whole: live 8 B, k 64 B and its validity 8 B, v 64 B
+    assert mine["page_fetch"]["items"] == 144
+    assert mine["page_encode"]["items"] == len(plain)
+    assert mine["page_decode"]["items"] == len(plain)
+    # four rows padded to 2^k lanes: live, k, its validity, v
+    cap = back.capacity
+    assert mine["page_upload"]["items"] == cap * (1 + 8 + 1 + 8)
+
+    before = len(obs_trace.summaries())
+    with DistributedRunner(_catalog(), n_workers=1,
+                           config=ExecConfig(tracing=False)) as dr:
+        df = dr.run("select k, sum(v) as s from t group by k")
+    assert len(df) == 7
+    assert len(obs_trace.summaries()) == before
+    assert len(waits) == 1
+
+
 def test_engine_phases_share_the_profilers_clock(tmp_path):
     """Off the chip: under a profiler session the phases are events on the
     engine threads' lines of the xplane, inside the benchmark's window."""
@@ -628,18 +751,31 @@ def test_engine_phases_share_the_profilers_clock(tmp_path):
     (path,) = glob.glob(os.path.join(
         str(tmp_path), "plugins", "profile", "*", "*.xplane.pb"))
     trace = trace_reduce.load_xplane(path)
-    window, found = None, {}
-    for plane in trace["planes"]:
-        for line in plane["lines"]:
+    window, found, lines = None, {}, {}
+    for p, plane in enumerate(trace["planes"]):
+        for i, line in enumerate(plane["lines"]):   # one an OS thread id
             for name, start, dur in line["events"]:
                 if name == trace_reduce.WINDOW_SPAN:
                     window = (start, start + dur)
                 elif name.startswith("engine:") and \
                         line["name"].startswith(trace_reduce.PYTHON_LINE):
                     found.setdefault(name, []).append((start, start + dur))
+                    lines.setdefault((p, i), []).append(
+                        (name, start, start + dur))
     assert window is not None
     assert "engine:window_stack" in found, sorted(found)
     assert any(n.startswith("engine:program_call:") for n in found)
+    for page in ("fetch", "encode", "decode", "upload"):
+        assert "engine:page_" + page in found, sorted(found)
+    # a worker's request thread serves the pages, not the sink that made
+    # them: no page is served inside a serialize on its own line
+    assert "engine:page_serve" in found, sorted(found)
+    for events in lines.values():
+        sinks = [(a, b) for n, a, b in events
+                 if n == "engine:host_sync:sink_serialize"]
+        for n, a, b in events:
+            if n == "engine:page_serve":
+                assert not any(s <= a and b <= e for s, e in sinks)
     assert not any("wait" in n or "queue_full" in n for n in found)
     for spans in found.values():
         for a, b in spans:
