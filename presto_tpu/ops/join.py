@@ -22,12 +22,15 @@ the 32 hash bits below the bucket's (one 32-bit gather a round where the
 is unresolved, at most `verify_width` of them: the widest run of lanes
 sharing bucket and `fp`, which a build of distinct keys holds to one.
 
-Fanout handling (the LookupJoinPageBuilder analog): a counts pass computes
-per-probe match counts and a prefix sum; materialization maps each output
-slot i back to (probe_row, ordinal) by counting the prefix sums' ends at
-or below i — one scatter-add of the ends and a running sum — fully
-vectorized, chunked by the driver when total matches exceed the output
-capacity.
+Fanout handling (the LookupJoinPageBuilder analog): a counts pass gives
+each probe row the width of its run of equal hashes, read from the
+directory alone, and a prefix sum; materialization maps each output slot i
+back to (probe_row, ordinal) by counting the prefix sums' ends at or below
+i — one scatter-add of the ends and a running sum — and verifies every
+pair against the key columns, fully vectorized, chunked by the prober when
+total matches exceed the output capacity. Where distinct keys share a hash
+(float keys hash by integer truncation) a run holds more lanes than match:
+the output capacity widens, the results do not.
 """
 
 from __future__ import annotations
@@ -323,36 +326,23 @@ def probe_counts(
 ):
     """General path, pass 1: per-probe-row candidate ranges and counts.
 
-    Hash-collision verification for the counting pass scans up to
-    `max_fanout_scan` candidates vectorized; ranges wider than that fall
-    back to counting hash matches (superset — rows are still verified and
-    masked at expand time, so correctness holds; only capacity estimation
-    widens). The number of probe rows that hit this widening is returned
-    as `overflow` so drivers can surface it as a counter instead of the
-    estimate silently inflating output capacity.
+    A live row's count is the width of its run of equal hashes, hi - lo,
+    read from the directory: no key column is gathered, so `build_keys` is
+    unused (kept for the callers' common signature). probe_expand verifies
+    every pair against the key columns, so where distinct keys share a
+    hash (float keys hash by integer truncation, and their verified
+    matches need not be contiguous) the output capacity widens and the
+    results do not. `overflow` counts the live rows whose run is wider
+    than `max_fanout_scan`, for the prober to surface as a counter.
 
     Returns (lo int32[cap], counts, offsets, total, live, overflow).
     """
     _, lo, hi, live = _probe_ranges(table, probe, probe_keys)
-    width = hi - lo
-    counts = jnp.zeros(width.shape, dtype=jnp.int64)
-    cap = table.hashes.shape[0]
-    for j in range(max_fanout_scan):
-        idx = jnp.clip(lo + j, 0, cap - 1).astype(jnp.int32)
-        ok = (j < width) & _keys_equal(table, idx, probe, probe_keys, build_keys)
-        counts = counts + ok.astype(jnp.int64)
-    # A range can verify NON-contiguously when distinct keys share a hash
-    # (float keys hash by integer truncation, so every value with the same
-    # integer part collides). probe_expand assumes verified matches start
-    # at lo, so emit the whole range in that case and let expand's key
-    # verification mask the non-matches — capacity widens, results don't.
-    counts = jnp.where(counts == width, counts, width)
-    widened = live & (width > max_fanout_scan)
-    counts = jnp.where(width > max_fanout_scan, width, counts)
-    counts = jnp.where(live, counts, 0)
+    width = (hi - lo).astype(jnp.int64)
+    counts = jnp.where(live, width, 0)
     offsets = jnp.cumsum(counts) - counts  # exclusive prefix sum
     total = jnp.sum(counts)
-    overflow = jnp.sum(widened.astype(jnp.int64))
+    overflow = jnp.sum((live & (width > max_fanout_scan)).astype(jnp.int64))
     return lo.astype(jnp.int32), counts, offsets, total, live, overflow
 
 

@@ -55,8 +55,8 @@ _HELP = {
     "bytes_skipped":
         "payload bytes never uploaded thanks to predicate-during-decode",
     "join_fanout_overflow_rows":
-        "probe rows whose candidate range exceeded max_fanout_scan so the "
-        "count pass fell back to the hash-match superset",
+        "live probe rows whose run of equal hashes is wider than "
+        "max_fanout_scan",
     "radix_partitions_spilled":
         "radix partitions whose build side exceeded join_spill_budget_bytes "
         "and were processed from host spill",

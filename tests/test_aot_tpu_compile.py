@@ -85,21 +85,14 @@ def test_grouped_sums_kernel_compiles(one_chip, groups, n_states):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-@pytest.mark.parametrize("program", [
-    "probe_unique", "probe_expand", "probe_expand_on_two_keys",
-    "bucket_directory"])
-def test_directory_probe_compiles(one_chip, program):
-    """The sort engine's join probe (ops/join.py) at sf1_q3's shapes: a
-    batch of 2^17 int64 keys against a sorted build of twelve such batches
-    — the directory gathers, the halving loop over 32-bit fingerprints
-    bounded by a device scalar, the verification of the lanes from there
-    bounded by another (one fingerprint and one key a round, the key's 64
-    bits as two 32-bit gathers); the slot-to-row scatter and running sum; and the
-    directory itself over hashes already sorted (the build's sort stays out
-    of this file, as above; so does probe_counts, the same probe as
-    probe_unique with a scan of 8 candidates that compiles for 17 s). On
-    two keys it is sf1_q9's expand: partsupp's batch against lineitem's
-    chain of 2^17 lanes."""
+_PROBE_TEXTS = {}
+
+
+def _probe_program_text(one_chip, program):
+    """The compiled text of one join-probe program (below), compiled once
+    a process."""
+    if program in _PROBE_TEXTS:
+        return _PROBE_TEXTS[program]
     from presto_tpu.batch import Batch, Column
     from presto_tpu.ops import join
     from presto_tpu.types import BIGINT
@@ -110,13 +103,7 @@ def test_directory_probe_compiles(one_chip, program):
                       for _ in names],
                      jax.ShapeDtypeStruct((n,), jnp.bool_), {})
 
-    if program == "bucket_directory":
-        compiled = jax.jit(join._bucket_directory).lower(
-            _sds((12 * N,), jnp.int64, one_chip),
-            _sds((), jnp.int64, one_chip)).compile()
-        assert compiled.memory_analysis() is not None
-        return
-    two = program == "probe_expand_on_two_keys"
+    two = program.endswith("_on_two_keys")
     pk, bk = (["pk", "pk2"], ["bk", "bk2"]) if two else (["pk"], ["bk"])
     table = jax.eval_shape(lambda b: join.build_side(b, bk),
                            batch(bk + ["payload"], N if two else 12 * N))
@@ -128,20 +115,57 @@ def test_directory_probe_compiles(one_chip, program):
             t, p, pk, bk, lo, c, o, base, N)
         args = (table, probe, lo, counts, offsets,
                 jax.ShapeDtypeStruct((), jnp.int64))
+    elif program.startswith("probe_counts"):
+        fn = lambda t, p: join.probe_counts(t, p, pk, bk)  # noqa: E731
+        args = (table, probe)
     else:
         fn = lambda t, p: join.probe_unique(  # noqa: E731
             t, p, ["pk"], ["bk"])
         args = (table, probe)
     compiled = jax.jit(fn).lower(*_placed(args, one_chip)).compile()
     assert compiled.memory_analysis() is not None
+    _PROBE_TEXTS[program] = compiled.as_text()
+    return _PROBE_TEXTS[program]
+
+
+@pytest.mark.parametrize("program", [
+    "probe_unique", "probe_expand", "probe_expand_on_two_keys",
+    "probe_counts", "probe_counts_on_two_keys", "bucket_directory"])
+def test_directory_probe_compiles(one_chip, program):
+    """The sort engine's join probe (ops/join.py) at sf1_q3's shapes: a
+    batch of 2^17 int64 keys against a sorted build of twelve such batches
+    — the directory gathers, the halving loop over 32-bit fingerprints
+    bounded by a device scalar, the verification of the lanes from there
+    bounded by another (one fingerprint and one key a round, the key's 64
+    bits as two 32-bit gathers); the slot-to-row scatter and running sum; and the
+    directory itself over hashes already sorted (the build's sort stays out
+    of this file, as above). probe_counts is the halving search over the
+    64-bit hashes and a read of the run's end: no key column is gathered.
+    On two keys it is sf1_q9's counting pass and expand: partsupp's batch
+    against lineitem's chain of 2^17 lanes."""
+    from presto_tpu.ops import join
+
+    if program == "bucket_directory":
+        compiled = jax.jit(join._bucket_directory).lower(
+            _sds((12 * N,), jnp.int64, one_chip),
+            _sds((), jnp.int64, one_chip)).compile()
+        assert compiled.memory_analysis() is not None
+        return
+    text = _probe_program_text(one_chip, program)
     # no binary search of the whole build or of the prefix sums is left:
     # the unique probe's loops are the halving inside a bucket and the
-    # verification; the expand has none
-    text = compiled.as_text()
-    assert text.count(" while(") == 2 * (program == "probe_unique")
+    # verification, the counting pass's the halving alone; the expand has
+    # none
+    loops = {"probe_unique": 2, "probe_counts": 1,
+             "probe_counts_on_two_keys": 1}.get(program, 0)
+    assert text.count(" while(") == loops
     if program == "probe_unique":
         # every gather is of the whole batch: 15 before the fingerprint
         assert text.count(" gather(") <= 6
+    if program.startswith("probe_counts"):
+        # a count is its run's width: a second key adds no gather
+        one_key = _probe_program_text(one_chip, "probe_counts")
+        assert text.count(" gather(") == one_key.count(" gather(")
 
 
 def test_q6_scan_filter_aggregate_chain_compiles(one_chip, monkeypatch):

@@ -337,6 +337,107 @@ def test_probe_unique_is_a_numpy_lookup_among_the_live_build_keys(
         assert int(tbl.verify_width) == widths[case]
 
 
+def _scanned_counts(table, probe, probe_keys, build_keys, max_fanout_scan=8):
+    """The counting pass as it was before it read run widths alone: it
+    verified up to `max_fanout_scan` candidates of each run against the key
+    columns, then gave every row its run's width all the same."""
+    _, lo, hi, live = join_ops._probe_ranges(table, probe, probe_keys)
+    width = hi - lo
+    counts = jnp.zeros(width.shape, dtype=jnp.int64)
+    cap = table.hashes.shape[0]
+    for j in range(max_fanout_scan):
+        idx = jnp.clip(lo + j, 0, cap - 1).astype(jnp.int32)
+        ok = (j < width) & join_ops._keys_equal(table, idx, probe, probe_keys,
+                                                build_keys)
+        counts = counts + ok.astype(jnp.int64)
+    counts = jnp.where(counts == width, counts, width)
+    widened = live & (width > max_fanout_scan)
+    counts = jnp.where(width > max_fanout_scan, width, counts)
+    counts = jnp.where(live, counts, 0)
+    offsets = jnp.cumsum(counts) - counts
+    total = jnp.sum(counts)
+    overflow = jnp.sum(widened.astype(jnp.int64))
+    return lo.astype(jnp.int32), counts, offsets, total, live, overflow
+
+
+def _pair_batch(a, b, live=None):
+    """A batch of exactly len(a) lanes with two BIGINT key columns."""
+    live = np.ones(len(a), bool) if live is None else np.asarray(live, bool)
+    return Batch(["id", "id2"], [BIGINT, BIGINT],
+                 [Column(jnp.asarray(np.asarray(k, np.int64)), None)
+                  for k in (a, b)], jnp.asarray(live), {})
+
+
+def _runs(widths, n):
+    """Keys 0, 1, ... repeated widths[0], widths[1], ... times, cut to n
+    lanes (live only where a key landed)."""
+    keys = np.repeat(np.arange(len(widths)), widths)[:n]
+    return _key_batch(np.r_[keys, np.zeros(n - len(keys))],
+                      np.arange(n) < len(keys))
+
+
+def _count_cases():
+    r = np.random.default_rng(38)
+    some = lambda n, p: r.random(n) < p  # noqa: E731
+    nb, npr = 1024, 256
+    one = ("id",)
+    past_8 = _runs(np.arange(100) % 20 + 1, nb)  # runs of 1 to 20
+    return {
+        # name: (build batch, probe batch, key names, max_fanout_scan)
+        "unique_keys": (_key_batch(r.permutation(nb)),
+                        _key_batch(r.integers(0, 2 * nb, npr)), one, 8),
+        "fanout_1_to_7": (_runs(np.arange(300) % 7 + 1, nb),
+                          _key_batch(r.integers(0, 320, npr)), one, 8),
+        "fanout_past_8": (past_8, _key_batch(r.integers(0, 110, npr)), one, 8),
+        "fanout_past_8_scan_4": (
+            past_8, _key_batch(r.integers(0, 110, npr)), one, 4),
+        "fanout_past_8_scan_16": (
+            past_8, _key_batch(r.integers(0, 110, npr)), one, 16),
+        # a quarter step: four values share each integer part and so a hash,
+        # and each repeats, so a run's matches are not contiguous
+        "doubles_share_a_hash_by_truncation": (
+            _double_batch(r.integers(0, 300, nb) / 4),
+            _double_batch(r.integers(-4, 320, npr) / 4), one, 8),
+        "two_keys": (_pair_batch(r.integers(0, 40, nb), r.integers(0, 4, nb)),
+                     _pair_batch(r.integers(0, 45, npr), r.integers(0, 5, npr)),
+                     ("id", "id2"), 8),
+        "null_keys_both_sides": (
+            _key_batch(r.integers(0, 100, nb), valid=some(nb, .8)),
+            _key_batch(r.integers(0, 120, npr), valid=some(npr, .7)), one, 8),
+        "dead_probe_lanes": (
+            _key_batch(r.integers(0, 100, nb)),
+            _key_batch(r.integers(0, 120, npr), some(npr, .5)), one, 8),
+        "empty_build": (_key_batch(np.arange(nb), np.zeros(nb, bool)),
+                        _key_batch(np.arange(npr)), one, 8),
+    }
+
+
+_COUNT_CASES = _count_cases()
+
+
+@pytest.mark.parametrize("case", sorted(_COUNT_CASES))
+def test_probe_counts_are_what_the_candidate_scan_gave(case):
+    """The six outputs of the counting pass, lane for lane, against the pass
+    that verified `max_fanout_scan` candidates first; `overflow` is the
+    live rows whose run of equal hashes is wider than that."""
+    build, probe, keys, scan = _COUNT_CASES[case]
+    tbl = build_side(build, keys)
+    got = probe_counts(tbl, probe, keys, keys, max_fanout_scan=scan)
+    want = _scanned_counts(tbl, probe, keys, keys, scan)
+    for g, w in zip(got, want):
+        assert g.dtype == w.dtype and g.shape == w.shape
+        np.testing.assert_array_equal(g, w)
+    _, counts, _, total, live, overflow = got
+    counts, live = np.asarray(counts), np.asarray(live)
+    assert int(total) == counts.sum()
+    assert int(overflow) == int((live & (counts > scan)).sum())
+    assert (counts[~live] == 0).all()
+    if case.startswith("fanout_past_8"):
+        assert int(overflow) > 0
+    if case == "empty_build":
+        assert int(total) == 0
+
+
 def _ends_cases():
     r = np.random.default_rng(29)
     n, cap = 256, 128  # one pair of shapes for most cases: one compile
@@ -379,14 +480,14 @@ def test_slot_rows_is_numpy_searchsorted_right_of_the_ends(case):
     assert got.dtype == jnp.int32
 
 
-def _loops(jaxpr):
-    """`while` equations of a jaxpr, at any depth."""
+def _eqns(jaxpr, primitive):
+    """`primitive`'s equations in a jaxpr, at any depth."""
     found = []
     for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "while":
+        if eqn.primitive.name == primitive:
             found.append(eqn)
         for sub in jax.core.jaxprs_in_params(eqn.params):
-            found += _loops(sub)
+            found += _eqns(sub, primitive)
     return found
 
 
@@ -412,8 +513,17 @@ def test_probe_programs_loop_only_in_the_bounded_finish(program, loops):
         jaxpr = jax.make_jaxpr(lambda t, p: fn(t, p, keys, keys))(tbl, probe)
     # a loop with a static trip count would be a `scan`: a `while` here is
     # one bounded by a device scalar of the table
-    assert len(_loops(jaxpr.jaxpr)) == loops
+    assert len(_eqns(jaxpr.jaxpr, "while")) == loops
     assert "sort" not in str(jaxpr).replace("indices_are_sorted", "")
+    if program == "probe_counts":
+        # a count is its run's width: a second key gathers nothing more
+        pairs = build_side(_pair_batch(np.arange(1000) % 300,
+                                       np.arange(1000) % 7), ("id", "id2"))
+        probe = _pair_batch(np.arange(256), np.arange(256) % 7)
+        gathers = [len(_eqns(jax.make_jaxpr(
+            lambda t, p: probe_counts(t, p, k, k))(pairs, probe).jaxpr,
+            "gather")) for k in (("id",), ("id", "id2"))]
+        assert gathers[0] == gathers[1]
 
 
 class TestSortCompact:
