@@ -66,6 +66,38 @@ def _interp(sf: float, idx: int) -> int:
     return max(1, int(base))
 
 
+# words of an item's description (dsdgen draws i_item_desc as sentences
+# of random words; here five words of this list a row)
+_DESC_WORDS = (
+    "able", "about", "above", "across", "actual", "after", "again", "almost",
+    "also", "always", "another", "area", "around", "away", "back", "basic",
+    "because", "before", "best", "better", "between", "black", "blue",
+    "both", "bright", "broad", "busy", "careful", "certain", "changes",
+    "clear", "close", "common", "complete", "current", "dark", "deep",
+    "different", "early", "easy", "economic", "entire", "equal", "even",
+    "every", "fair", "final", "fine", "first", "free", "fresh", "full",
+    "general", "gentle", "good", "great", "green", "happy", "hard", "heavy",
+    "high", "huge", "important", "just", "large", "late", "light", "likely",
+    "little", "local", "long", "major", "modern", "national", "natural",
+    "new", "normal", "old", "only", "open", "other", "particular", "past",
+    "plain", "popular", "possible", "present", "private", "public", "quick",
+    "quiet", "rare", "ready", "real", "recent", "red", "rich", "right",
+    "round", "safe", "same", "serious", "short", "similar", "simple",
+    "single", "small", "social", "soft", "special", "still", "strong",
+    "sure", "sweet", "tall", "thin", "true", "usual", "various", "warm",
+    "white", "whole", "wide", "wild", "young")
+
+
+def _item_desc(rng: np.random.Generator, n: int):
+    """i_item_desc as (Dictionary, codes): five words of `_DESC_WORDS`
+    a row, drawn from `rng`."""
+    from presto_tpu.dictionary import Dictionary
+
+    words = np.array(_DESC_WORDS, object)[
+        rng.integers(0, len(_DESC_WORDS), (n, 5))]
+    return Dictionary.encode([" ".join(w).capitalize() + "." for w in words])
+
+
 class TpcdsGenerator:
     def __init__(self, sf: float = 1.0, seed: int = 20030101):
         self.sf = sf
@@ -135,6 +167,8 @@ class TpcdsGenerator:
             "i_manufact_id": rng.integers(1, 1001, n),
             "i_size": np.array([["small", "medium", "large", "extra large", "economy", "N/A", "petite"][i % 7] for i in range(n)], object),
             "i_color": np.array([["red", "green", "blue", "white", "black", "ivory", "khaki", "salmon"][i % 8] for i in range(n)], object),
+            # drawn last, so every column above keeps its stream
+            "i_item_desc": _item_desc(rng, n),
         }
 
     def customer(self) -> Dict[str, np.ndarray]:
@@ -384,6 +418,9 @@ class TpcdsGenerator:
         }
         for col, domain in extra_fk.items():
             out[col] = rng.integers(1, domain + 1, n)
+        # drawn last, so every column above keeps its stream
+        out[f"{prefix}_bill_cdemo_sk"] = rng.integers(1, self.n_cdemo + 1, n)
+        out[f"{prefix}_bill_hdemo_sk"] = rng.integers(1, self.n_hdemo + 1, n)
         return out
 
     def catalog_sales(self) -> Dict[str, np.ndarray]:
@@ -534,6 +571,21 @@ _DS_TYPES: Dict[str, Dict[str, object]] = {
     "date_dim": {"d_date": DATE},
 }
 
+# the dimensions' surrogate keys (spec clause 2: each dimension's primary
+# key), unique by construction above
+_PRIMARY_KEYS: Dict[str, List[str]] = {
+    "date_dim": ["d_date_sk"], "time_dim": ["t_time_sk"],
+    "store": ["s_store_sk"], "item": ["i_item_sk"],
+    "customer": ["c_customer_sk"], "customer_address": ["ca_address_sk"],
+    "customer_demographics": ["cd_demo_sk"],
+    "household_demographics": ["hd_demo_sk"],
+    "income_band": ["ib_income_band_sk"], "promotion": ["p_promo_sk"],
+    "warehouse": ["w_warehouse_sk"], "ship_mode": ["sm_ship_mode_sk"],
+    "reason": ["r_reason_sk"], "call_center": ["cc_call_center_sk"],
+    "catalog_page": ["cp_catalog_page_sk"], "web_site": ["web_site_sk"],
+    "web_page": ["wp_web_page_sk"],
+}
+
 
 class TpcdsConnector(MemoryConnector):
     """Lazy TPC-DS connector: tables generate on first access and are cached
@@ -582,7 +634,8 @@ class TpcdsConnector(MemoryConnector):
                 else v)
             for c, v in data.items()
         }
-        self.add_generated(name, converted, types=_DS_TYPES.get(name))
+        self.add_generated(name, converted, types=_DS_TYPES.get(name),
+                           primary_key=_PRIMARY_KEYS.get(name))
 
     def get_table(self, name: str):
         self._ensure(name)

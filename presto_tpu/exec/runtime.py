@@ -726,6 +726,15 @@ def _emit_phase(tracer, lanes: int):
     return tracer.phase("join_emit", items=lanes)
 
 
+def _outer_phase(tracer, lanes: int) -> None:
+    """One `join_outer` occurrence, no time of its own: a batch a LEFT or
+    FULL join hands on gathered whole, at `lanes` lanes, because every
+    probe row stays (a single-match probe's emit, the general path's
+    NULL-extended rows)."""
+    with tracer.phase("join_outer", items=lanes):
+        pass
+
+
 def _expand_phases(tracer, rows: int, lanes: int, overflow: int) -> None:
     """What one probe batch on the join's general path expanded to, from
     numbers the host has already read: `join_expand` (`items` = the batch's
@@ -4610,14 +4619,16 @@ def _join_plan_cdt(node) -> tuple:
 def _observe_build_table(ctx: "ExecContext", table) -> float:
     """A built join table's live row count, read once from the device; the
     sorted engine's bucket-search steps and unique-probe verify width ride
-    in the same transfer and are reported as `items` of one `join_search`
-    and one `join_verify` occurrence a build."""
+    in the same transfer. A sorted build is one occurrence each of
+    `join_build_table` (`items` = its live rows), `join_search` and
+    `join_verify` (`items` = its steps, its width)."""
     with ctx.tracer.phase("host_sync:join_build_rows"):
         rows, steps, width = table_stats(table)
     if steps is not None:
         from presto_tpu.scan import metrics as _scan_metrics
 
         for phase, counter, items in (
+                ("join_build_table", "join_build_rows", int(rows)),
                 ("join_search", "join_search_steps", steps),
                 ("join_verify", "join_verify_width", width)):
             with ctx.tracer.phase(phase, items=items):
@@ -4656,6 +4667,8 @@ class _PendingJoinOutput:
         with _emit_phase(prober.ctx.tracer, out_cap or cap):
             out = prober.jemit(prober.table, self.pb, self.idx,
                                self.matched, out_cap=out_cap)
+        if prober.node.kind != "inner":
+            _outer_phase(prober.ctx.tracer, cap)
         return out, sparse
 
 
@@ -5070,6 +5083,7 @@ class _JoinProber:
                 ph.items = 1
         _expand_phases(self.ctx.tracer, tot, base, ovn)
         if nb is not None:
+            _outer_phase(self.ctx.tracer, pb.capacity)
             yield nb
 
     def probe_batch(self, pb_raw: Batch) -> Iterator[Batch]:

@@ -1547,10 +1547,7 @@ class Planner:
             scope = Scope([])
             rows = 1.0
         else:
-            if isinstance(left.node, _PendingCross):
-                raise AnalysisError(
-                    "UNNEST after a comma-join chain is not supported; use "
-                    "explicit CROSS JOIN ordering")
+            left = self._resolved(left)
             child, scope, rows = left.node, left.scope, left.rows
         analyzer = ExprAnalyzer(scope, self)
         exprs = [analyzer.analyze(a) for a in rel.exprs]
@@ -1607,51 +1604,66 @@ class Planner:
                             rows=rows * 4)
 
     def plan_join(self, rel: ast.Join) -> RelationPlan:
+        """A join as written. Inner joins (comma, CROSS JOIN, INNER JOIN ...
+        ON) are not planned here: they stay pending, their ON conjuncts
+        beside them, and `_assemble_joins` orders their leaves with the
+        WHERE conjuncts as one join graph (reference: ReorderJoins over a
+        MultiJoinNode). A LEFT (or RIGHT, turned round) join is a leaf of
+        that graph, planned once WHERE is known (`_plan_outer`); a FULL
+        join is planned here."""
         if isinstance(rel.right, ast.UnnestRelation):
             if rel.kind not in ("cross", "inner") or rel.condition is not None:
                 raise AnalysisError(
                     "UNNEST is only supported with CROSS JOIN")
             return self.plan_unnest(rel.right, self.plan_relation(rel.left))
-        # flatten pure cross-join chains into leaves for WHERE-driven ordering
         left = self.plan_relation(rel.left)
         right = self.plan_relation(rel.right)
         scope = left.scope + right.scope
-        if rel.kind == "cross":
-            # deferred: caller (plan_from_where) orders cross joins by
-            # conjunct connectivity. Represent as a pending cross product.
-            return RelationPlan(_PendingCross(left, right), scope,
-                               rows=left.rows * right.rows)
         cond = ExprAnalyzer(scope, self).analyze(rel.condition) if rel.condition else None
         conjs = _split_ir_conjuncts(cond) if cond is not None else []
+        if rel.kind in ("cross", "inner"):
+            rows = left.rows * right.rows if rel.kind == "cross" else \
+                max(left.rows, right.rows)
+            return RelationPlan(_PendingCross(left, right, conjs), scope,
+                                rows=rows)
+        if rel.kind == "right":
+            left, right = right, left
+        lsyms = {f.symbol for f in left.scope.fields}
+        rsyms = {f.symbol for f in right.scope.fields}
+        if not _extract_equi_keys(conjs, lsyms, rsyms)[0]:
+            raise AnalysisError(
+                "outer joins require at least one equi-join condition")
+        if rel.kind == "full":
+            return self._plan_outer("full", left, right, conjs, [])
+        return RelationPlan(_PendingOuter(left, right, conjs), scope,
+                            rows=max(left.rows, right.rows))
+
+    def _plan_outer(self, kind: str, left: RelationPlan, right: RelationPlan,
+                    conjs: List[RowExpression],
+                    where: List[RowExpression]) -> RelationPlan:
+        """A LEFT or FULL join over its two sides. `where` is what the
+        preserved side of a LEFT join is assembled with: WHERE conjuncts
+        over its columns alone. The other side is assembled with its own ON
+        conjuncts only, and builds."""
+        if where:
+            node, _, rest = self._order_joins(left, where)
+            if rest:
+                node = Filter(node, combine_conjuncts(rest))
+            left = RelationPlan(node, left.scope, left.rows)
+        else:
+            left = self._resolved(left)
+        right = self._resolved(right)
         lsyms = {f.symbol for f in left.scope.fields}
         rsyms = {f.symbol for f in right.scope.fields}
         lkeys, rkeys, residual = _extract_equi_keys(conjs, lsyms, rsyms)
-        if rel.kind == "right":
-            left, right = right, left
-            lkeys, rkeys = rkeys, lkeys
-            kind = "left"
-        else:
-            kind = rel.kind
-        if not lkeys and kind != "cross":
-            if kind != "inner":
-                raise AnalysisError(
-                    "outer joins require at least one equi-join condition")
-            # non-equi INNER join → nested loop with the condition fused
-            # (NestedLoopJoinOperator; build = right as written)
-            node = NestedLoopJoin(left.node, right.node,
-                                  residual=combine_conjuncts(residual) or cond)
-            return RelationPlan(node, scope, rows=left.rows * right.rows)
         if kind == "left":
             # push build-side-only residuals into the build side (correct for
             # LEFT: non-matching build rows are dropped pre-join)
-            keep = []
             for c in residual:
-                syms = expr_inputs(c)
-                if syms <= rsyms:
-                    right = RelationPlan(Filter(right.node, c), right.scope, right.rows)
-                else:
+                if not expr_inputs(c) <= rsyms:
                     raise AnalysisError("left join residual on probe side unsupported")
-            residual = keep
+                right = RelationPlan(Filter(right.node, c), right.scope, right.rows)
+            residual = []
         if kind == "full" and residual:
             # an ON residual must not drop unmatched rows on either side;
             # no correct place to evaluate it outside the join yet
@@ -1659,10 +1671,18 @@ class Planner:
         node = HashJoin(kind=kind, left=left.node, right=right.node,
                         left_keys=lkeys, right_keys=rkeys,
                         build_unique=_derives_unique(right.node, rkeys))
-        out: PlanNode = node
-        if residual:
-            out = Filter(out, combine_conjuncts(residual))
-        return RelationPlan(out, scope, rows=max(left.rows, right.rows))
+        return RelationPlan(node, left.scope + right.scope,
+                            rows=max(left.rows, right.rows))
+
+    def _resolved(self, rp: RelationPlan) -> RelationPlan:
+        """`rp` with its pending joins planned, from its ON conjuncts
+        alone."""
+        if not isinstance(rp.node, _PendingCross):
+            return rp
+        node, _, rest = self._order_joins(rp, [])
+        if rest:
+            node = Filter(node, combine_conjuncts(rest))
+        return RelationPlan(node, rp.scope, rp.rows)
 
     # -- set operations ---------------------------------------------------
 
@@ -1973,17 +1993,33 @@ class Planner:
         return QueryPlan(root, dict(self.scalar_subqueries),
                          cacheable=not self.symbols.volatile_plan)
 
-    # -- join assembly from comma-FROM + WHERE ----------------------------
+    # -- join assembly from FROM + WHERE ----------------------------------
 
     def _assemble_joins(self, rp: RelationPlan, conjs_ast) -> Tuple[PlanNode, Scope, List[RowExpression]]:
-        scope = rp.scope
-        analyzer = ExprAnalyzer(scope, self)
-        conjs = [analyzer.analyze(c) for c in conjs_ast]
+        analyzer = ExprAnalyzer(rp.scope, self)
+        return self._order_joins(rp, [analyzer.analyze(c) for c in conjs_ast])
 
+    def _order_joins(self, rp: RelationPlan, conjs: List[RowExpression]) -> Tuple[PlanNode, Scope, List[RowExpression]]:
+        """The leaves of `rp`'s inner joins, ordered by their ON conjuncts
+        and `conjs` (the ON conjuncts first, in the text's order). A LEFT
+        join among the leaves is planned first, its preserved side taking
+        the conjuncts over its columns alone. Returns (node, scope, the
+        conjuncts no join consumed)."""
+        scope = rp.scope
         leaves: List[RelationPlan] = []
-        _collect_cross_leaves(rp, leaves)
+        on: List[RowExpression] = []
+        _collect_cross_leaves(rp, leaves, on)
+        conjs = on + list(conjs)
+        for i, leaf in enumerate(leaves):
+            if isinstance(leaf.node, _PendingOuter):
+                outer = leaf.node
+                psyms = {f.symbol for f in outer.left.scope.fields}
+                mine = [c for c in conjs if expr_inputs(c) <= psyms]
+                conjs = [c for c in conjs if not expr_inputs(c) <= psyms]
+                leaves[i] = self._plan_outer("left", outer.left, outer.right,
+                                             outer.conjs, mine)
         if len(leaves) == 1:
-            return rp.node, scope, conjs
+            return leaves[0].node, scope, conjs
 
         # Stats-driven greedy join ordering (CBO v1 — the role of
         # ReorderJoins.java:94 with JoinStatsRule estimates): each leaf's
@@ -2252,7 +2288,7 @@ class Planner:
             raise AnalysisError("EXISTS subquery with group/order/limit unsupported")
         for name, cq in sq.ctes:
             sub.ctes[name] = cq
-        rel = sub.plan_relation(sq.from_)
+        rel = sub._resolved(sub.plan_relation(sq.from_))
         inner_scope = rel.scope
         inner_syms = {f.symbol for f in inner_scope.fields}
         combined = scope + inner_scope
@@ -2882,22 +2918,36 @@ def _host_split(e: RowExpression):
 
 
 class _PendingCross(PlanNode):
-    """Marker node: cross product whose ordering is decided by WHERE
+    """Marker node: an inner join (comma, CROSS JOIN, INNER JOIN ... ON)
+    whose ordering is decided by its ON conjuncts `conjs` and the WHERE
     conjuncts in _assemble_joins. Never reaches execution."""
 
-    def __init__(self, left: RelationPlan, right: RelationPlan):
+    def __init__(self, left: RelationPlan, right: RelationPlan,
+                 conjs: List[RowExpression] = ()):
         self.left = left
         self.right = right
+        self.conjs = list(conjs)
         self.output = list(left.node.output) + list(right.node.output)
 
     def children(self):
         return [self.left.node, self.right.node]
 
 
-def _collect_cross_leaves(rp: RelationPlan, out: List[RelationPlan]):
-    if isinstance(rp.node, _PendingCross):
-        _collect_cross_leaves(rp.node.left, out)
-        _collect_cross_leaves(rp.node.right, out)
+class _PendingOuter(_PendingCross):
+    """Marker node: `left` LEFT JOIN `right` ON `conjs`, planned once the
+    WHERE conjuncts are known (Planner._plan_outer). Never reaches
+    execution."""
+
+
+def _collect_cross_leaves(rp: RelationPlan, out: List[RelationPlan],
+                          on: List[RowExpression]):
+    """The leaves of `rp`'s pending inner joins into `out`, their ON
+    conjuncts into `on`, both in the text's order."""
+    if isinstance(rp.node, _PendingCross) and \
+            not isinstance(rp.node, _PendingOuter):
+        _collect_cross_leaves(rp.node.left, out, on)
+        _collect_cross_leaves(rp.node.right, out, on)
+        on.extend(rp.node.conjs)
     else:
         out.append(rp)
 

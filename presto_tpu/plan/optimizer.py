@@ -344,30 +344,51 @@ def make_index_joins(node: PlanNode, catalog) -> PlanNode:
         if hasattr(node, attr):
             setattr(node, attr, make_index_joins(getattr(node, attr), catalog))
     if (isinstance(node, HashJoin) and node.kind in ("inner", "left")
-            and node.residual is None and not node.colocated
-            and isinstance(node.right, TableScan)):
-        scan = node.right
-        try:
-            conn = catalog.connectors[scan.catalog]
-            handle = conn.get_table(scan.table)
-        except Exception:
-            return node
-        key_cols = [scan.assignments.get(k) for k in node.right_keys]
-        if None in key_cols:
-            return node
-        if conn.get_index(handle, key_cols) is None:
-            return node
+            and node.residual is None and not node.colocated):
         from presto_tpu.plan.builder import _derives_unique
 
+        left, lkeys, rkeys = node.left, node.left_keys, node.right_keys
+        right, key_cols = _indexed_scan(node.right, rkeys, catalog)
+        if key_cols is None and node.kind == "inner":
+            # the join order may put the indexed table on the probe side:
+            # an inner join turns round, the index looked up by the other
+            left, lkeys, rkeys = node.right, rkeys, lkeys
+            right, key_cols = _indexed_scan(node.left, rkeys, catalog)
+        if key_cols is None:
+            return node
         return IndexJoin(
-            kind=node.kind, left=node.left,
-            catalog=scan.catalog, table=scan.table,
-            left_keys=list(node.left_keys), index_key_cols=key_cols,
-            assignments=dict(scan.assignments),
-            index_output=list(scan.output),
-            build_unique=_derives_unique(scan, node.right_keys),
+            kind=node.kind, left=left,
+            catalog=right.catalog, table=right.table,
+            left_keys=list(lkeys), index_key_cols=key_cols,
+            assignments=dict(right.assignments),
+            index_output=list(right.output),
+            build_unique=_derives_unique(right, rkeys),
         )
     return node
+
+
+def _indexed_scan(node: PlanNode, keys: List[str], catalog):
+    """(scan, the columns of `keys`) where `node` is a scan of a table whose
+    connector exposes an index over exactly those columns - bare, or under
+    the join order's IS NOT NULL filter of the keys, which a lookup by key
+    applies anyway - else (node, None)."""
+    scan = node
+    if isinstance(node, Filter) and all(
+            isinstance(c, Call) and c.fn == "is_not_null"
+            and expr_inputs(c) <= set(keys)
+            for c in _conjuncts(node.predicate)):
+        scan = node.child
+    if not isinstance(scan, TableScan):
+        return node, None
+    try:
+        conn = catalog.connectors[scan.catalog]
+        handle = conn.get_table(scan.table)
+    except Exception:
+        return node, None
+    key_cols = [scan.assignments.get(k) for k in keys]
+    if None in key_cols or conn.get_index(handle, key_cols) is None:
+        return node, None
+    return scan, key_cols
 
 
 def _debug_checks_enabled() -> bool:

@@ -17,7 +17,7 @@ COUNTER_NAMES = (
     "join_fanout_overflow_rows", "radix_partitions_spilled",
     "radix_spill_bytes", "radix_aligned_batches",
     "join_search_steps", "join_verify_width", "join_emit_lanes",
-    "join_expand_rows",
+    "join_expand_rows", "join_build_rows",
 )
 
 # dispatch-count counters for whole-fragment fusion (exec/fragment_jit.py):
@@ -81,6 +81,10 @@ _HELP = {
         "rows the probe batches on a join's general path (a build that "
         "fans out) expanded to: each batch's total, read once by the host "
         "(exec/runtime.py: _expand_phases)",
+    "join_build_rows":
+        "live rows of the sorted join builds observed, read once by the "
+        "host with the build's other statistics (exec/runtime.py: "
+        "_observe_build_table)",
     "fragment_dispatches":
         "fused whole-fragment device dispatches (one lax.scan program "
         "covering a stacked window of batches)",

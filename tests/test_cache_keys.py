@@ -133,6 +133,13 @@ def _star_catalog(dup_d2=False):
     return cat
 
 
+# every order's customer and every customer's nation exist: the LEFT JOIN
+# answers as an inner join would
+_SNOWFLAKE_SQL = (
+    "select o.o_orderkey, c.c_name, n.n_name from orders o "
+    "join customer c on o.o_custkey = c.c_custkey "
+    "left join nation n on c.c_nationkey = n.n_nationkey")
+
 _STAR_SQL = ("select f.v, d1.a1, d2.a2 from f "
              "join d1 on f.k1 = d1.p1 join d2 on f.k2 = d2.p2")
 
@@ -144,12 +151,12 @@ def _mw_keys():
 
 def test_multiway_unique_keys_carry_engine_vector(cat):
     # primary-key builds (customer, nation) are provably unique, which
-    # selects the mw_unique fused-probe program
+    # selects the mw_unique fused-probe program; the LEFT JOIN keeps the
+    # chain left-deep (inner joins alone are reordered: customer would
+    # probe nation, and orders that join)
     cfg = ExecConfig(join_mode="multiway", batch_rows=1 << 12)
     r = LocalRunner(cat, cfg)
-    r.run("select o.o_orderkey, c.c_name, n.n_name from orders o "
-          "join customer c on o.o_custkey = c.c_custkey "
-          "join nation n on c.c_nationkey = n.n_nationkey")
+    r.run(_SNOWFLAKE_SQL)
     assert r.last_stats.get("multiway.joins", 0) >= 1
     keys = _mw_keys()
     probe = [k for k in keys if k.startswith("mw_unique@e")]
@@ -186,10 +193,6 @@ def test_mwspec_in_pytree_registration_table():
             MwSpec, serialized_name="dup.MwSpec")
 
 
-_SNOWFLAKE_SQL = (
-    "select o.o_orderkey, c.c_name, n.n_name from orders o "
-    "join customer c on o.o_custkey = c.c_custkey "
-    "join nation n on c.c_nationkey = n.n_nationkey")
 
 
 def test_multiway_programs_restore_from_artifacts(cat, tmp_path,

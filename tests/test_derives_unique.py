@@ -208,8 +208,10 @@ def test_explain_marks_both_of_q3s_joins_and_none_of_a_fan_out(runner):
                  .splitlines() if "HashJoin[" in line]
     assert len(joins) == 2 and all("; unique]" in line for line in joins), joins
     assert "['l_orderkey'] = ['o_orderkey']" in joins[0]
-    # orders probing a lineitem build (as written) on the order's key
+    # orders probing a build of two months' lineitems (the smaller side
+    # once filtered) on the order's key
     fan_out = runner.explain("select o_orderkey, l_quantity from orders "
-                             "join lineitem on o_orderkey = l_orderkey")
+                             "join lineitem on o_orderkey = l_orderkey "
+                             "where l_shipdate < date '1992-03-01'")
     (line,) = [line for line in fan_out.splitlines() if "HashJoin[" in line]
     assert "['o_orderkey'] = ['l_orderkey']" in line and "unique" not in line

@@ -12,7 +12,9 @@ occurrence that a generator leaves before a `yield` and enters again after
 counts once. A batch on the general path also leaves `join_expand` (`items` =
 its rows), `join_expand_lanes` (chunks x `out_cap`) and, where a key's matches
 pass the counting scan of 8, `join_fanout_overflow`; the single-match path
-leaves none. TPC-H Q9 through the same path, its two-key join general in the
+leaves none. A sorted build leaves `join_build_table` (`items` = its live
+rows), and a LEFT join one `join_outer` a batch it hands on whole (`items` =
+the lanes). TPC-H Q9 through the same path, its two-key join general in the
 plan, answers as `benchmark/reference/q9.py` does."""
 
 import json
@@ -29,17 +31,18 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BATCH = 8192
 EXPAND_PHASES = ("join_expand", "join_expand_lanes", "join_fanout_overflow")
 JOIN_PHASES = ("join_build", "join_probe", "join_search", "join_verify",
-               "join_emit",
+               "join_emit", "join_build_table", "join_outer",
                *EXPAND_PHASES, "host_sync:join_build_rows",
                "host_sync:join_total", "host_sync:join_overflow",
                "host_sync:join_output_rows", "host_sync:join_selectivity")
 
 
-# a join that fans out: the build (`lineitem`, as written) holds an order's
-# key several times, so its probe takes the general path; two months' orders
+# a join that fans out: the build (`lineitem` of two months' shipments, the
+# smaller side once filtered) holds an order's key several times, so every
+# batch of orders probes it on the general path
 FAN_OUT = ("select count(*) as n, sum(l_quantity) as q from orders "
            "join lineitem on o_orderkey = l_orderkey "
-           "where o_orderdate < date '1992-03-01'")
+           "where l_shipdate < date '1992-03-01'")
 
 
 def query_text(qid):
@@ -172,8 +175,7 @@ def test_tracing_off_gives_the_same_answer_and_records_nothing(url):
 def test_a_join_that_fans_out_counts_no_unique_batch(url):
     (orders,), = statement(url, "select count(*) from orders")[0]
     (n, _), = statement(url, "select count(*), sum(l_quantity) from lineitem "
-                             "where l_orderkey in (select o_orderkey from orders "
-                             "where o_orderdate < date '1992-03-01')")[0]
+                             "where l_shipdate < date '1992-03-01'")[0]
     rows, first = statement(url, FAN_OUT)
     rows_again, second = statement(url, FAN_OUT)
     assert rows == rows_again and rows[0][0] == n > 0
@@ -206,8 +208,9 @@ def test_a_small_join_out_capacity_adds_chunks_not_occurrences(url):
 
 
 # a key with more matches than the counting pass scans (8): the build
-# (`customer`, as written) holds some sixty customers a nation
-OVERFLOWS = ("select count(*) as n from nation join customer "
+# (`customer`, the side a LEFT JOIN supplies, as written) holds some sixty
+# customers a nation; every nation has customers, so no row is NULL-extended
+OVERFLOWS = ("select count(*) as n from nation left join customer "
              "on n_nationkey = c_nationkey")
 
 
@@ -253,6 +256,86 @@ def test_a_key_with_more_matches_than_the_scan_records_an_overflow(url):
     # with tracing off the answer is the same and nothing is recorded
     rows_off, none = statement(url, OVERFLOWS, breaker_engine="sort", tracing="false")
     assert rows_off == rows and none is None
+
+
+def test_a_sorted_builds_live_rows_ride_as_join_build_table(url):
+    """`join_build_table`: one occurrence a sorted build beside
+    `join_build_rows`, no time of its own, its `items` the build's live rows
+    - Q3's BUILDING customers, then their orders before the date - and the
+    process counter alike; with tracing off the answer is the same and no
+    phase is recorded."""
+    from presto_tpu.scan import metrics
+
+    (customers,), = statement(url, "select count(*) from customer "
+                                   "where c_mktsegment = 'BUILDING'")[0]
+    (orders,), = statement(url, "select count(*) from orders join customer "
+                                "on o_custkey = c_custkey where c_mktsegment = "
+                                "'BUILDING' and o_orderdate < date '1995-03-15'")[0]
+    before = metrics.snapshot()["join_build_rows"]
+    rows, summary = statement(url, query_text("q3"))
+    task = summary["phases"]["task"]
+    table = task["join_build_table"]
+    assert table["n"] == task["host_sync:join_build_rows"]["n"] == 2
+    assert table["items"] == customers + orders > 0
+    assert metrics.snapshot()["join_build_rows"] - before == table["items"]
+    assert table["busy_s"] < 0.01 * task["join_build"]["busy_s"]
+    rows_off, none = statement(url, query_text("q3"), tracing="false")
+    assert rows_off == rows and none is None
+
+
+# LEFT JOINs, each batch of orders handed on whole: a unique build of
+# BUILDING customers (the ON residual filters the build), and a build of two
+# months' lineitems that fans out (the general path)
+OUTER = {
+    "single_match": ("select count(*) as n, count(c_custkey) as m from orders "
+                     "left join customer on o_custkey = c_custkey "
+                     "and c_mktsegment = 'BUILDING'",
+                     "select count(*) from orders join customer on o_custkey "
+                     "= c_custkey where c_mktsegment = 'BUILDING'"),
+    "general": ("select count(*) as n, count(l_orderkey) as m from orders "
+                "left join lineitem on o_orderkey = l_orderkey "
+                "and l_shipdate < date '1992-03-01'",
+                "select count(*) from lineitem "
+                "where l_shipdate < date '1992-03-01'"),
+}
+
+
+@pytest.mark.parametrize("path", sorted(OUTER))
+def test_a_left_join_records_each_batch_it_hands_on_whole(url, path):
+    """`join_outer`: one occurrence a probe batch, no time of its own, its
+    `items` the lanes the batch was gathered at - a single-match probe's
+    dense emit (`join_emit` of the same lanes), the general path's
+    NULL-extended rows (a batch's capacity); none with tracing off."""
+    sql, matched = OUTER[path]
+    (orders,), = statement(url, "select count(*) from orders")[0]
+    (pairs,), = statement(url, matched)[0]
+    rows, summary = statement(url, sql)
+    task = summary["phases"]["task"]
+    outer = task["join_outer"]
+    batches = math.ceil(orders / BATCH)
+    assert outer["n"] == task["join_probe"]["n"] == batches
+    assert outer["busy_s"] < 0.01 * task["join_probe"]["busy_s"]
+    assert orders <= outer["items"] <= batches * BATCH
+    if path == "single_match":
+        assert "host_sync:join_total" not in task
+        assert outer["items"] == task["join_emit"]["items"]
+        assert rows == [[orders, pairs]]
+    else:
+        assert task["host_sync:join_total"]["n"] == batches
+        # every order without such a line once, every line with its order
+        (with_lines,), = statement(url, "select count(distinct l_orderkey) "
+                                        "from lineitem where l_shipdate < "
+                                        "date '1992-03-01'")[0]
+        assert rows == [[orders - with_lines + pairs, pairs]]
+    rows_off, none = statement(url, sql, tracing="false")
+    assert rows_off == rows and none is None
+
+
+def test_an_inner_join_records_no_outer_batch(url):
+    _, summary = statement(url, FAN_OUT)
+    _, q3 = statement(url, query_text("q3"))
+    for doc in (summary, q3):
+        assert "join_outer" not in all_phases(doc)
 
 
 @pytest.mark.parametrize("seed", [9, 2147483909])

@@ -237,12 +237,13 @@ def test_build_memory_pressure_falls_back_to_cascade_and_spill():
     """The orders build blows a 256 KiB pool mid-collect: the node must
     hand the already-collected batches to the binary cascade, whose
     PR 15 spiller finishes the job — same answer as the unconstrained
-    binary path."""
+    binary path. LEFT JOINs keep orders a build: inner joins alone are
+    reordered, and orders, the largest, would probe."""
     cat = tpch_catalog(0.01)
     sql = ("select n.n_name, count(*) c, sum(o.o_totalprice) s "
            "from customer c "
-           "join orders o on c.c_custkey = o.o_custkey "
-           "join nation n on c.c_nationkey = n.n_nationkey "
+           "left join orders o on c.c_custkey = o.o_custkey "
+           "left join nation n on c.c_nationkey = n.n_nationkey "
            "group by n.n_name")
     base = dict(batch_rows=1 << 13)
     off = LocalRunner(cat, ExecConfig(join_mode="off", **base))

@@ -150,3 +150,72 @@ def test_inventory_grain():
     n = r.run("select count(*) as n from inventory")
     # grain = (date, item, warehouse): row count divides evenly
     assert n.n[0] % 261 == 0
+
+
+# -- the columns TPC-DS Q72 reads beyond the rest ---------------------------
+
+_ADDED = {"catalog_sales": ("cs_bill_cdemo_sk", "cs_bill_hdemo_sk"),
+          "web_sales": ("ws_bill_cdemo_sk", "ws_bill_hdemo_sk"),
+          "item": ("i_item_desc",)}
+# sha256 (24 hex digits) of every column these tables had before the three
+# were added, names and values in order: what the generator made then
+_BEFORE = {
+    0.01: {"catalog_sales": "3189c3819535691b0511e253",
+           "web_sales": "ba312a5320eb5de92025aa63",
+           "item": "2f193727f71df11cbe90aad4"},
+    1: {"catalog_sales": "c2a017a0a18f2c4a4065e4db",
+        "web_sales": "e1db8d5153cb5789383b4394",
+        "item": "2f193727f71df11cbe90aad4"},
+}
+
+
+def _digest_of_the_rest(table, cols):
+    import hashlib
+
+    h = hashlib.sha256()
+    for c, v in cols.items():
+        if c in _ADDED[table]:
+            continue
+        a = np.asarray(v[1] if isinstance(v, tuple) else v)
+        h.update(c.encode())
+        if a.dtype == object:
+            h.update("\x00".join(map(str, a)).encode())
+        else:
+            h.update(np.ascontiguousarray(a.astype(np.int64)).tobytes())
+    return h.hexdigest()[:24]
+
+
+@pytest.mark.parametrize("sf", sorted(_BEFORE))
+def test_every_earlier_column_keeps_its_values(sf):
+    """The three are drawn after every column of their table, from the
+    table's own stream: what was there is the same bit for bit."""
+    from presto_tpu.catalog.tpcds import TpcdsGenerator
+
+    gen = TpcdsGenerator(sf)
+    for table, digest in _BEFORE[sf].items():
+        cols = getattr(gen, table)()
+        assert list(cols)[-len(_ADDED[table]):] == list(_ADDED[table])
+        assert _digest_of_the_rest(table, cols) == digest, table
+
+
+def test_the_added_columns_hit_their_dimensions():
+    from presto_tpu.catalog.tpcds import TpcdsGenerator, tpcds_catalog
+    from presto_tpu.exec import ExecConfig, LocalRunner
+
+    gen = TpcdsGenerator(0.01)
+    for prefix, sales in (("cs", gen.catalog_sales()), ("ws", gen.web_sales())):
+        cdemo, hdemo = sales[f"{prefix}_bill_cdemo_sk"], sales[f"{prefix}_bill_hdemo_sk"]
+        assert 1 <= cdemo.min() and cdemo.max() <= gen.n_cdemo
+        assert 1 <= hdemo.min() and hdemo.max() <= gen.n_hdemo
+        assert len(np.unique(hdemo)) > gen.n_hdemo // 2  # uniform over the keys
+    d, codes = gen.item()["i_item_desc"]
+    assert len(codes) == gen.n_item and len(d) > gen.n_item // 2
+    assert all(len(s.split()) == 5 for s in d.decode(codes[:50]))
+    r = LocalRunner(tpcds_catalog(0.01), ExecConfig(batch_rows=1 << 14))
+    n = r.run("select count(*) as n from catalog_sales").n[0]
+    joined = r.run(
+        "select count(*) as n from catalog_sales "
+        "join customer_demographics on cs_bill_cdemo_sk = cd_demo_sk "
+        "join household_demographics on cs_bill_hdemo_sk = hd_demo_sk "
+        "join item on cs_item_sk = i_item_sk")
+    assert joined.n[0] == n
