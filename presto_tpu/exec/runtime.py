@@ -735,18 +735,25 @@ def _outer_phase(tracer, lanes: int) -> None:
         pass
 
 
-def _expand_phases(tracer, rows: int, lanes: int, overflow: int) -> None:
+def _expand_phases(tracer, rows: int, lanes: int, overflow: int,
+                   sized: int = 0) -> None:
     """What one probe batch on the join's general path expanded to, from
     numbers the host has already read: `join_expand` (`items` = the batch's
-    `total`, its output rows), `join_expand_lanes` (`items` = chunks handed
-    on x `out_cap`: what was gathered, rows or not) and, where a probe row's
-    candidates passed the counting scan, `join_fanout_overflow` (`items` =
-    those rows). One occurrence each, no time of their own; the process
-    counter `join_expand_rows` carries the rows."""
+    `total`, its output rows), `join_expand_lanes` (`items` = the lanes its
+    chunks gathered, rows or not), where chunk 0 was sized below `out_cap`
+    by the previous batch's total, `join_expand_sized` (`items` = that
+    chunk's lanes) and, where a probe row's candidates passed the counting
+    scan, `join_fanout_overflow` (`items` = those rows). One occurrence
+    each, no time of their own; the process counter `join_expand_rows`
+    carries the rows and, with tracing on, `join_expand_sized` the sized
+    chunk's lanes."""
     from presto_tpu.scan import metrics as _scan_metrics
 
     _scan_metrics.record("join_expand_rows", rows)
     named = [("join_expand", rows), ("join_expand_lanes", lanes)]
+    if sized and tracer.enabled:
+        _scan_metrics.record("join_expand_sized", sized)
+        named.append(("join_expand_sized", sized))
     if overflow:
         named.append(("join_fanout_overflow", overflow))
     for name, items in named:
@@ -4894,6 +4901,25 @@ class _JoinProber:
         self.jexpand = _node_jit(node, _ek("expand"), lambda: expand_fn,
                                  static_argnames=("out_cap",))
         self.jnull = _node_jit(node, "null_extend", lambda: null_extend_fn)
+        # the `total` the host read for this prober's previous general
+        # batch: it sizes the next batch's chunk 0 (`_chunk0_lanes`)
+        self._prev_total: Optional[int] = None
+
+    def _chunk0_lanes(self, out_cap: int) -> int:
+        """Lanes of a general batch's first expand chunk, dispatched before
+        its `total` reaches the host: `out_cap` for the prober's first
+        batch, else the power-of-two bucket of twice the previous batch's
+        total, at most `out_cap`. The full batches of one probe stream
+        expand to totals a few percent apart (TPC-H Q9 and TPC-DS Q72 at
+        SF1), so the doubling covers the next batch, and it keeps the
+        chunk at most half full, as `out_cap` kept it: `_merging_output`
+        compacts and merges it, where a dense chunk would travel
+        downstream alone. A batch that outgrows its chunk 0 takes further
+        chunks once its total is read; no row is lost."""
+        prev = self._prev_total
+        if prev is None:
+            return out_cap
+        return min(out_cap, round_up_capacity(2 * prev))
 
     def _hbo_observe_build(self) -> None:
         """Observe the build side's actual live row count (one host sync of
@@ -4960,10 +4986,10 @@ class _JoinProber:
     def probe_start(self, pb_raw: Batch):
         """Dispatch phase of one probe batch: everything up to (not
         including) the host sync on `total`. Chunk 0 is dispatched
-        unconditionally while `total` travels to the host (it is usually
-        the only chunk). The radix driver starts ALL partitions of a batch
-        before finishing any, so the P count round trips overlap instead
-        of serializing. The state carries the batch's `join_probe` phase:
+        unconditionally, at `_chunk0_lanes`, while `total` travels to the
+        host (it is usually the only chunk). The radix driver starts ALL
+        partitions of a batch before finishing any, so the P count round
+        trips overlap instead of serializing. The state carries the batch's `join_probe` phase:
         `probe_finish` enters it again."""
         if self.empty:
             return None
@@ -4987,10 +5013,11 @@ class _JoinProber:
             except Exception:
                 pass
             out_cap = self.ctx.config.join_out_capacity or pb.capacity
+            chunk0 = self._chunk0_lanes(out_cap)
             out, exists_acc, self.bm = self.jexpand(
-                table, pb, pba, lo, counts, offsets, 0, out_cap, self.bm)
+                table, pb, pba, lo, counts, offsets, 0, chunk0, self.bm)
             return ("g", pb, pba, lo, counts, offsets, total, ovf, out_cap,
-                    out, exists_acc, ph)
+                    chunk0, out, exists_acc, ph)
 
     def probe_finish(self, st) -> Iterator[Batch]:
         """The chunks of one started probe batch. The batch's `join_probe`
@@ -5003,8 +5030,8 @@ class _JoinProber:
             yield st[1]
             return
         node, table, phase = self.node, self.table, self.ctx.tracer.phase
-        (_, pb, pba, lo, counts, offsets, total, ovf, out_cap, out,
-         exists_acc, ph) = st
+        (_, pb, pba, lo, counts, offsets, total, ovf, out_cap, chunk0,
+         out, exists_acc, ph) = st
         with ph:
             # the sort engine's overflow is informational (counts already
             # widened) and syncs after the chunk loop; the hash engine's
@@ -5035,7 +5062,7 @@ class _JoinProber:
                     with phase("host_sync:join_overflow"):
                         ovn = int(ovf)
                 out, exists, self.bm = self.jexpand(
-                    table, pb, pba, lo, counts, offsets, 0, out_cap, self.bm)
+                    table, pb, pba, lo, counts, offsets, 0, chunk0, self.bm)
                 exists_acc = exists_acc | exists
                 ovn = ov_rows  # recorded after the chunk loop
             self._n_out = self._n_out + jnp.sum(out.live)
@@ -5044,17 +5071,22 @@ class _JoinProber:
         with ph:
             with phase("host_sync:join_total"):
                 tot = int(total)
-        base = out_cap
+        self._prev_total = tot
+        # the chunks after the first cover [chunk0, tot), each at the bucket
+        # of what remains, at most `out_cap`; `base` ends at the lanes
+        # gathered
+        base = chunk0
         while base < tot:
+            lanes = min(out_cap, round_up_capacity(tot - base))
             with ph:
                 out, exists, self.bm = self.jexpand(
-                    table, pb, pba, lo, counts, offsets, base, out_cap,
+                    table, pb, pba, lo, counts, offsets, base, lanes,
                     self.bm)
                 exists_acc = exists_acc | exists
                 self._n_out = self._n_out + jnp.sum(out.live)
                 ph.items = 1
             yield out
-            base += out_cap
+            base += lanes
         nb = None
         with ph:
             if self.engine != "hash":
@@ -5081,7 +5113,8 @@ class _JoinProber:
                 nb = self.jnull(table, pb, exists_acc)
                 self._n_out = self._n_out + jnp.sum(nb.live)
                 ph.items = 1
-        _expand_phases(self.ctx.tracer, tot, base, ovn)
+        _expand_phases(self.ctx.tracer, tot, base, ovn,
+                       sized=chunk0 if chunk0 < out_cap else 0)
         if nb is not None:
             _outer_phase(self.ctx.tracer, pb.capacity)
             yield nb

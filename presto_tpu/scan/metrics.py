@@ -17,7 +17,7 @@ COUNTER_NAMES = (
     "join_fanout_overflow_rows", "radix_partitions_spilled",
     "radix_spill_bytes", "radix_aligned_batches",
     "join_search_steps", "join_verify_width", "join_emit_lanes",
-    "join_expand_rows", "join_build_rows",
+    "join_expand_rows", "join_build_rows", "join_expand_sized",
 )
 
 # dispatch-count counters for whole-fragment fusion (exec/fragment_jit.py):
@@ -85,6 +85,11 @@ _HELP = {
         "live rows of the sorted join builds observed, read once by the "
         "host with the build's other statistics (exec/runtime.py: "
         "_observe_build_table)",
+    "join_expand_sized":
+        "lanes of the first expand chunks of general-path probe batches "
+        "sized by the previous batch's total below their cap "
+        "(join_out_capacity or the probe's capacity), recorded with "
+        "tracing on (exec/runtime.py: _expand_phases)",
     "fragment_dispatches":
         "fused whole-fragment device dispatches (one lax.scan program "
         "covering a stacked window of batches)",

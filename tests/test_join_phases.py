@@ -10,11 +10,15 @@ records none of them; `join_emit` is one occurrence a batch whose output was
 gathered once its count was read, `items` its lanes. And the one thing the tracer learned for it: an
 occurrence that a generator leaves before a `yield` and enters again after
 counts once. A batch on the general path also leaves `join_expand` (`items` =
-its rows), `join_expand_lanes` (chunks x `out_cap`) and, where a key's matches
-pass the counting scan of 8, `join_fanout_overflow`; the single-match path
-leaves none. A sorted build leaves `join_build_table` (`items` = its live
-rows), and a LEFT join one `join_outer` a batch it hands on whole (`items` =
-the lanes). TPC-H Q9 through the same path, its two-key join general in the
+its rows), `join_expand_lanes` (the lanes its chunks gathered: a prober's first
+batch expands at `out_cap`, a later one's chunk 0 at the bucket of twice the
+previous batch's total, `join_expand_sized` where that is below `out_cap`)
+and, where a key's matches pass the counting scan of 8, `join_fanout_overflow`;
+the single-match path leaves none. Planted tables show the sized chunks lose
+no row: a skewed batch behind a sparse one, its keys past the hash engine's
+match width, inner and LEFT. A sorted build leaves `join_build_table`
+(`items` = its live rows), and a LEFT join one `join_outer` a batch it hands
+on whole (`items` = the lanes). TPC-H Q9 through the same path, its two-key join general in the
 plan, answers as `benchmark/reference/q9.py` does."""
 
 import json
@@ -22,14 +26,18 @@ import math
 import os
 import time
 
+import numpy as np
+import pandas as pd
 import pytest
 
 from presto_tpu import client
+from presto_tpu.batch import round_up_capacity
 from presto_tpu.obs import trace as obs_trace
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BATCH = 8192
-EXPAND_PHASES = ("join_expand", "join_expand_lanes", "join_fanout_overflow")
+EXPAND_PHASES = ("join_expand", "join_expand_lanes", "join_expand_sized",
+                 "join_fanout_overflow")
 JOIN_PHASES = ("join_build", "join_probe", "join_search", "join_verify",
                "join_emit", "join_build_table", "join_outer",
                *EXPAND_PHASES, "host_sync:join_build_rows",
@@ -75,6 +83,33 @@ def statement(url, sql, **properties):
 def all_phases(summary):
     return {name: agg for by_name in summary["phases"].values()
             for name, agg in by_name.items()}
+
+
+def expand_chunks(totals, out_cap):
+    """The lanes of each chunk that one prober's general batches gather, in
+    order, from their totals: the first batch's chunk 0 is `out_cap`, a later
+    batch's the bucket of twice the previous total, at most `out_cap`; the
+    chunks after it cover the rest of the batch's total, each at the bucket
+    of what remains, at most `out_cap`."""
+    chunks, prev = [], None
+    for tot in totals:
+        first = out_cap if prev is None else min(
+            out_cap, round_up_capacity(2 * prev))
+        batch = [first]
+        while sum(batch) < tot:
+            batch.append(min(out_cap, round_up_capacity(tot - sum(batch))))
+        chunks.append(batch)
+        prev = tot
+    return chunks
+
+
+def per_batch(keys, matches, batches):
+    """Each probe batch's total: the matches (a count a key) of the keys
+    each of `batches` even splits of the probe column holds, as the memory
+    connector splits a table."""
+    n = len(keys)
+    return [int(matches.reindex(keys[n * i // batches:n * (i + 1) // batches])
+                .fillna(0).sum()) for i in range(batches)]
 
 
 def unique_batches(task):
@@ -231,7 +266,20 @@ def test_a_general_batch_records_what_it_expanded_to(url, out_capacity):
     # the count(*) - and what the chunks gathered, rows or not
     assert expand["items"] == rows[0][0] > 0
     assert metrics.snapshot()["join_expand_rows"] - before == expand["items"]
-    assert lanes["items"] == task["join_probe"]["items"] * int(out_capacity or BATCH)
+    # the lanes of every chunk gathered: the first batch's at `out_cap`,
+    # the next one's from the first's total (orders' keys, a batch's split,
+    # against the two months' lines of each order)
+    from presto_tpu.catalog.tpch import TpchGenerator
+
+    orders, lines = TpchGenerator(0.01).orders_and_lineitem()
+    shipped = lines["l_shipdate"] < np.datetime64("1992-03-01", "D").astype(int)
+    totals = per_batch(orders["o_orderkey"],
+                       pd.Series(lines["l_orderkey"][shipped]).value_counts(),
+                       general)
+    assert sum(totals) == expand["items"]
+    chunks = expand_chunks(totals, int(out_capacity or BATCH))
+    assert lanes["items"] == sum(map(sum, chunks))
+    assert task["join_probe"]["items"] == sum(map(len, chunks))
     assert expand["items"] <= lanes["items"]
     # an order has seven lines at the most: no key passes the scan of 8
     assert "join_fanout_overflow" not in task
@@ -338,6 +386,154 @@ def test_an_inner_join_records_no_outer_batch(url):
         assert "join_outer" not in all_phases(doc)
 
 
+# planted tables for the sized chunks: `b` builds (500 rows; keys 0-99 three
+# times, hot keys 1000-1009 twenty times, past the hash engine's match width
+# of 8), `p` probes in four batches of BATCH rows that mostly miss: 60 rows of
+# the first match, 30 of the second, 50 of the third match hot keys and 100
+# others (1,300 rows, far past the 256 lanes its chunk 0 gets from the
+# second's 90), 50 of the fourth
+PLANTED_HITS = [(60, 0), (30, 0), (100, 50), (50, 0)]   # (plain, hot) a batch
+
+
+def planted_tables():
+    bk = np.concatenate([np.repeat(np.arange(100), 3),
+                         np.repeat(np.arange(1000, 1010), 20)])
+    pk = -1 - np.arange(4 * BATCH)
+    for i, (plain, hot) in enumerate(PLANTED_HITS):
+        at = i * BATCH + 7 * np.arange(plain + hot)
+        pk[at] = np.concatenate([np.arange(plain) % 100,
+                                 1000 + np.arange(hot) % 10])
+    return (pd.DataFrame({"bk": bk, "bv": np.arange(len(bk)) * 3 + 1}),
+            pd.DataFrame({"pk": pk, "id": np.arange(len(pk))}))
+
+
+PLANTED_INNER = ("select count(*) as n, sum(bv) as s, sum(id * bv) as w "
+                 "from p join b on pk = bk")
+PLANTED_LEFT = ("select count(*) as n, count(bv) as m, sum(bv) as s, "
+                "sum(case when bv is null then id else 0 end) as missed "
+                "from p left join b on pk = bk")
+
+
+@pytest.fixture(scope="module")
+def planted():
+    """(url, b, p): a one-worker cluster over the planted tables."""
+    from presto_tpu.catalog.memory import MemoryConnector
+    from presto_tpu.connector import Catalog
+    from presto_tpu.server.coordinator import DistributedRunner
+
+    b, p = planted_tables()
+    conn = MemoryConnector()
+    conn.add_table("b", b)
+    conn.add_table("p", p)
+    catalog = Catalog()
+    catalog.register("m", conn, default=True)
+    with DistributedRunner(catalog, n_workers=1) as dr:
+        yield dr.coordinator.url, b, p
+
+
+def planted_totals(b, p):
+    return per_batch(p["pk"].to_numpy(), b["bk"].value_counts(), 4)
+
+
+@pytest.mark.parametrize("engine", ["sort", "hash"])
+def test_a_general_join_over_several_batches_answers_as_at_full_chunks(
+        planted, engine):
+    """Chunk 0 sized by the previous batch's total: the same rows as with
+    `join_out_capacity` at the probe's capacity, as with every chunk at a
+    cap of 128 lanes (the least bucket, so no chunk is sized), and as
+    pandas."""
+    url, b, p = planted
+    rows, summary = statement(url, PLANTED_INNER, breaker_engine=engine)
+    rows_cap, _ = statement(url, PLANTED_INNER, breaker_engine=engine,
+                            join_out_capacity=str(BATCH))
+    rows_least, least = statement(url, PLANTED_INNER, breaker_engine=engine,
+                                  join_out_capacity="128")
+    m = p.merge(b, left_on="pk", right_on="bk")
+    assert rows == rows_cap == rows_least == [
+        [len(m), int(m.bv.sum()), int((m.id * m.bv).sum())]]
+    task = summary["phases"]["task"]
+    assert task["host_sync:join_total"]["n"] == task["join_expand"]["n"] == 4
+    assert task["join_expand"]["items"] == len(m) == sum(planted_totals(b, p))
+    assert task["join_expand_sized"]["n"] == 3
+    assert "join_expand_sized" not in least["phases"]["task"]
+
+
+@pytest.mark.parametrize("engine", ["sort", "hash"])
+def test_a_batch_past_its_sized_chunk_takes_more_chunks(planted, engine):
+    """The third batch expands to 1,300 rows from a chunk 0 of 256 lanes
+    (twice the second's 90 rows): the chunks after it cover the rest, each
+    at the bucket of what remains. Every lane is accounted for
+    and every row reaches the count."""
+    url, b, p = planted
+    totals = planted_totals(b, p)
+    assert totals == [180, 90, 1300, 150]
+    chunks = expand_chunks(totals, BATCH)
+    assert chunks == [[BATCH], [512], [256, 2048], [4096]]
+    rows, summary = statement(url, PLANTED_INNER, breaker_engine=engine)
+    assert rows[0][0] == sum(totals)
+    task = summary["phases"]["task"]
+    assert task["join_probe"]["items"] == sum(map(len, chunks))
+    assert task["join_expand_lanes"]["items"] == sum(map(sum, chunks))
+
+
+def test_a_left_join_on_the_general_path_null_extends_the_same_rows(planted):
+    """Every probe row without a match once with a NULL build side, every
+    match once, whether chunk 0 was sized or at the probe's capacity."""
+    url, b, p = planted
+    m = p.merge(b, left_on="pk", right_on="bk", how="left")
+    missed = m.bv.isna()
+    want = [[len(m), int((~missed).sum()), int(m.bv.sum()),
+             int(m.id[missed].sum())]]
+    rows, summary = statement(url, PLANTED_LEFT, breaker_engine="sort")
+    rows_cap, _ = statement(url, PLANTED_LEFT, breaker_engine="sort",
+                            join_out_capacity=str(BATCH))
+    assert rows == rows_cap == want
+    task = summary["phases"]["task"]
+    assert task["join_outer"]["n"] == task["join_expand"]["n"] == 4
+    assert task["join_expand_sized"]["n"] == 3
+
+
+def test_join_expand_sized_counts_the_batches_after_the_first(planted):
+    """`join_expand_sized`: one occurrence a general batch whose chunk 0 was
+    below `out_cap`, every batch of a prober but its first, `items` those
+    chunks' lanes, no time of its own, and the process counter alike; with
+    tracing off the answer is the same and nothing is recorded."""
+    from presto_tpu.scan import metrics
+
+    url, b, p = planted
+    chunks = expand_chunks(planted_totals(b, p), BATCH)
+    before = metrics.snapshot()["join_expand_sized"]
+    rows, summary = statement(url, PLANTED_INNER, breaker_engine="sort")
+    task = summary["phases"]["task"]
+    sized = task["join_expand_sized"]
+    assert sized["n"] == task["host_sync:join_total"]["n"] - 1 == 3
+    assert sized["items"] == sum(c[0] for c in chunks[1:]) == 512 + 256 + 4096
+    assert sized["busy_s"] < 0.01 * task["join_probe"]["busy_s"]
+    assert metrics.snapshot()["join_expand_sized"] - before == sized["items"]
+    before = metrics.snapshot()["join_expand_sized"]
+    rows_off, none = statement(url, PLANTED_INNER, breaker_engine="sort",
+                               tracing="false")
+    assert rows_off == rows and none is None
+    assert metrics.snapshot()["join_expand_sized"] == before
+
+
+def test_the_hash_engines_overflow_redo_keeps_a_sized_chunk_whole(planted):
+    """The third batch's hot keys hold 20 build rows, past the match matrix's
+    8: the hash engine discards its chunk 0 of 256 lanes, widens, and redoes
+    it at 256 lanes before the chunks after it; no row is lost or doubled."""
+    url, b, p = planted
+    rows, summary = statement(url, PLANTED_INNER, breaker_engine="hash")
+    m = p.merge(b, left_on="pk", right_on="bk")
+    assert rows == [[len(m), int(m.bv.sum()), int((m.id * m.bv).sum())]]
+    task = summary["phases"]["task"]
+    # the hot keys' 50 probe rows, in the one batch
+    assert (task["join_fanout_overflow"]["n"],
+            task["join_fanout_overflow"]["items"]) == (1, 50)
+    assert task["join_expand_sized"]["n"] == 3
+    # the overflow reads: one a batch, and the widenings' in the third
+    assert task["host_sync:join_overflow"]["n"] > task["host_sync:join_total"]["n"]
+
+
 @pytest.mark.parametrize("seed", [9, 2147483909])
 def test_q9_answers_as_its_reference_with_its_two_key_join_general(seed):
     """Its own cluster a seed: the reference reads the arrays the catalog
@@ -376,8 +572,20 @@ def test_q9_answers_as_its_reference_with_its_two_key_join_general(seed):
     assert task["host_sync:join_total"]["n"] == task["join_expand"]["n"] == general
     assert unique_batches(task) == math.ceil(
         len(data["lineitem"]["l_orderkey"]) / BATCH) + 2
-    assert task["join_expand"]["items"] < task["join_expand_lanes"]["items"] \
-        == general * BATCH
+    # orders' second batch expands at the bucket of twice its first's total;
+    # partsupp's one batch at the probe's capacity
+    green = np.char.find(bdata.strings(data["part"]["p_name"]).astype(str),
+                         "green") >= 0
+    green_lines = np.isin(data["lineitem"]["l_partkey"],
+                          data["part"]["p_partkey"][green])
+    totals = per_batch(
+        data["orders"]["o_orderkey"],
+        pd.Series(data["lineitem"]["l_orderkey"][green_lines]).value_counts(),
+        math.ceil(len(data["orders"]["o_orderkey"]) / BATCH))
+    chunks = expand_chunks(totals, BATCH) + expand_chunks([sum(totals)], BATCH)
+    assert task["join_expand"]["items"] == 2 * sum(totals) > 0
+    assert task["join_expand_lanes"]["items"] == sum(map(sum, chunks))
+    assert task["join_expand"]["items"] < task["join_expand_lanes"]["items"]
 
 
 @pytest.mark.parametrize("qid", ["q6", "q1"])
