@@ -98,6 +98,47 @@ def _item_desc(rng: np.random.Generator, n: int):
     return Dictionary.encode([" ".join(w).capitalize() + "." for w in words])
 
 
+# the classes of each category (dsdgen's `categories` and `classes`
+# distributions): an item's i_class lies in its i_category's list
+_CLASSES = {
+    "Books": ("arts", "business", "computers", "cooking", "entertainment",
+              "fiction", "history", "home repair", "mystery", "parenting",
+              "reference", "romance", "science", "self-help", "sports",
+              "travel"),
+    "Children": ("infants", "newborn", "school-uniforms", "toddlers"),
+    "Electronics": ("audio", "automotive", "camcorders", "cameras",
+                    "disk drives", "dvd/vcr players", "karoke", "memory",
+                    "monitors", "musical", "personal", "portable",
+                    "scanners", "stereo", "televisions", "wireless"),
+    "Home": ("accent", "bathroom", "bedding", "blinds/shades",
+             "curtains/drapes", "decor", "flatware", "furniture",
+             "glassware", "kids", "lighting", "mattresses", "paint", "rugs",
+             "tables", "wallpaper"),
+    "Jewelry": ("birdal", "bracelets", "consignment", "costume", "custom",
+                "diamonds", "earings", "estate", "gold", "jewelry boxes",
+                "loose stones", "mens watch", "pendants", "rings",
+                "semi-precious", "womens watch"),
+    "Men": ("accessories", "pants", "shirts", "sports-apparel"),
+    "Music": ("classical", "country", "pop", "rock"),
+    "Shoes": ("athletic", "kids", "mens", "womens"),
+    "Sports": ("archery", "athletic shoes", "baseball", "basketball",
+               "camping", "fishing", "fitness", "football", "golf", "guns",
+               "hockey", "optics", "outdoor", "pools", "sailing", "tennis"),
+    "Women": ("dresses", "fragrances", "maternity", "swimwear"),
+}
+_BRANDS = 1000  # i_brand is brand#{i % 1000}: a brand's items share a category
+
+
+def _item_class(rng: np.random.Generator, n: int, cats):
+    """i_class: one class a brand, drawn from `rng` uniformly over its
+    category's classes, so that category, class and brand nest."""
+    classes = [_CLASSES[cats[b % len(cats)]] for b in range(_BRANDS)]
+    pick = rng.random(_BRANDS) * np.array([len(c) for c in classes])
+    names = np.array([c[k] for c, k in zip(classes, pick.astype(int).tolist())],
+                     object)
+    return names[np.arange(n) % _BRANDS]
+
+
 class TpcdsGenerator:
     def __init__(self, sf: float = 1.0, seed: int = 20030101):
         self.sf = sf
@@ -132,6 +173,8 @@ class TpcdsGenerator:
             "d_dow": dow.astype(np.int64),
             "d_qoy": ((months - 1) // 3 + 1).astype(np.int64),
             "d_week_seq": (np.arange(_DATE_DIM_ROWS) // 7 + 1).astype(np.int64),
+            # the specification's month sequence: 1200 is January 2000
+            "d_month_seq": ((years - 1900) * 12 + months - 1).astype(np.int64),
         }
 
     def store(self) -> Dict[str, np.ndarray]:
@@ -169,6 +212,7 @@ class TpcdsGenerator:
             "i_color": np.array([["red", "green", "blue", "white", "black", "ivory", "khaki", "salmon"][i % 8] for i in range(n)], object),
             # drawn last, so every column above keeps its stream
             "i_item_desc": _item_desc(rng, n),
+            "i_class": _item_class(rng, n, cats),
         }
 
     def customer(self) -> Dict[str, np.ndarray]:

@@ -174,15 +174,19 @@ def config_fingerprint(config) -> str:  # fp: key(program-ns) covers(config, pla
     return hashlib.sha256(repr(sorted(items)).encode()).hexdigest()[:16]
 
 
-def structural_fingerprint(node, config=None) -> Optional[str]:
+def structural_fingerprint(node, config=None,
+                           program: bool = False) -> Optional[str]:
     """sha256 namespace for one plan node: the codec's canonical JSON of
     its subtree (survives a wire round trip because strip_runtime_state
     keeps plans runtime-state-free) plus the config fingerprint. None
-    when the subtree has no codec encoding."""
+    when the subtree has no codec encoding. `program=True` hashes the
+    codec's program form (no RemoteSource fragment ids): the compile
+    plane's namespace, where the history and result-cache keys keep
+    them."""
     from presto_tpu.plan.codec import CodecError, canonical_node_json
 
     try:
-        doc = canonical_node_json(node)
+        doc = canonical_node_json(node, program=program)
     except (CodecError, TypeError, ValueError):
         return None
     h = hashlib.sha256(doc.encode())
@@ -203,7 +207,7 @@ def install_plan(root, config) -> int:  # fp: uses-key(program-ns)
 
     def walk(n):
         nonlocal stamped
-        ns = structural_fingerprint(n)
+        ns = structural_fingerprint(n, program=True)
         if ns is not None:
             n.__dict__["_program_ns"] = ns + cfg_fp
             stamped += 1

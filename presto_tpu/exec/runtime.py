@@ -71,6 +71,7 @@ from presto_tpu.ops.sort import (
     SortKey,
     compact,
     compact_permutation,
+    lex_sort,
     limit_batch,
     permute_batch,
     sort_batch,
@@ -1352,9 +1353,7 @@ def _sorted_group_agg(b: Batch, key_syms, a: AggSpec, cap: int):
         sortval = jnp.where(ov, cy.values, _minmax_ident(cy.values.dtype, False))
     operands.append(sortval)
 
-    perm = jnp.arange(n, dtype=jnp.int32)
-    sorted_ops = jax.lax.sort(operands + [perm], num_keys=len(operands))
-    sperm = sorted_ops[-1]
+    sorted_ops, sperm = lex_sort(operands)
     sdead = sorted_ops[0]
     change = jnp.zeros(n, dtype=bool).at[0].set(True)
     for sk in sorted_ops[:num_key_ops]:
@@ -2218,6 +2217,11 @@ def _agg_presize(node: Aggregate, ctx: "ExecContext"):
         except Exception:
             _st = None
         rows = _st.rows if (_st is not None and _st.rows) else None
+        if rows is None and node.grouping_set:
+            # behind the exchanges a grouping set's steps take the estimate
+            # the fragmenter derived before them: one capacity, or grace
+            # from the start past the ceiling, not a ladder of programs
+            rows = node.partial_groups
         # behind its exchange a final step derives nothing: where the
         # fragmenter left it its partial step's estimate, one past the
         # ceiling takes the partial's decision (that went passthrough and
@@ -2677,6 +2681,18 @@ def _hbo_record_scans(root: PlanNode, ctx: "ExecContext") -> None:
         pass
 
 
+def _grouping_set_lanes(stream: Iterator[Batch], tracer) -> Iterator[Batch]:
+    """The input of one GROUPING SETS set's aggregate, passed on as it comes;
+    once drained, one `grouping_set` occurrence, no time of its own, `items`
+    = the lanes of the batches it held (their capacities: host numbers)."""
+    lanes = 0
+    for b in stream:
+        lanes += b.capacity
+        yield b
+    with tracer.phase("grouping_set", items=lanes):
+        pass
+
+
 def _execute_aggregate(node: Aggregate, ctx: ExecContext) -> Iterator[Batch]:
     if ctx.lifespan is None:
         ls = _grouped_execution_lifespans(node)
@@ -2704,6 +2720,8 @@ def _execute_aggregate(node: Aggregate, ctx: ExecContext) -> Iterator[Batch]:
         return
 
     in_stream, _ = _fused_child(node.child, ctx)
+    if node.grouping_set and node.step != "partial" and ctx.tracer.enabled:
+        in_stream = _grouping_set_lanes(in_stream, ctx.tracer)
     engine = _breaker_engine_choice(node, ctx)
     steps = _agg_steps(node, engine)
     chain = steps.chain
@@ -5994,12 +6012,10 @@ def _distinct_rows(b: Batch) -> Batch:
     enc, _ = _null_safe_encode(b)
     n = b.capacity
     operands = [(~b.live).astype(jnp.int32)] + [c.values for c in enc.columns]
-    perm = jnp.arange(n, dtype=jnp.int32)
-    sorted_ops = jax.lax.sort(operands + [perm], num_keys=len(operands))
-    sperm = sorted_ops[-1]
+    sorted_ops, sperm = lex_sort(operands)
     sdead = sorted_ops[0]
     first = jnp.zeros(n, dtype=bool).at[0].set(True)
-    for sk in sorted_ops[:-1]:
+    for sk in sorted_ops:
         first = first.at[1:].set(first[1:] | (sk[1:] != sk[:-1]))
     from presto_tpu.ops.sort import permute_batch
 
@@ -6107,10 +6123,23 @@ def _execute_window(node: Window, ctx: ExecContext) -> Iterator[Batch]:
     (partition keys, order keys), compute every function in the node's spec
     as closed-form vector ops (ops/window.py), emit one batch with the
     window columns appended (reference: WindowOperator.java:47 over a
-    PagesIndex — here one lax.sort + O(n) vector passes)."""
-    acc = _collect_concat(execute_node(node.child, ctx))
+    PagesIndex — here one lax.sort + O(n) vector passes).
+
+    `window_collect` runs from the first pull of the input to the
+    concatenated batch (`items` = the batches concatenated), the wait on the
+    fragment upstream inside it; `window_lanes`, no time of its own, counts
+    the lanes of the batch the sort runs over. The input is padded with dead
+    lanes to its power-of-two bucket: the sum of its batches' capacities
+    follows the data, and each new sum would compile the sort again."""
+    with ctx.tracer.phase("window_collect") as ph:
+        batches = list(execute_node(node.child, ctx))
+        ph.items = len(batches)
+        acc = _collect_concat(iter(batches))
     if acc is None:
         return
+    acc = _pad_batch(acc, round_up_capacity(acc.capacity))
+    with ctx.tracer.phase("window_lanes", items=acc.capacity):
+        pass
     compute = build_window_compute(node)
     yield _node_jit(node, "window", lambda: compute)(acc)
 

@@ -145,24 +145,21 @@ def catalog_token(catalog) -> str:
 
 
 def node_fingerprint(node, catalog) -> Optional[str]:  # fp: key(hbo-history) covers(plan-structure, catalog)
-    """History key for a plan node: pure structural sha (reusing the
-    compile plane's ``_program_ns`` stamp when present — its last 16 hex
-    chars are the config fingerprint, which must NOT key history) plus
-    the catalog snapshot token. Memoized on the node."""
+    """History key for a plan node: pure structural sha (with its
+    RemoteSources' fragment ids, which the compile plane's ``_program_ns``
+    leaves out: subtrees behind different exchanges read different data)
+    plus the catalog snapshot token. Memoized on the node."""
     fp = node.__dict__.get("_hbo_fp")
     if fp is not None:
         return fp or None
-    sha = None
-    ns = node.__dict__.get("_program_ns")
-    if isinstance(ns, str) and len(ns) > 16:
-        sha = ns[:-16]
+    try:
+        from presto_tpu.exec.programs import structural_fingerprint
+        sha = structural_fingerprint(node)
+    except Exception:
+        sha = None
     if sha is None:
-        try:
-            from presto_tpu.exec.programs import structural_fingerprint
-            sha = structural_fingerprint(node)
-        except Exception:
-            node.__dict__["_hbo_fp"] = ""
-            return None
+        node.__dict__["_hbo_fp"] = ""
+        return None
     fp = sha[:24] + "/" + catalog_token(catalog)
     node.__dict__["_hbo_fp"] = fp
     return fp

@@ -41,6 +41,8 @@ from typing import NamedTuple, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
+from presto_tpu.ops.sort import lex_sort
+
 
 class StateCol(NamedTuple):
     values: jnp.ndarray
@@ -125,11 +127,7 @@ def grouped_merge(
             operands.append(jnp.where(k.validity, k.values, jnp.zeros_like(k.values)))
         else:
             operands.append(k.values)
-    num_keys = len(operands)
-    perm = jnp.arange(n, dtype=jnp.int32)
-    sorted_ops = jax.lax.sort(operands + [perm], num_keys=num_keys)
-    sorted_keys = sorted_ops[:num_keys]
-    sperm = sorted_ops[-1]
+    sorted_keys, sperm = lex_sort(operands)
     sdead = sorted_keys[0]
 
     # boundary where any sort key changes (first row is always a boundary)

@@ -72,12 +72,15 @@ def window_keys(
     """All boundary structure for one spec, over batch-sorted rows (live rows
     first — sort_permutation puts dead rows last)."""
     n = live.shape[0]
-    iota = jnp.arange(n)
+    # positions in int32 (a batch has fewer than 2^31 lanes): the TPU has no
+    # 64-bit integer unit, and its compiler takes minutes over an emulated
+    # int64 cummax or scatter, or fails on them
+    iota = jnp.arange(n, dtype=jnp.int32)
     is_start = _change_mask(part_cols, live)
     seg_start = jax.lax.cummax(jnp.where(is_start, iota, 0))
     seg_id = jnp.cumsum(is_start.astype(jnp.int32)) - 1
-    ones = live.astype(jnp.int64)
-    sizes = jnp.zeros(n, dtype=jnp.int64).at[seg_id].add(ones, mode="drop")
+    ones = live.astype(jnp.int32)
+    sizes = jnp.zeros(n, dtype=jnp.int32).at[seg_id].add(ones, mode="drop")
     seg_size = sizes[seg_id]
     peer_change = is_start | _change_mask(order_cols, live) if order_cols else is_start
     if not order_cols:
@@ -87,15 +90,16 @@ def window_keys(
     else:
         peer_start = jax.lax.cummax(jnp.where(peer_change, iota, 0))
         peer_id = jnp.cumsum(peer_change.astype(jnp.int32)) - 1
-        last = jnp.zeros(n, dtype=jnp.int64).at[peer_id].max(
+        last = jnp.zeros(n, dtype=jnp.int32).at[peer_id].max(
             jnp.where(live, iota, 0), mode="drop"
         )
         peer_last = last[peer_id]
     row_number = iota - seg_start + 1
+    i64 = jnp.int64
     return WindowKeys(
-        is_start, seg_start, seg_id, seg_size, peer_start,
-        peer_last.astype(jnp.int32), row_number.astype(jnp.int64),
-        live, jnp.sum(ones),
+        is_start, seg_start.astype(i64), seg_id, seg_size.astype(i64),
+        peer_start.astype(i64), peer_last, row_number.astype(i64),
+        live, jnp.sum(ones, dtype=i64),
     )
 
 

@@ -2761,6 +2761,8 @@ class Planner:
             # in place (pruning one branch's copy of the shared
             # pre-projection must not strip columns another branch needs)
             agg_i = plan_one(gsyms, pre if i == 0 else _copy.deepcopy(pre))
+            if isinstance(agg_i, Aggregate):
+                agg_i.grouping_set = True
             pad = []
             for sym in group_syms:
                 if sym in gsyms:

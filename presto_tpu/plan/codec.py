@@ -139,6 +139,8 @@ def node_to_json(n: PlanNode) -> Dict[str, Any]:
                       for a in n.aggs]}
         if n.partial_groups is not None:
             d["partial_groups"] = float(n.partial_groups)
+        if n.grouping_set:
+            d["grouping_set"] = True
         return d
     if isinstance(n, HashJoin):
         return {"k": "join", "kind": n.kind,
@@ -223,7 +225,7 @@ def node_to_json(n: PlanNode) -> Dict[str, Any]:
     raise CodecError(f"unencodable plan node {type(n).__name__}")
 
 
-def canonical_node_json(n: PlanNode) -> str:
+def canonical_node_json(n: PlanNode, program: bool = False) -> str:
     """Canonical structural serialization of one node's subtree: the wire
     encoding rendered with sorted keys and no whitespace, so it is
     byte-identical for any two nodes that encode to the same logical plan
@@ -231,11 +233,27 @@ def canonical_node_json(n: PlanNode) -> str:
     across processes. strip_runtime_state keeps wire plans free of
     runtime attrs, so nothing execution-dependent can leak in. This is
     the basis of the compile plane's structural program fingerprints
-    (exec/programs.py)."""
+    (exec/programs.py).
+
+    `program=True` is the form a program is keyed on: a RemoteSource
+    without its fragment id, which names the exchange that feeds it and
+    not what is computed over its pages, so copies of one subtree behind
+    different exchanges (the sets of a GROUPING SETS) share programs."""
     import json
 
-    return json.dumps(node_to_json(n), sort_keys=True,
-                      separators=(",", ":"), default=str)
+    d = node_to_json(n)
+    if program:
+        d = _without_fragment_ids(d)
+    return json.dumps(d, sort_keys=True, separators=(",", ":"), default=str)
+
+
+def _without_fragment_ids(d):
+    if isinstance(d, dict):
+        return {k: _without_fragment_ids(v) for k, v in d.items()
+                if not (k == "fid" and d.get("k") == "remote")}
+    if isinstance(d, list):
+        return [_without_fragment_ids(v) for v in d]
+    return d
 
 
 def node_fingerprint(n: PlanNode) -> str:
@@ -268,6 +286,7 @@ def node_from_json(d: Dict[str, Any]) -> PlanNode:
                      a.get("param")) for a in d["aggs"]],
             step=d.get("step", "single"),
             partial_groups=d.get("partial_groups"),
+            grouping_set=bool(d.get("grouping_set", False)),
         )
     if k == "join":
         return HashJoin(

@@ -284,10 +284,13 @@ class _Fragmenter:
 
         if behind_exchange(partial):
             return None
+        return self._derived_groups(partial)
+
+    def _derived_groups(self, node: Aggregate) -> Optional[float]:
         try:
             from presto_tpu.plan.stats import derive
 
-            st = derive(partial, self.catalog)
+            st = derive(node, self.catalog)
         except Exception:
             return None
         return float(st.rows) if st is not None and st.rows else None
@@ -305,6 +308,10 @@ class _Fragmenter:
         if isinstance(node, Aggregate):
             from presto_tpu.plan.agg_states import is_decomposable
 
+            # a grouping set's estimate, derived before the exchanges cut its
+            # input: both steps size from it (exec/runtime.py, _agg_presize)
+            # instead of climbing capacities, a sort program compiled at each
+            est = self._derived_groups(node) if node.grouping_set else None
             child, cpart = self.process(node.child)
             if cpart == SINGLE:
                 # already on one task — no exchange needed
@@ -315,16 +322,20 @@ class _Fragmenter:
                 # have no mergeable partial form: gather raw rows to one task
                 node.child = self.cut(child, cpart, OUT_GATHER)
                 return node, SINGLE
-            partial = Aggregate(child, node.group_keys, node.aggs, step="partial")
+            partial = Aggregate(child, node.group_keys, node.aggs, step="partial",
+                                partial_groups=est,
+                                grouping_set=node.grouping_set)
             if node.group_keys:
-                groups = self._groups_its_task_derives(partial)
+                groups = est or self._groups_its_task_derives(partial)
                 rs = self.cut(partial, cpart, OUT_HASH, node.group_keys,
                               radix_align=True)
                 final = Aggregate(rs, node.group_keys, node.aggs, step="final",
-                                  partial_groups=groups)
+                                  partial_groups=groups,
+                                  grouping_set=node.grouping_set)
                 return final, HASH
             rs = self.cut(partial, cpart, OUT_GATHER)
-            final = Aggregate(rs, [], node.aggs, step="final")
+            final = Aggregate(rs, [], node.aggs, step="final",
+                              grouping_set=node.grouping_set)
             return final, SINGLE
         if isinstance(node, HashJoin):
             # colocated bucketed join first (GroupedExecutionTagger +

@@ -91,8 +91,13 @@ class Aggregate(PlanNode):
     # on a FINAL step: the groups its PARTIAL step estimates, where that
     # step's own task can derive them (no exchange below it). Nothing
     # derives behind an exchange, so this is how the final step takes the
-    # decision its partial takes (exec/runtime.py: _agg_presize)
+    # decision its partial takes (exec/runtime.py: _agg_presize). A
+    # grouping set's two steps both carry the estimate derived before the
+    # exchanges were cut
     partial_groups: Optional[float] = None
+    # one set's aggregate of a GROUPING SETS / ROLLUP / CUBE (its FINAL
+    # step, behind the exchange): the runtime's `grouping_set` phase
+    grouping_set: bool = False
 
     @property
     def output(self):

@@ -39,6 +39,7 @@ from presto_tpu.plan.nodes import (
     Project,
     QueryPlan,
     SemiJoin,
+    SetOp,
     Sort,
     TableScan,
     Unnest,
@@ -248,6 +249,10 @@ def prune_columns(node: PlanNode, required: Set[str]) -> PlanNode:
         node.aggs = [a for a in node.aggs if a.symbol in required]
         need = set(node.group_keys) | {a.arg for a in node.aggs if a.arg}
         need |= {a.arg2 for a in node.aggs if a.arg2}
+        if node.grouping_set:
+            # every set reads the shared pre-projection whole: the sets'
+            # copies of the input stay alike and compile to one program each
+            need |= {n for n, _ in node.child.output}
         node.child = prune_columns(node.child, need)
         return node
     if isinstance(node, HashJoin):
@@ -283,6 +288,18 @@ def prune_columns(node: PlanNode, required: Set[str]) -> PlanNode:
     if isinstance(node, Limit):
         node.child = prune_columns(node.child, required)
         return node
+    if isinstance(node, SetOp):
+        # positional: both sides keep the positions the node keeps. UNION
+        # ALL drops a position nobody above reads (one stays, for the row
+        # count); the DISTINCT variants compare whole rows and keep all
+        keep = list(range(len(node.symbols)))
+        if node.kind == "union" and node.all:
+            keep = [i for i in keep if node.symbols[i] in required] or keep[:1]
+        node.left = _prune_positions(node.left, keep)
+        node.right = _prune_positions(node.right, keep)
+        node.symbols = [node.symbols[i] for i in keep]
+        node.types = [node.types[i] for i in keep]
+        return node
     from presto_tpu.plan.nodes import HostProject as _HP
 
     if isinstance(node, _HP):
@@ -310,6 +327,22 @@ def prune_columns(node: PlanNode, required: Set[str]) -> PlanNode:
     for c in node.children():
         prune_columns(c, required)
     return node
+
+
+def _prune_positions(child: PlanNode, keep: List[int]) -> PlanNode:
+    """A set operation's child pruned to the output positions `keep`, in
+    that order (a GROUPING SETS branch's padding projection, or a nested
+    query's Output)."""
+    if isinstance(child, Output):
+        child.names = [child.names[i] for i in keep]
+        child.symbols = [child.symbols[i] for i in keep]
+        return prune_columns(child, set())
+    out = child.output
+    want = [out[i] for i in keep]
+    child = prune_columns(child, {n for n, _ in want})
+    if [n for n, _ in child.output] != [n for n, _ in want]:
+        child = Project(child, [(n, InputRef(t, n)) for n, t in want])
+    return child
 
 
 def cleanup(node: PlanNode) -> PlanNode:

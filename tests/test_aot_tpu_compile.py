@@ -8,8 +8,10 @@ are (ops/pallas_hash.TPU_REFUSAL), which is why that engine is not
 selectable on a TPU backend, and one case pins that gate.
 
 The multi-operand int64 sorts of the sort engine (build_side, grouped_merge
-on wide keys) are accepted too but take minutes each to compile, so they
-stay out of this file; CHANGES.md (PR 23) has their verdicts and seconds.
+on up to `ops/sort.ONE_SORT_MAX_KEYS` keys) are accepted too but take
+minutes each to compile, so they stay out of this file; CHANGES.md has
+their verdicts and seconds. Past that many keys a sort is a loop of
+one-key sorts, and one case compiles it.
 
 The topology is described inside a module-scoped fixture — never at import:
 only one process may load the TPU library, and under xdist every worker
@@ -166,6 +168,31 @@ def test_directory_probe_compiles(one_chip, program):
         # a count is its run's width: a second key adds no gather
         one_key = _probe_program_text(one_chip, "probe_counts")
         assert text.count(" gather(") == one_key.count(" gather(")
+
+
+def test_wide_grouped_merge_compiles_to_one_sort_in_a_loop(one_chip):
+    """The sort engine's merge of TPC-DS Q67's finest grouping set: eight
+    keys (five int32 dictionary codes, three int64), a table of N and a
+    batch of N. Its nine key operands are one stable one-key sort in a
+    loop (ops/sort.lex_sort), where one nine-key lax.sort compiled for
+    825 s at N."""
+    from presto_tpu.ops.grouping import KeyCol, StateCol, grouped_merge
+
+    dtypes = [jnp.int32] * 4 + [jnp.int64] * 3 + [jnp.int32]
+
+    def merge(keys, x, live):
+        kout, sout, out_live, n_groups = grouped_merge(
+            [KeyCol(k, None) for k in keys], [StateCol(x, None, "sum")],
+            live, N, engine="sort")
+        return [k.values for k in kout], sout[0].values, out_live, n_groups
+
+    compiled = jax.jit(merge).lower(
+        [_sds((2 * N,), d, one_chip) for d in dtypes],
+        _sds((2 * N,), jnp.int64, one_chip),
+        _sds((2 * N,), jnp.bool_, one_chip)).compile()
+    text = compiled.as_text()
+    assert text.count(" sort(") == 1
+    assert compiled.memory_analysis() is not None
 
 
 def test_q6_scan_filter_aggregate_chain_compiles(one_chip, monkeypatch):

@@ -151,15 +151,26 @@ class TestFingerprint:
         assert runstats.catalog_token(cat) != tok_before
 
     def test_fingerprint_strips_config_suffix(self, no_history):
+        # the history key is the config-free structural sha, whatever the
+        # compile plane stamped: its `_program_ns` (program form plus the
+        # config fingerprint) keys nothing here
+        from presto_tpu.exec.programs import structural_fingerprint
+        from presto_tpu.plan.nodes import RemoteSource
+
         cat = _skewed_catalog(100)
-
-        class N:
-            pass
-
-        n = N()
-        n.__dict__["_program_ns"] = "a" * 40 + "f" * 16  # sha + config fp
-        fp = runstats.node_fingerprint(n, cat)
-        assert fp.startswith("a" * 24 + "/")
+        node = LocalRunner(cat).plan("select k from m.t where k > 5").root.child
+        node.__dict__["_program_ns"] = "a" * 40 + "f" * 16  # sha + config fp
+        fp = runstats.node_fingerprint(node, cat)
+        assert fp == structural_fingerprint(node)[:24] + "/" + \
+            runstats.catalog_token(cat)
+        # two subtrees alike but for the exchange that feeds them share a
+        # program namespace and keep history keys of their own
+        src = [RemoteSource(fragment_id=f, output=list(node.output))
+               for f in (1, 2)]
+        a, b = (structural_fingerprint(s, program=True) for s in src)
+        assert a is not None and a == b
+        assert runstats.node_fingerprint(src[0], cat) != \
+            runstats.node_fingerprint(src[1], cat)
 
 
 class TestMetricRows:

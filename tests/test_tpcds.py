@@ -154,9 +154,10 @@ def test_inventory_grain():
 
 # -- the columns TPC-DS Q72 reads beyond the rest ---------------------------
 
+# (and i_class, which Q67 reads, drawn after i_item_desc)
 _ADDED = {"catalog_sales": ("cs_bill_cdemo_sk", "cs_bill_hdemo_sk"),
           "web_sales": ("ws_bill_cdemo_sk", "ws_bill_hdemo_sk"),
-          "item": ("i_item_desc",)}
+          "item": ("i_item_desc", "i_class")}
 # sha256 (24 hex digits) of every column these tables had before the three
 # were added, names and values in order: what the generator made then
 _BEFORE = {
