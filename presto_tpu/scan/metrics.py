@@ -46,6 +46,9 @@ _DISPATCH_COUNTER_NAMES = (
     # revocations honored by spillable operators
     "spill_partitions", "spill_repartitions", "spill_role_reversals",
     "spill_revocations",
+    # exchange plane (server/exchange.py): batches a consumer took from an
+    # upstream task on its own worker in process, and pages it decoded
+    "exchange_handoff_batches", "exchange_page_batches",
 )
 
 _HELP = {
@@ -133,6 +136,14 @@ _HELP = {
     "spill_revocations":
         "memory-pool revoke requests honored by spillable operator state "
         "(accumulators / join builds spilled down at a batch boundary)",
+    "exchange_handoff_batches":
+        "batches an exchange consumer took in process from an upstream "
+        "task's output buffer on its own worker: device batches, no page "
+        "(server/exchange.py)",
+    "exchange_page_batches":
+        "exchange pages a consumer decoded and put on the device: from "
+        "another worker, or the coordinator's result stream "
+        "(server/exchange.py)",
 }
 
 _lock = threading.Lock()

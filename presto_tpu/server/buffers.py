@@ -11,6 +11,15 @@ in a LATER phase, so back-pressure can never drain — pages beyond the
 memory cap overflow to an unlinked temp file instead of blocking (the
 reference's spooling output buffers), and `get` reads them back
 transparently by token.
+
+In-process handoff: a consumer task on the same worker claims its partition
+(`claim`) when it is created and reads the buffer directly
+(`get(in_process=True)`). Its entries are then the producer's device
+batches themselves, not pages. A partition that neither a local consumer has
+claimed nor an HTTP pull has read yet also takes device batches, up to
+`_UNREAD_DEVICE_BYTES`, so that whatever the producer makes before its
+consumer exists is handed too; an HTTP pull that finds them serializes them
+(server/worker.py). A spooling buffer holds pages only.
 """
 
 from __future__ import annotations
@@ -20,30 +29,50 @@ import tempfile
 import threading
 from typing import List, Optional, Tuple
 
+from presto_tpu.memory import batch_device_bytes
 
-class _SharedPage:
-    """One broadcast page shared by every partition queue. The buffer's
-    byte accounting holds it exactly ONCE and releases it when the last
-    consumer acks or aborts (BroadcastOutputBuffer's page refcounting) —
-    counting per partition overstated buffered bytes N× and tripped
-    back-pressure long before the buffer was actually full."""
+# device bytes a partition read by no one yet may hold: a local consumer
+# claims its partition within a task creation of its producer's, an HTTP
+# consumer may first pull only once it has read its other inputs
+_UNREAD_DEVICE_BYTES = 32 << 20
 
-    __slots__ = ("page", "refs")
 
-    def __init__(self, page: bytes, refs: int):
-        self.page = page
+def _item_bytes(item) -> int:
+    """What an entry holds: a page's length, or a device batch's bytes."""
+    if isinstance(item, bytes):
+        return len(item)
+    return batch_device_bytes(item)
+
+
+class _Shared:
+    """One broadcast item (a page, or a device batch handed in process)
+    shared by every partition queue. The buffer's byte accounting holds it
+    exactly ONCE and releases it when the last consumer acks or aborts
+    (BroadcastOutputBuffer's page refcounting) — counting per partition
+    overstated buffered bytes N× and tripped back-pressure long before the
+    buffer was actually full."""
+
+    __slots__ = ("item", "size", "refs")
+
+    def __init__(self, item, size: int, refs: int):
+        self.item = item
+        self.size = size
         self.refs = refs
 
 
 class _PartitionBuffer:
-    """Token-addressed page queue for one consumer. Entries are either hot
-    bytes or ("d", offset, length) descriptors into the shared spool file."""
+    """Token-addressed queue for one consumer. Entries are hot bytes, device
+    batches, `_Shared` items or ("d", offset, length) descriptors into the
+    shared spool file."""
 
     def __init__(self):
         self.entries: List[object] = []
         self.base_token = 0          # token of entries[0]
         self.no_more = False
         self.aborted = False
+        self.local = False           # claimed by a consumer on this worker
+        self.remote = False          # read by an HTTP pull
+        self.unread_bytes = 0        # device bytes enqueued before either
 
     @property
     def end_token(self) -> int:
@@ -51,17 +80,20 @@ class _PartitionBuffer:
 
 
 class OutputBuffer:
-    """Pages per downstream partition with token/ack delivery.
+    """Pages or device batches per downstream partition with token/ack
+    delivery.
 
-    broadcast=True appends every page to all partitions (shared bytes —
-    reference: BroadcastOutputBuffer page reference counting).
+    broadcast=True appends every item to all partitions (shared, not
+    copied — reference: BroadcastOutputBuffer page reference counting).
     spool_dir, when set, disables producer blocking: overflow pages go to
-    disk (see module docstring).
+    disk (see module docstring). in_process=False holds pages alone, for a
+    buffer no consumer on this worker reads (the coordinator's result
+    stream); a spooling buffer holds pages alone too.
     """
 
     def __init__(self, n_partitions: int, broadcast: bool = False,
                  max_buffered_bytes: int = 256 << 20,
-                 spool_dir: Optional[str] = None):
+                 spool_dir: Optional[str] = None, in_process: bool = True):
         self.n_partitions = n_partitions
         self.broadcast = broadcast
         self._parts = [_PartitionBuffer() for _ in range(n_partitions)]
@@ -71,6 +103,7 @@ class OutputBuffer:
         self._spooled_bytes = 0
         self._max_bytes = max_buffered_bytes
         self._spool_dir = spool_dir
+        self._pages_only = spool_dir is not None or not in_process
         self._spool_f = None  # unlinked temp file: space frees on close
         self._failed: Optional[str] = None
 
@@ -88,45 +121,82 @@ class OutputBuffer:
         self._spooled_bytes += len(page)
         return ("d", off, len(page))
 
-    def _read_entry(self, entry) -> bytes:
-        if isinstance(entry, bytes):
-            return entry
-        if isinstance(entry, _SharedPage):
-            return entry.page
-        _, off, length = entry
-        return os.pread(self._spool_f.fileno(), length, off)
+    def _read_entry(self, entry):
+        if isinstance(entry, _Shared):
+            return entry.item
+        if isinstance(entry, tuple):
+            _, off, length = entry
+            return os.pread(self._spool_f.fileno(), length, off)
+        return entry
+
+    def _entry_bytes(self, entry) -> int:
+        if isinstance(entry, _Shared):
+            return entry.size
+        if isinstance(entry, tuple):
+            return entry[2]
+        return _item_bytes(entry)
 
     def _release_entry(self, entry):
         # caller holds the lock
-        if isinstance(entry, bytes):
-            self._bytes -= len(entry)
-        elif isinstance(entry, _SharedPage):
+        if isinstance(entry, _Shared):
             entry.refs -= 1
             if entry.refs == 0:
-                self._bytes -= len(entry.page)
+                self._bytes -= entry.size
+        elif not isinstance(entry, tuple):
+            self._bytes -= _item_bytes(entry)
 
-    def enqueue(self, partition: Optional[int], page: bytes):
-        """Append a page; partition=None broadcasts. Blocks for back-pressure
-        when the buffer is full (OutputBufferMemoryManager's blocked future)
-        — unless spooling, where overflow goes to disk instead."""
+    def claim(self, partition: int) -> bool:
+        """A consumer on this worker takes `partition` in process: True
+        unless the buffer holds pages alone or an HTTP pull already read
+        it."""
+        with self._cond:
+            pb = self._parts[partition]
+            if not (self._pages_only or pb.remote):
+                pb.local = True
+            return pb.local
+
+    def hands_off(self, partition: int) -> bool:
+        """Whether the producer should enqueue its device batch for
+        `partition` rather than a page: claimed in process, or read by no
+        one yet and under `_UNREAD_DEVICE_BYTES`."""
+        pb = self._parts[partition]
+        if self._pages_only or pb.remote or pb.aborted:
+            return False
+        return pb.local or pb.unread_bytes < _UNREAD_DEVICE_BYTES
+
+    def enqueue(self, partition, item):
+        """Append a page, or a device batch for partitions handed in
+        process. `partition` is a list of partitions, exactly those; one
+        partition (all of them where the buffer broadcasts); or None, all.
+        Blocks for back-pressure when the buffer is full
+        (OutputBufferMemoryManager's blocked future) — unless spooling,
+        where overflow pages go to disk instead."""
         with self._cond:
             if self._spool_dir is None:
                 while self._bytes >= self._max_bytes and not self._all_aborted():
                     self._cond.wait(timeout=1.0)
-            fanout = range(self.n_partitions) if (self.broadcast or partition is None) \
-                else (partition,)
+            if isinstance(partition, (list, tuple)):
+                fanout = partition
+            elif self.broadcast or partition is None:
+                fanout = range(self.n_partitions)
+            else:
+                fanout = (partition,)
             targets = [p for p in fanout if not self._parts[p].aborted]
-            entry: object = page
+            size = _item_bytes(item)
+            entry: object = item
             if (self._spool_dir is not None
-                    and self._bytes + len(page) > self._max_bytes):
-                entry = self._spool_page(page)
+                    and self._bytes + size > self._max_bytes):
+                entry = self._spool_page(item)
             elif len(targets) > 1:
-                entry = _SharedPage(page, len(targets))
-                self._bytes += len(page)
+                entry = _Shared(item, size, len(targets))
+                self._bytes += size
             elif targets:
-                self._bytes += len(page)
+                self._bytes += size
             for p in targets:
-                self._parts[p].entries.append(entry)
+                pb = self._parts[p]
+                pb.entries.append(entry)
+                if not (isinstance(item, bytes) or pb.local or pb.remote):
+                    pb.unread_bytes += size
             self._cond.notify_all()
 
     def set_no_more_pages(self):
@@ -162,14 +232,20 @@ class OutputBuffer:
     # -- consumer ---------------------------------------------------------
 
     def get(self, partition: int, token: int, max_bytes: int = 16 << 20,
-            max_wait_s: float = 1.0) -> Tuple[List[bytes], int, bool]:
-        """Pages from `token` on (long-poll up to max_wait_s).
+            max_wait_s: float = 1.0, in_process: bool = False,
+            ) -> Tuple[list, int, bool]:
+        """Items from `token` on (long-poll up to max_wait_s): pages, and
+        device batches where the partition is handed in process or was not
+        read before. `in_process=False` is an HTTP pull, after which the
+        producer makes pages for the partition.
 
-        Returns (pages, next_token, complete). Re-fetching an unacked token
-        returns the same pages (exactly-once via client-side dedup, like
+        Returns (items, next_token, complete). Re-fetching an unacked token
+        returns the same items (exactly-once via client-side dedup, like
         SerializedPage token semantics)."""
         with self._cond:
             pb = self._parts[partition]
+            if not in_process:
+                pb.remote = True
             if self._failed is not None:
                 raise BufferFailed(self._failed)
             deadline = max_wait_s
@@ -185,9 +261,9 @@ class OutputBuffer:
             if t < pb.base_token:
                 t = pb.base_token  # already acked past this point
             while t < pb.end_token and size < max_bytes:
-                page = self._read_entry(pb.entries[t - pb.base_token])
-                pages.append(page)
-                size += len(page)
+                entry = pb.entries[t - pb.base_token]
+                pages.append(self._read_entry(entry))
+                size += self._entry_bytes(entry)
                 t += 1
             complete = pb.no_more and t >= pb.end_token
             return pages, t, complete

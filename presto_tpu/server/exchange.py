@@ -9,6 +9,10 @@ back-pressure.
 Response wire format (mirrors PagesResponseWriter):
     <u32 header_len> <json header {next_token, complete, page_lens,
                                    task_state, error}> <pages bytes...>
+
+An upstream task on the consumer's own worker is read in process instead,
+with the same token/ack loop over its OutputBuffer (`_BufferPuller`): what
+comes out is the device batch the producer handed, not a page.
 """
 
 from __future__ import annotations
@@ -19,10 +23,12 @@ import struct
 import threading
 import urllib.error
 import urllib.request
-from typing import Iterator, List, Optional
+from typing import Dict, Iterator, List, Optional
 
 from presto_tpu.batch import Batch
+from presto_tpu.scan import metrics as _scan_metrics
 from presto_tpu.serde import deserialize_batch
+from presto_tpu.server.buffers import BufferFailed
 
 
 class ExchangeFailure(RuntimeError):
@@ -68,31 +74,17 @@ class _LocationPuller(threading.Thread):
         token = 0
         try:
             while not self.out.closed:
-                url = f"{self.location}/{token}"
-                try:
-                    with urllib.request.urlopen(url, timeout=30) as r:
-                        data = r.read()
-                except urllib.error.HTTPError as e:
-                    if e.code == 404:
-                        # task not created yet — transient during scheduling
-                        import time
-
-                        time.sleep(0.05)
-                        continue
-                    raise
-                header, pages = parse_results_payload(data)
-                if header.get("error"):
-                    raise ExchangeFailure(header["error"], task_error=True)
-                for p in pages:
+                got = self._fetch(token)
+                if got is None:
+                    continue
+                items, next_token, complete = got
+                for p in items:
                     self.out._offer(p)
-                next_token = header["next_token"]
-                if pages:
-                    # acknowledge so the producer can release the pages
-                    urllib.request.urlopen(
-                        f"{self.location}/{next_token}/ack", timeout=30
-                    ).read()
+                if items:
+                    # acknowledge so the producer can release them
+                    self._ack(next_token)
                 token = next_token
-                if header.get("complete"):
+                if complete:
                     break
         except Exception as e:  # propagate to the consuming iterator
             self.out._fail(f"{self.location}: {e}",
@@ -100,18 +92,70 @@ class _LocationPuller(threading.Thread):
         finally:
             self.out._done()
 
+    def _fetch(self, token: int):
+        """(items, next token, complete) from `token` on, or None to ask
+        again."""
+        url = f"{self.location}/{token}"
+        try:
+            with urllib.request.urlopen(url, timeout=30) as r:
+                data = r.read()
+        except urllib.error.HTTPError as e:
+            if e.code == 404:
+                # task not created yet — transient during scheduling
+                import time
+
+                time.sleep(0.05)
+                return None
+            raise
+        header, pages = parse_results_payload(data)
+        if header.get("error"):
+            raise ExchangeFailure(header["error"], task_error=True)
+        return pages, header["next_token"], header.get("complete")
+
+    def _ack(self, token: int):
+        urllib.request.urlopen(f"{self.location}/{token}/ack",
+                               timeout=30).read()
+
+
+class _BufferPuller(_LocationPuller):
+    """The same loop over an upstream task's OutputBuffer on this worker,
+    whose partition the consumer claimed: device batches, no HTTP."""
+
+    def __init__(self, location: str, buffer, partition: int,
+                 out: "ExchangeClient"):
+        super().__init__(location, out)
+        self.buffer = buffer
+        self.partition = partition
+
+    def _fetch(self, token: int):
+        try:
+            return self.buffer.get(self.partition, token, in_process=True)
+        except BufferFailed as e:
+            # as the HTTP path reports it: the producer's own error
+            raise ExchangeFailure(str(e), task_error=True) from e
+
+    def _ack(self, token: int):
+        self.buffer.ack(self.partition, token)
+
 
 class ExchangeClient:
-    """Pulls pages from N upstream locations concurrently, yields Batches."""
+    """Pulls pages from N upstream locations concurrently, yields Batches.
 
-    def __init__(self, locations: List[str], max_buffered_pages: int = 64):
+    `local` maps the locations whose producer runs on this worker to its
+    (OutputBuffer, partition), read in process (`_BufferPuller`)."""
+
+    def __init__(self, locations: List[str], max_buffered_pages: int = 64,
+                 local: Optional[Dict[str, tuple]] = None):
         self.locations = list(locations)
+        local = local or {}
         self._queue: queue.Queue = queue.Queue(maxsize=max_buffered_pages)
         self._remaining = len(self.locations)
         self._lock = threading.Lock()
         self._error: Optional[str] = None
         self.closed = False
-        self._pullers = [_LocationPuller(loc, self) for loc in self.locations]
+        self._pullers = [
+            _BufferPuller(loc, *local[loc], self) if loc in local
+            else _LocationPuller(loc, self) for loc in self.locations]
         for p in self._pullers:
             p.start()
 
@@ -134,7 +178,9 @@ class ExchangeClient:
             self._remaining -= 1
         self._queue.put(None)  # wake consumer
 
-    def pages(self) -> Iterator[bytes]:
+    def pages(self) -> Iterator[object]:
+        """Each upstream item as it arrives: a page, or a device batch
+        handed in process; `decode` makes either a Batch."""
         done = 0
         while True:
             with self._lock:
@@ -175,9 +221,15 @@ class ExchangeClient:
             f"dictionary {digest[:12]} unresolvable from any upstream",
             task_error=True)
 
-    def decode(self, page: bytes, tracer=None) -> Batch:
+    def decode(self, page, tracer=None) -> Batch:
         """One page as a batch on the device; `tracer` records its
-        `page_decode` and `page_upload` (serde.deserialize_batch)."""
+        `page_decode` and `page_upload` (serde.deserialize_batch). A device
+        batch handed in process needs neither (a broadcast's is shared by
+        its consumers: no consumer program donates an input batch)."""
+        if isinstance(page, Batch):
+            _scan_metrics.record("exchange_handoff_batches", 1)
+            return page
+        _scan_metrics.record("exchange_page_batches", 1)
         return deserialize_batch(page, dict_resolver=self._resolve_dict,
                                  tracer=tracer)
 

@@ -11,9 +11,11 @@ Reference surface:
   PUT /v1/info/state "SHUTTING_DOWN"
 
 TPU-native shape: a task executes one plan fragment as a stream of
-fixed-capacity device batches (exec/runtime); the task's sink serializes
-output pages into an OutputBuffer partitioned for the consumer stage
-(hash / broadcast / gather). Fragments arrive as JSON over the closed
+fixed-capacity device batches (exec/runtime); the task's sink puts its
+output into an OutputBuffer partitioned for the consumer stage
+(hash / broadcast / gather): serialized pages for a consumer elsewhere, the
+device batch itself for a consumer task on this worker, which reads the
+buffer in process. Fragments arrive as JSON over the closed
 plan-node vocabulary (plan/codec.py) — the TaskUpdateRequest JSON/Smile
 codec analog; nothing on the wire can execute code.
 """
@@ -44,7 +46,9 @@ from presto_tpu.plan.fragmenter import (
     OUT_RR,
     Fragment,
 )
-from presto_tpu.serde import serialize_batch
+from presto_tpu.plan.nodes import Output
+from presto_tpu.memory import batch_device_bytes
+from presto_tpu.serde import handoff_batch, serialize_batch
 from presto_tpu.server.buffers import BufferFailed, OutputBuffer
 from presto_tpu.server.exchange import ExchangeClient, encode_results_payload
 
@@ -167,7 +171,8 @@ class TaskExecution:
 
     def __init__(self, task_id: str, update: TaskUpdate, catalog: Catalog,
                  memory_pool=None, spill_manager=None, executor=None,
-                 trace_token: Optional[str] = None, node_id: str = ""):
+                 trace_token: Optional[str] = None, node_id: str = "",
+                 local_upstreams: Optional[dict] = None):
         self.task_id = task_id
         self.update = update
         self.catalog = catalog
@@ -214,7 +219,13 @@ class TaskExecution:
             # are created in a later phase, so back-pressure cannot drain
             spool_dir=(spill_manager.dir if update.spool and spill_manager
                        is not None else None),
+            # the plan's root fragment (its Output) feeds the coordinator's
+            # result stream, which is pulled over HTTP
+            in_process=not isinstance(f.root, Output),
         )
+        # upstream location -> (OutputBuffer, partition) for the producers
+        # on this worker whose partition this task claimed (TaskManager)
+        self._local = local_upstreams or {}
         self.created_at = time.time()
         self.finished_at: Optional[float] = None
         self._clients: List[ExchangeClient] = []
@@ -225,7 +236,8 @@ class TaskExecution:
 
     def _remote_source_factory(self, fragment_id: int):
         urls = self.update.upstreams[fragment_id]
-        client = ExchangeClient(urls)
+        client = ExchangeClient(urls, local={
+            u: self._local[u] for u in urls if u in self._local})
         self._clients.append(client)
         if not self.tracer.enabled:
             return client.batches()
@@ -287,8 +299,15 @@ class TaskExecution:
         finally:
             if self._inflight is not None:
                 self._inflight.finish()
-            for c in self._clients:
-                c.close()
+            self._close_upstreams()
+
+    def _close_upstreams(self):
+        """Stop pulling, and release what local producers still hold for
+        this task: it reads its partitions no more."""
+        for c in self._clients:
+            c.close()
+        for buf, part in self._local.values():
+            buf.abort(part)
 
     def _run_inner(self, cfg: ExecConfig):
         ctx = ExecContext(self.catalog, cfg,
@@ -430,13 +449,27 @@ class TaskExecution:
 
         return counting_sink
 
+    def _put(self, parts, b: Batch, n: int, radix: Optional[tuple] = None):
+        """Enqueue `b`, whose live count is `n`, for the partitions `parts`:
+        the device batch itself for those taken in process or not read yet
+        (serde.handoff_batch: phase `page_handoff`, `items` its device
+        bytes), one page for the rest; `radix` tags a partition-aligned
+        one."""
+        handed = [p for p in parts if self.buffer.hands_off(p)]
+        if handed:
+            with self.tracer.phase("page_handoff") as ph:
+                hb = handoff_batch(b, n, radix)
+                ph.items = batch_device_bytes(hb)
+            self.buffer.enqueue(handed, hb)
+        rest = [p for p in parts if p not in handed]
+        if rest:
+            with self.tracer.phase("host_sync:sink_serialize"):
+                page = serialize_batch(b, dict_refs=True, tracer=self.tracer,
+                                       radix=radix)
+            self.buffer.enqueue(rest, page)
+
     def _make_sink_inner(self, f: Fragment, cfg):
         phase = self.tracer.phase
-
-        def page_of(b: Batch, **kw):
-            with phase("host_sync:sink_serialize"):
-                return serialize_batch(b, dict_refs=True, tracer=self.tracer,
-                                       **kw)
 
         if f.output_partitioning == OUT_HASH and self.update.n_out_partitions > 1:
             pid_fn = _jit_partition_ids(
@@ -454,9 +487,9 @@ class TaskExecution:
                 if rid_fn is None:
                     for p in range(self.update.n_out_partitions):
                         mask = live & (pid == p)
-                        if mask.any():
-                            self.buffer.enqueue(
-                                p, page_of(b.with_live(mask)))
+                        n = int(mask.sum())
+                        if n:
+                            self._put((p,), b.with_live(mask), n)
                     return
                 # partition-aligned exchange: the consumer breaker radix-
                 # partitions on these same keys, so split each consumer's
@@ -471,9 +504,9 @@ class TaskExecution:
                     if not pmask.any():
                         continue
                     for r in np.unique(rid[pmask]):
-                        self.buffer.enqueue(
-                            p, page_of(b.with_live(pmask & (rid == r)),
-                                       radix=(int(r), R, keys)))
+                        mask = pmask & (rid == r)
+                        self._put((p,), b.with_live(mask), int(mask.sum()),
+                                  radix=(int(r), R, keys))
 
             return sink
 
@@ -485,30 +518,29 @@ class TaskExecution:
                 # page-level round robin (the reference's
                 # ArbitraryOutputBuffer: any consumer may take a page;
                 # deterministic rotation here keeps tasks balanced)
-                if self._live_rows(b) == 0:
+                n = self._live_rows(b)
+                if n == 0:
                     return
                 p = state["next"] % n_parts
                 state["next"] += 1
-                self.buffer.enqueue(p, page_of(b))
+                self._put((p,), b, n)
 
             return sink
 
+        parts = (tuple(range(self.update.n_out_partitions))
+                 if f.output_partitioning == OUT_BROADCAST else (0,))
+
         def sink(b: Batch):
-            # gather/broadcast: one serialized page, fanned out by the buffer
-            if self._live_rows(b) == 0:
-                return
-            page = page_of(b)
-            if f.output_partitioning == OUT_BROADCAST:
-                self.buffer.enqueue(None, page)
-            else:
-                self.buffer.enqueue(0, page)
+            # gather/broadcast: one item, fanned out by the buffer
+            n = self._live_rows(b)
+            if n:
+                self._put(parts, b, n)
 
         return sink
 
     def abort(self):
         self.state = "aborted"
-        for c in self._clients:
-            c.close()
+        self._close_upstreams()
         for p in range(self.buffer.n_partitions):
             self.buffer.abort(p)
 
@@ -546,6 +578,9 @@ class TaskManager:
         self.memory_pool = memory_pool or MemoryPool(None)
         self.spill_manager = spill_manager or SpillManager()
         self.tasks: Dict[str, TaskExecution] = {}
+        # this worker's base URL once it serves (Worker): upstream
+        # locations under it are read in process
+        self.url: Optional[str] = None
         self.executor = TaskExecutor(run_slots)
         self._lock = threading.Lock()
         # query_id -> QueryScopedPool: per-query slice of the node pool,
@@ -628,6 +663,24 @@ class TaskManager:
             return {qid: qp.query_reserved
                     for qid, qp in self._query_pools.items()}
 
+    def _claim_upstreams_locked(self, update: TaskUpdate) -> dict:
+        """Caller holds self._lock. The upstream locations whose producer
+        task runs on this worker, each partition claimed on its buffer so
+        that the producer hands it device batches, not pages: location ->
+        (buffer, partition). A location elsewhere, or one whose buffer
+        spools, is pulled over HTTP."""
+        out = {}
+        for urls in update.upstreams.values():
+            for url in urls:
+                m = _LOCATION_RE.match(url)
+                if m is None or m.group(1) != self.url:
+                    continue
+                t = self.tasks.get(m.group(2))
+                part = int(m.group(3))
+                if t is not None and t.buffer.claim(part):
+                    out[url] = (t.buffer, part)
+        return out
+
     def update_task(self, task_id: str, update: TaskUpdate,
                     trace_token: Optional[str] = None) -> dict:
         with self._lock:
@@ -638,7 +691,9 @@ class TaskManager:
                                   self.spill_manager,
                                   executor=self.executor,
                                   trace_token=trace_token,
-                                  node_id=self.node_id)
+                                  node_id=self.node_id,
+                                  local_upstreams=(
+                                      self._claim_upstreams_locked(update)))
                 self.tasks[task_id] = t
             return t.info()
 
@@ -658,6 +713,8 @@ class TaskManager:
         return any(t.state == "running" for t in self.tasks.values())
 
 
+# an upstream location: "{worker base url}/v1/task/{task id}/results/{partition}"
+_LOCATION_RE = re.compile(r"^(.+)/v1/task/([^/]+)/results/(\d+)$")
 _TASK_RE = re.compile(r"^/v1/task/([^/]+)$")
 _RESULTS_RE = re.compile(r"^/v1/task/([^/]+)/results/(\d+)/(\d+)$")
 _ACK_RE = re.compile(r"^/v1/task/([^/]+)/results/(\d+)/(\d+)/ack$")
@@ -796,6 +853,13 @@ class Worker:
                     tracer = t.tracer if pages else _obs_trace.NOOP
                     with tracer.phase("page_serve", items=len(pages),
                                       role="http"):
+                        # a device batch enqueued before this partition's
+                        # first pull: its page is made here
+                        pages = [p if isinstance(p, bytes) else
+                                 serialize_batch(
+                                     p, dict_refs=True, tracer=t.tracer,
+                                     radix=getattr(p, "radix", None))
+                                 for p in pages]
                         return self._bytes(
                             encode_results_payload(header, pages))
                 m = _ACK_RE.match(self.path)
@@ -885,6 +949,7 @@ class Worker:
             install_client_context(tls)
         self.port = self.server.server_address[1]
         self.url = f"{scheme}://127.0.0.1:{self.port}"
+        self.task_manager.url = self.url
         self._serve_thread = threading.Thread(
             target=self.server.serve_forever, daemon=True,
             name=f"worker-http-{self.node_id}",

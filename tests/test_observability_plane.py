@@ -609,8 +609,7 @@ def test_statements_leave_phase_summaries_that_outlive_the_cluster():
     assert "host_sync:breaker_finish" in by_id[ids["q1"][0]]["phases"]["task"]
 
 
-PAGE_PHASES = {"task": ("page_ready", "page_fetch", "page_encode",
-                        "page_decode", "page_upload"),
+PAGE_PHASES = {"task": ("page_ready", "page_fetch", "page_encode"),
                "http": ("page_serve",),
                "coordinator": ("page_decode", "page_upload")}
 
@@ -627,10 +626,11 @@ def _pages(summary, field):
 
 
 def test_a_page_trip_is_six_phases():
-    """Q3 through one worker: a sink serializes each page in three phases
-    inside `host_sync:sink_serialize`, a request thread serves it, and its
-    consumer - a task, or the coordinator for the root stream - decodes and
-    uploads it, with `exchange_wait` round the queue alone."""
+    """Q3 through one worker: the tasks hand each other device batches
+    (`page_handoff`), so the root stream alone is pages. Its sink
+    serializes each page in three phases inside `host_sync:sink_serialize`,
+    a request thread serves it, and the coordinator decodes and uploads it,
+    with `exchange_wait` round the queue alone."""
     from presto_tpu.server.coordinator import DistributedRunner
 
     with DistributedRunner(_lineitem_catalog(), n_workers=1) as dr:
@@ -642,19 +642,22 @@ def test_a_page_trip_is_six_phases():
         for name in names:
             assert a["phases"][role][name]["n"] >= 1, (role, name)
     assert a["phases"]["coordinator"]["exchange_wait"]["wait"] is True
+    task = a["phases"]["task"]
+    assert task["page_handoff"]["n"] >= 1 and task["page_handoff"]["items"]
+    assert "page_decode" not in task and "page_upload" not in task
     # the counts and the bytes repeat exactly; the request threads' `n`
     # follows the clock (module `_counts`), the pages they served do not
     assert _counts(a) == _counts(b)
     assert _pages(a, "items") == _pages(b, "items")
-    # every page serialized is decoded once, the root stream's included
+    # every page serialized is decoded once, and is the root stream's
     n = {name: sum(agg["n"] for _, nm, agg in _each(a) if nm == name)
          for name in ("page_encode", "page_decode", "page_fetch")}
-    assert n["page_encode"] == n["page_decode"] == n["page_fetch"] >= 2
+    root = a["phases"]["coordinator"]["page_decode"]["n"]
+    assert n["page_encode"] == n["page_decode"] == n["page_fetch"] == root
     served = a["phases"]["http"]["page_serve"]
     assert served["items"] == n["page_encode"] >= served["n"]
     # the sink's three phases are inside its serialize, not beside it
-    task = a["phases"]["task"]
-    inside = sum(task[p]["busy_s"] for p in PAGE_PHASES["task"][:3])
+    inside = sum(task[p]["busy_s"] for p in PAGE_PHASES["task"])
     assert inside <= task["host_sync:sink_serialize"]["busy_s"]
     named = sum(agg["self_s"] for agg in task.values())
     assert 0 < named <= a["task_wall_s"] * 1.01
